@@ -37,6 +37,7 @@ from typing import Callable, Optional
 
 import jax
 import numpy as np
+from jax.extend import core as jex_core
 
 from raft_tpu.analysis.findings import Finding
 
@@ -65,9 +66,9 @@ def _sub_jaxprs(eqn):
     subs = []
 
     def collect(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             subs.append(v.jaxpr)
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             subs.append(v)
         elif isinstance(v, (tuple, list)):
             for e in v:
@@ -80,17 +81,17 @@ def _sub_jaxprs(eqn):
 
 def peak_live_bytes(jaxpr) -> int:
     """Peak simultaneously-live INTERMEDIATE bytes of a (closed) jaxpr."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
 
     n = len(jaxpr.eqns)
     last_use: dict = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 last_use[v] = i
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, jex_core.Literal):
             last_use[v] = n  # results never die
 
     live: dict = {}

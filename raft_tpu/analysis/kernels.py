@@ -1104,7 +1104,8 @@ def _ivf_build(pk, jnp, sds, p):
 def _ivf_acc(pk, rec, blocks, p):
     in_blocks = [b for role, b in blocks if role == "in"]
     pt = in_blocks[2][1]  # the (1, pt, rot) slab block
-    return f"pad_tile={pt}", pk.fused_ivf_vmem_bytes(pt, p["rot"], p["k"])
+    return f"pad_tile={pt}", pk.fused_ivf_vmem_bytes(
+        pt, p["rot"], p["k"], n_probes=p["n_probes"])
 
 
 def _pq_build(pk, jnp, sds, p):
@@ -1122,7 +1123,7 @@ def _pq_build(pk, jnp, sds, p):
 
 def _pq_acc(pk, rec, blocks, p):
     in_blocks = [b for role, b in blocks if role == "in"]
-    pt = in_blocks[4][1]  # the (1, pt, pq_dim) code block
+    pt = in_blocks[4][2]  # the (1, pq_dim, pt) code block
     return f"pad_tile={pt}", pk.fused_pq_vmem_bytes(
         pt, p["pq_dim"], p["book"], p["pq_len"], p["k"])
 
@@ -1151,18 +1152,10 @@ def _ring_build(pk, jnp, sds, p):
     import numpy as _np
 
     import jax as _jax
-    try:
-        from jax.experimental.shard_map import shard_map
-
-        def wrap(fn, mesh):
-            from jax.sharding import PartitionSpec as P
-            return shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                             check_rep=False)
-    except ImportError:  # jax >= 0.6 moved it
-        def wrap(fn, mesh):
-            from jax.sharding import PartitionSpec as P
-            return _jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                                  check_vma=False)
+    def wrap(fn, mesh):
+        from jax.sharding import PartitionSpec as P
+        return _jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                              check_vma=False)
     from jax.sharding import Mesh
     mesh = Mesh(_np.array(_jax.devices()[:1]), ("rx",))
     fn = wrap(lambda x: pk.pallas_ring_shift(x, "rx", 1, interpret=True),

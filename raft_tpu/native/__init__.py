@@ -8,13 +8,17 @@ Reference analogs: the mmap'd fbin dataset reader
 these are the IO/packing/sequential-host pieces the reference also keeps
 native.
 
-Built with g++ into ``libraft_tpu_native.so`` on first use (``ensure_built``)
-and bound via ctypes — no pybind11 dependency. Every entry point has a
-pure-numpy fallback so the package works without a toolchain."""
+Built with g++ into ``libraft_tpu_native-<hash>.so`` on first use
+(``ensure_built``) and bound via ctypes — no pybind11 dependency. The name
+carries a hash of the source, so a copied tree never loads a binary its
+own source did not produce (mtimes say nothing after a copy). Every entry
+point has a pure-numpy fallback so the package works without a toolchain."""
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -24,7 +28,6 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "raft_tpu_native.cpp")
-_SO = os.path.join(_HERE, "libraft_tpu_native.so")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -33,27 +36,49 @@ _has_prefetch = False
 _has_graph_search = False
 
 
+def library_path() -> Optional[str]:
+    """The shared library built from the current source: its name holds
+    the first 16 hex digits of the source's sha256. None without source."""
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    return os.path.join(_HERE, f"libraft_tpu_native-{digest}.so")
+
+
 def ensure_built(force: bool = False) -> bool:
-    """Compile the shared library if missing or older than its source;
-    returns availability."""
+    """Compile the shared library unless one built from this exact source
+    exists; returns availability. Builds land under a temporary name and
+    are renamed into place, so concurrent first uses never load a
+    half-written file; binaries of other sources are removed."""
     global _build_failed
-    # rebuild only when the source exists and is newer; a shipped .so
-    # without src/ is still valid
-    if (os.path.exists(_SO) and not force
-            and (not os.path.exists(_SRC)
-                 or os.path.getmtime(_SO) >= os.path.getmtime(_SRC))):
+    so = library_path()
+    if so is None:
+        return False
+    if os.path.exists(so) and not force:
         return True
     if _build_failed and not force:
         return False
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-             _SRC, "-o", _SO],
+             _SRC, "-o", tmp],
             check=True, capture_output=True, timeout=120)
-        return True
+        os.replace(tmp, so)
     except Exception:
         _build_failed = True
-        return os.path.exists(_SO)
+        if os.path.exists(tmp):
+            os.remove(tmp)
+        return os.path.exists(so)
+    for old in glob.glob(os.path.join(_HERE, "libraft_tpu_native*.so")):
+        if old != so:
+            try:
+                os.remove(old)
+            except OSError:
+                pass
+    return True
 
 
 def _get_lib():
@@ -64,7 +89,7 @@ def _get_lib():
         if not ensure_built():
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(library_path())
         except OSError:
             # stale/foreign-arch artifact: the numpy fallbacks take over
             _build_failed = True

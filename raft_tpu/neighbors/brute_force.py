@@ -386,6 +386,7 @@ def search(index: Index, queries, k: int, filter=None,
         "brute_force", scan_mode)
     ineligible = fused_ineligible_reason(
         index.metric, index.dataset.dtype, k, filter is not None, fast_scan)
+    pk.require_compiled_kernel("brute_force", scan_mode, ineligible)
     ex_params = {"k": k, "nq": nq, "bucket": queries.shape[0],
                  "n_db": index.size, "dim": index.dim,
                  "metric": index.metric.name}

@@ -639,6 +639,7 @@ def search(
     ineligible = fused_ineligible_reason(
         index.metric, index.dataset.dtype, itopk, filter is not None,
         fast_scan)
+    pk.require_compiled_kernel("cagra", scan_mode, ineligible)
     ex_params = {"k": int(k), "nq": nq, "bucket": queries.shape[0],
                  "metric": index.metric.name, "graph_degree":
                  index.graph_degree, "fast_scan": fast_scan}

@@ -687,6 +687,7 @@ def search(
     ineligible = fused_ineligible_reason(
         index.metric, index.list_data.dtype, int(k), filter is not None,
         fast_scan, require_float=False)
+    pk.require_compiled_kernel("ivf_flat", scan_mode, ineligible)
     ex_params = {"k": int(k), "nq": nq, "bucket": queries.shape[0],
                  "n_probes": n_probes, "n_lists": index.n_lists,
                  "list_pad": list_pad, "dim": index.dim,
@@ -696,7 +697,8 @@ def search(
         if use_fused and ineligible is None:
             pad_tile = pk.plan_fused_ivf_tile(
                 list_pad, index.dim, int(k),
-                jnp.dtype(index.list_data.dtype).itemsize)
+                jnp.dtype(index.list_data.dtype).itemsize,
+                n_probes=n_probes)
             obs_explain.record_dispatch(
                 "ivf_flat", scan_mode, "pallas", dreason, params=ex_params,
                 plan={"pad_tile": pad_tile, "interpret": fused_interp})
@@ -709,8 +711,8 @@ def search(
             )
         else:
             # The unfused ivf_scan kernel only routes where a committed probe
-            # artifact shows it beating XLA — PALLAS_PROBE_tpu.json currently
-            # says it does not (22.3 ms vs 10.9 ms), so this stays off
+            # artifact shows it beating XLA — none is committed (a pre-fused
+            # probe measured 22.3 ms vs 10.9 ms), so this stays off
             # without a measured verdict; the RAFT_TPU_PALLAS=1 env override
             # is retired. An explicit bf16 request still wins over any fp32
             # Pallas scan — never silently benchmark fp32 under a bf16 label.

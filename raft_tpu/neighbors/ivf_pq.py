@@ -1459,6 +1459,7 @@ def search(
     ineligible = fused_ineligible_reason(
         index.metric, index.list_codes.dtype, int(k), filter is not None,
         False, require_float=False)
+    pk.require_compiled_kernel("ivf_pq", scan_mode, ineligible)
     ex_params = {"k": int(k), "nq": nq, "bucket": queries.shape[0],
                  "n_probes": n_probes, "n_lists": index.n_lists,
                  "list_pad": list_pad, "pq_dim": index.pq_dim,
@@ -1480,7 +1481,8 @@ def search(
                 ensure_scan_cache(index, params.scan_cache_dtype)
                 pad_tile = pk.plan_fused_ivf_tile(
                     list_pad, index.rot_dim, int(k),
-                    jnp.dtype(index.list_decoded.dtype).itemsize)
+                    jnp.dtype(index.list_decoded.dtype).itemsize,
+                    n_probes=n_probes)
                 obs_explain.record_dispatch(
                     "ivf_pq", requested, "pallas_cache", dreason,
                     params=ex_params,
@@ -1516,6 +1518,8 @@ def search(
             else:
                 # fused LUT regime unsupported at these params -> XLA engines
                 lut_unsupported = True
+                pk.require_compiled_kernel("ivf_pq", requested,
+                                           "lut_params_unsupported")
         if v is None:
             memory_resolved = scan_mode in ("auto", "pallas")
             if memory_resolved:

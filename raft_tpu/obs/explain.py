@@ -224,8 +224,8 @@ def note_select_k(n: int, k: int, algo: str, k_pad: int = 0) -> None:
 def dispatch_counts(
         registry: Optional[_metrics.Registry] = None) -> Dict[tuple, int]:
     """``{(family, engine, reason): count}`` view of the dispatch
-    counter — the explain reason histogram serving_bench / tpu_queue2
-    artifacts record next to the pallasgate verdicts."""
+    counter — the explain reason histogram serving_bench artifacts
+    record next to the pallasgate verdicts."""
     reg = registry if registry is not None else _metrics.REGISTRY
     fam = reg.get("raft_tpu_dispatch_total")
     if fam is None:

@@ -16,8 +16,8 @@ Routes:
 - ``GET /metrics``  → Prometheus text exposition (0.0.4), 200.
 - ``GET /metrics.json`` → the registry's JSON dump, 200.
 - ``GET /healthz``  → JSON health doc; 200 for ``ok``/``degraded``
-  (alive but shedding is still alive), 503 for anything else — the
-  TPU_RUNBOOK pre-flight curls this before pointing traffic at a host.
+  (alive but shedding is still alive), 503 for anything else — a
+  pre-flight curls this before pointing traffic at a host.
   Fleet-backed servers aggregate: ``"degraded"`` while any replica is
   degraded/draining but quorum holds, ``"unhealthy"`` below quorum.
 - ``GET /debug/bundle`` → a freshly-built flight-recorder diagnostics

@@ -301,15 +301,8 @@ class Comms:
         """shard_map ``fn`` over this comms' mesh (the "enqueue a collective
         program" entry point; analog of launching NCCL ops on the handle's
         stream)."""
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        # jax < 0.6: shard_map lives in jax.experimental and the replication
-        # check is spelled check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check_vma)
 
     def shard(self, x, spec: P):
         """Place ``x`` with a NamedSharding on this mesh. In a

@@ -19,7 +19,7 @@ Each surviving point carries:
   anchor that flags a prediction promising less than physics allows.
 
 The default grids are deliberately modest (the artifact is refreshed by
-a tpu_queue2.sh step with a bounded window); ``mini=True`` shrinks them
+a bounded chip run); ``mini=True`` shrinks them
 to CI scale (seconds on CPU).
 """
 

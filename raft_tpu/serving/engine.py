@@ -4,10 +4,11 @@
 :mod:`raft_tpu.serving.searchers` handle) and turns concurrent
 single-query ``submit()`` calls into batched searches at the
 ``utils.shape.query_bucket`` shapes the index's public wrapper already
-compiles. The measured case for coalescing: on chip, batch-10 search
-latency equals batch-1 latency (BENCH_r05.json: ivf_flat 6.238 ms b1 vs
-6.259 ms b10), so every solo dispatch forfeits ~10x per-replica QPS at
-iso-latency.
+compiles. The case for coalescing: a pre-fused chip record (since
+removed with the chip setup it came from) had batch-10 search latency
+equal to batch-1 latency (ivf_flat 6.238 ms b1 vs 6.259 ms b10), so every
+solo dispatch would forfeit ~10x per-replica QPS at iso-latency — to be
+re-measured on the current chip.
 
 Three mechanisms, each its own thread-or-phase:
 
@@ -27,8 +28,8 @@ Three mechanisms, each its own thread-or-phase:
    bench/timing.py) and scatters per-request row slices through the
    futures. With ``max_inflight >= 2`` batch N's readback overlaps
    batch N+1's staging and device time, so host staging — the thing
-   that ballooned b1 latency to 37-45 ms under host contention in
-   BENCH_TPU_SESSION_r05.json — no longer serializes with the device.
+   that ballooned b1 latency to 37-45 ms under host contention in that
+   same pre-fused record — no longer serializes with the device.
 
 Exactness: a coalesced request's result row is bit-identical to a solo
 search of the same query at the same bucket shape and row (the search
@@ -546,8 +547,7 @@ class Engine:
                       host: str = "127.0.0.1") -> MetricsServer:
         """Expose this engine's registry at ``/metrics`` (Prometheus
         text), ``/metrics.json``, its :meth:`health` at ``/healthz``
-        (200 for ok/degraded, 503 otherwise — the TPU_RUNBOOK pre-flight
-        curl), and a fresh flight-recorder bundle at ``/debug/bundle``.
+        (200 for ok/degraded, 503 otherwise — the pre-flight curl), and a fresh flight-recorder bundle at ``/debug/bundle``.
         ``port=0`` binds an ephemeral port; read
         ``engine.metrics_server.port``. Stopped by :meth:`stop`."""
         if self.metrics_server is None:

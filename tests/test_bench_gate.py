@@ -40,7 +40,7 @@ def test_flatten_accepts_all_three_artifact_shapes():
     flat = bench_gate.flatten_metrics(raw)
     assert flat == {"knn_qps": 100.0, "knn_qps.recall": 0.98,
                     "ivf_flat.qps": 50.0, "ivf_flat.build_s": 2.0}
-    # the tpu_queue wrapper unwraps to the same thing
+    # the {"parsed": ...} wrapper unwraps to the same thing
     assert bench_gate.flatten_metrics({"parsed": raw}) == flat
     # a flat metrics document passes through
     assert bench_gate.flatten_metrics(

@@ -415,7 +415,12 @@ def test_map_shards_cancels_siblings_on_failure(monkeypatch):
 
 def test_workspace_shrink_same_results():
     """A 1 MiB workspace budget forces the tiled paths; results must not
-    change (acceptance: memory pressure degrades speed, never answers)."""
+    change (acceptance: memory pressure degrades speed, never answers).
+    Ids are compared exactly. Distances agree to a few ulp of the row's
+    distance scale, not bitwise: XLA:CPU rounds the tiled LUT contraction
+    in a shape-dependent order, and a near-zero ADC distance is a
+    difference of ~30-sized terms, so its error is absolute (atol), not
+    relative."""
     rng = np.random.default_rng(3)
     x = rng.standard_normal((2048, DIM)).astype(np.float32)
     q = x[:16] + 0.01 * rng.standard_normal((16, DIM)).astype(np.float32)
@@ -429,4 +434,5 @@ def test_workspace_shrink_same_results():
         assert res.workspace_limit_bytes == 1 << 20
         d1, i1 = ivf_pq.search(idx, q, 10, sp, res=res)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_allclose(np.asarray(d0), np.asarray(d1), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(d0), np.asarray(d1), rtol=1e-6,
+                               atol=1e-5)

@@ -436,9 +436,12 @@ def test_pq_bits5_end_to_end_both_engines(rng):
 def test_lut_probe_tiling_bit_identical(data):
     """A workspace too small to hold all probes at once forces the
     probe-tile loop (probe_tile < n_probes); the tiled scan must complete
-    and return bit-identical values/ids to the untiled single-tile run —
-    per-element contractions are unchanged, only the top-k merge order
-    differs."""
+    and return identical ids to the untiled single-tile run. Distances
+    agree to a few ulp, not bitwise: the LUT einsum runs at a different
+    batch shape per tile, and XLA:CPU blocks (hence rounds) the
+    contraction by shape, so the two paths cannot share one reduction
+    order without leaving the engine's matmul (measured: ≤ 2.1e-6
+    relative on jaxlib 0.9.0)."""
     from raft_tpu import Resources
 
     db, q = data
@@ -458,8 +461,8 @@ def test_lut_probe_tiling_bit_identical(data):
     assert probe_tile < n_probes, (q_tile, probe_tile)
     assert q_tile * probe_tile * per_qp <= tight.workspace_limit_bytes
     v1, i1 = ivf_pq.search(index, q, 10, sp, res=tight)
-    np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+    np.testing.assert_allclose(np.asarray(v0), np.asarray(v1), rtol=5e-6)
 
 
 def test_lut_probe_tiling_matches_cache_engine(data, gt):

@@ -11,7 +11,7 @@ from raft_tpu import native
 
 
 def test_native_builds():
-    assert native.ensure_built(), "g++ build of libraft_tpu_native.so failed"
+    assert native.ensure_built(), "g++ build of the native library failed"
     assert native.available()
 
 
@@ -244,3 +244,20 @@ def test_hnsw_cpu_engine_roundtrip(tmp_path, rng):
     assert abs(rec_c - rec_x) < 0.2
     with pytest.raises(ValueError, match="l2"):
         hnsw.search(ix, q, 5, engine="cpu", space="ip")
+
+
+def test_library_name_tracks_the_source_hash(tmp_path, monkeypatch):
+    # a copied tree never loads a binary its own source did not produce:
+    # the .so name carries the source hash, not an mtime
+    src = tmp_path / "a.cpp"
+    src.write_text("int x;")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    first = native.library_path()
+    assert first == native.library_path()
+    src.write_text("int y;")
+    second = native.library_path()
+    assert first != second
+    assert os.path.basename(second).startswith("libraft_tpu_native-")
+    monkeypatch.setattr(native, "_SRC", str(tmp_path / "missing.cpp"))
+    assert native.library_path() is None
+    assert native.ensure_built() is False

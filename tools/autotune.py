@@ -11,8 +11,8 @@ peaks are known), and writes ``PARETO_<platform>.json`` — the artifact
 spends latency budgets against (docs/tuning.md "Adaptive planning").
 
 Artifact discipline matches PALLAS_PROBE / SELECT_K_TABLE: schema tag
-(``raft_tpu.pareto/v1``), flat ``"metrics"`` mirror, refreshed by the
-tpu_queue2.sh ``autotune`` step, diffed curve-aware by
+(``raft_tpu.pareto/v1``), flat ``"metrics"`` mirror, refreshed by a
+chip run, diffed curve-aware by
 ``tools/bench_gate.py`` (frontier kind: hypervolume + per-recall-band
 QPS, never pointwise).
 

@@ -18,7 +18,7 @@ scheduler hiccup does not — so best-of-N kills the false-positive rate
 without hiding sustained losses. Pass repeats as extra positional
 files.
 
-Accepts the repo's bench artifact shapes: the ``tpu_queue`` wrapper
+Accepts the repo's bench artifact shapes: a wrapped run
 (``{"parsed": {...}}``), a raw bench.py stdout object
 (``{"metric", "value", "recall", "extra": {family: {...}}}``), a flat
 ``{"metrics": {name: value}}`` document, or a ``.log`` file whose last
@@ -40,7 +40,7 @@ Exit status: 0 all gated metrics flat/improved; 1 any ``regressed`` (or
 
 Typical use::
 
-    python tools/bench_gate.py BENCH_r05.json BENCH_r06.json
+    python tools/bench_gate.py BENCH_r03.json BENCH_r04.json
     python tools/bench_gate.py baseline.json run1.json run2.json run3.json
     python tools/bench_gate.py --tolerance 0.08 old.json new.json
 """
