@@ -86,9 +86,7 @@ def main():
 
     # which select algorithm the winning variant's scan actually used:
     # APPROX when the variant opted in via select_recall, else what AUTO
-    # resolves at the scan's true select width (db_tile, not n_db) —
-    # records whether a measured SELECT_K_TABLE artifact flipped the
-    # exact default (SCREEN vs DIRECT) in this run
+    # resolves at the scan's true select width (db_tile, not n_db)
     if chosen.get("select_recall", 1.0) < 1.0:
         sel_algo = "approx"
         k_pad = 0
@@ -102,7 +100,7 @@ def main():
             ensure_resources(None).workspace_limit_bytes)
         sel_algo = _resolve_auto(db_tile, k).value
         # whether a measured TOPK_PAD rule rewrote the requested k
-        k_pad = _pad_k(db_tile, k) if sel_algo in ("direct", "screen") else 0
+        k_pad = _pad_k(db_tile, k) if sel_algo == "direct" else 0
 
     row = {
         "metric": "brute_force_knn_qps_sift10k_k10",

@@ -1,7 +1,7 @@
 """Artifact consistency gate (``graftcheck --artifacts``, rule A001).
 
 The repo's committed JSON artifacts are load-bearing: the dispatch
-layer reads ``SELECT_K_TABLE_*``/``TOPK_PAD_*``/``PALLAS_PROBE_*`` at
+layer reads ``TOPK_PAD_*``/``PALLAS_PROBE_*`` at
 import time to pick engines, the adaptive planner reads ``PARETO_*``
 frontiers, and graftcheck itself reads ``graftcheck_baseline.json``.
 Each of those loaders was written against a schema that has already
@@ -14,9 +14,6 @@ would notice until a TPU session burned time rediscovering it.
 This module re-runs every committed ``*.json`` at the repo root through
 the loader that consumes it:
 
-- ``SELECT_K_TABLE_*`` → the crossover-table extractor
-  (``art["crossovers"]`` must be a dict, as ``select_k._load_auto_table``
-  reads it);
 - ``TOPK_PAD_*`` → the pad-rule extractor (``art["pad_rules"]``);
 - ``PALLAS_PROBE_*`` → the fused-verdict extractor plus
   ``tools/pallas_probe.missing_verdicts`` coverage over
@@ -67,22 +64,12 @@ def artifact_kind(name: str) -> str:
     if name == "graftcheck_baseline.json":
         return "baseline"
     for prefix, kind in (("PALLAS_PROBE_", "pallas_probe"),
-                         ("SELECT_K_TABLE_", "select_k_table"),
                          ("TOPK_PAD_", "topk_pad"),
                          ("PARETO_", "pareto"),
                          ("TIERED_MANIFEST_", "tiered_manifest")):
         if name.startswith(prefix):
             return kind
     return "json"
-
-
-def _check_select_k_table(art: dict, path: str) -> None:
-    # mirrors select_k._load_auto_table's extractor
-    crossovers = art["crossovers"]
-    if not isinstance(crossovers, dict) or not crossovers:
-        raise ValueError("'crossovers' must be a non-empty dict")
-    if "platform" not in art:
-        raise ValueError("missing 'platform' key (the scanner keys by it)")
 
 
 def _check_topk_pad(art: dict, path: str) -> None:
@@ -122,7 +109,6 @@ def _check_baseline(art: dict, path: str) -> None:
 
 
 _CHECKERS: Dict[str, Callable[[dict, str], None]] = {
-    "select_k_table": _check_select_k_table,
     "topk_pad": _check_topk_pad,
     "pareto": _check_pareto,
     "baseline": _check_baseline,

@@ -67,6 +67,11 @@ class Index:
         return self.dataset.shape[1]
 
 
+#: metrics whose expanded distances use the rows' squared norms
+NORM_METRICS = (DistanceType.L2Expanded, DistanceType.L2SqrtExpanded,
+                DistanceType.CosineExpanded)
+
+
 @tracing.range("brute_force.build")
 def build(dataset, metric="euclidean", metric_arg: float = 2.0,
           res: Optional[Resources] = None) -> Index:
@@ -75,11 +80,13 @@ def build(dataset, metric="euclidean", metric_arg: float = 2.0,
     ensure_resources(res)
     dataset = jnp.asarray(dataset)
     m = resolve_metric(metric)
-    norms = None
-    if m in (DistanceType.L2Expanded, DistanceType.L2SqrtExpanded,
-             DistanceType.CosineExpanded):
-        norms = row_norms_sq(dataset)
+    norms = row_norms_sq(dataset) if m in NORM_METRICS else None
     return Index(dataset, m, float(metric_arg), norms)
+
+
+#: rows per group of the exact scan's group minima: one 128-lane row, so
+#: a group the scan keeps is gathered whole
+GROUP = 128
 
 
 def _choose_tiles(n_queries: int, n_db: int, dim: int, k: int, budget: int
@@ -87,18 +94,18 @@ def _choose_tiles(n_queries: int, n_db: int, dim: int, k: int, budget: int
     """Pick (query_tile, db_tile) so the distance tile fits the workspace
     budget (analog of chooseTileSize, detail/knn_brute_force.cuh:84).
 
-    The budget pays for (a) one whole-dataset pad copy that stays live
-    across the scan (the tile reshape needs n_db rounded up to the tile)
-    and (b) ~5 concurrent fp32 tiles in the expanded-L2 chain
-    (dot, norm-add, clamp, mask-select, top-k negation) — the graftcheck
-    jaxpr audit certifies the resulting peak statically; the old solve
-    charged only 4 tiles and no pad copy and overshot by ~25%."""
+    The budget pays for ~5 concurrent fp32 tiles in the expanded-L2 chain
+    (dot, norm-add, clamp, mask-select, selection) — the graftcheck jaxpr
+    audit certifies the resulting peak statically. The scan makes no
+    padded copy of the database. A db tile is whole groups of ``GROUP``
+    rows unless it is the whole database, so that exact scans take the
+    group minima (``_scan_tiles``)."""
     q_tile = balanced_tile(n_queries, min(n_queries, 1024), 8)
-    pad_copy = n_db * dim * 4
-    avail = max(budget - pad_copy, budget // 4)
-    db_budget = max(avail // (5 * max(q_tile, 1) * 4), 1)
-    db_tile = min(n_db, max(db_budget, 4 * k, 1024))
-    return q_tile, balanced_tile(n_db, db_tile, 128)
+    db_budget = max(budget // (5 * max(q_tile, 1) * 4), 1)
+    db_tile = max(db_budget, 4 * k, 1024)
+    if db_tile >= n_db:
+        return q_tile, max(n_db, 1)
+    return q_tile, balanced_tile(n_db, db_tile - db_tile % GROUP, GROUP)
 
 
 #: public planner name — consumed by the graftcheck jaxpr audit, which
@@ -109,12 +116,12 @@ choose_tiles = _choose_tiles
 def planned_peak_bytes(n_queries: int, n_db: int, dim: int, k: int,
                        budget: int) -> int:
     """The peak live set ``choose_tiles`` believes its solve yields: the
-    whole-dataset pad copy plus the 5 concurrent fp32 distance tiles of
-    the expanded-L2 chain at the planned (q_tile, db_tile). Public so the
-    obs.costs calibration audit can compare this prediction against the
-    compiled ``memory_analysis`` ground truth at the same shape."""
+    5 concurrent fp32 distance tiles of the expanded-L2 chain at the
+    planned (q_tile, db_tile). Public so the obs.costs calibration audit
+    can compare this prediction against the compiled ``memory_analysis``
+    ground truth at the same shape."""
     q_tile, db_tile = _choose_tiles(n_queries, n_db, dim, k, budget)
-    return n_db * dim * 4 + 5 * q_tile * db_tile * 4
+    return 5 * q_tile * db_tile * 4
 
 
 #: metrics eligible for the bf16 fast-scan (their scan is one MXU matmul and
@@ -127,6 +134,114 @@ _FAST_SCAN_METRICS = (
 )
 
 
+def _scan_tiles(nq: int, n_db: int, db_tile: int, k: int, tile_dist,
+                select_min: bool, select_recall: float):
+    """The database-tile loop of every brute-force scan. ``tile_dist(start,
+    width)`` gives the [nq, width] distances of rows ``[start, start +
+    width)``; this runs it over whole tiles of ``db_tile`` rows and once
+    over the remainder. No padded copy of the database is made, and one
+    tile's distances are live at a time (the reference's
+    tiled_brute_force_knn, detail/knn_brute_force.cuh). Returns candidates
+    ``(values, row ids)`` [nq, m], m ≥ k when n_db ≥ k, holding the k best.
+
+    An exact scan whose tiles hold at least ``k`` groups of ``GROUP`` rows
+    takes ``_group_topk``, which never ranks a whole tile; the tiles must
+    be whole groups unless there is one, so that only the last tile pads
+    its last group, past every row. Otherwise each tile keeps its
+    ``min(k, width)`` best by ``select_k`` (APPROX below ``select_recall``
+    1) and the candidates are pooled in row order (the analog of
+    knn_merge_parts)."""
+    if (select_recall >= 1.0 and k * GROUP <= db_tile <= n_db
+            and (db_tile % GROUP == 0 or db_tile == n_db)):
+        return _group_topk(nq, n_db, db_tile, k, tile_dist, select_min)
+
+    def tile_topk(start, width):
+        v, i = select_k_maybe_approx(tile_dist(start, width), min(k, width),
+                                     select_min, select_recall)
+        return v, i + start
+
+    n_full, rem = divmod(n_db, db_tile)
+    vs, ids = [], []
+    if n_full:
+        tv, ti = jax.lax.map(lambda t: tile_topk(t * db_tile, db_tile),
+                             jnp.arange(n_full))
+        vs.append(jnp.moveaxis(tv, 0, 1).reshape(tv.shape[1], -1))
+        ids.append(jnp.moveaxis(ti, 0, 1).reshape(ti.shape[1], -1))
+    if rem:
+        v, i = tile_topk(n_full * db_tile, rem)
+        vs.append(v)
+        ids.append(i)
+    return jnp.concatenate(vs, axis=1), jnp.concatenate(ids, axis=1)
+
+
+def _group_topk(nq: int, n_db: int, db_tile: int, k: int, tile_dist,
+                select_min: bool):
+    """Exact top-k by group minima, carried across the tiles; the same
+    values and ids as one ``lax.top_k`` over the whole row, ties to the
+    lower row.
+
+    Rows fall in groups of ``GROUP``. The k groups of least minimum (ties
+    to the lower group) hold the k best rows: a row outside them is no
+    better than any of those k minima, which are k distinct rows ranked
+    ahead of it. The carry holds the k best groups so far (minimum, the
+    rows' distances, first row), in row order; each tile's group minima
+    compete with it in one top-k over width / GROUP + k values, and one
+    top-k over the k·GROUP kept distances ends the scan. Minima are taken
+    of ``-distance`` when selecting the largest: negation is exact."""
+    g = GROUP
+
+    def groups(start, width):
+        d = tile_dist(start, width)
+        d = d if select_min else -d
+        n_g = cdiv(width, g)
+        if n_g * g > width:  # the remainder: its pad lies past every row
+            d = jnp.pad(d, ((0, 0), (0, n_g * g - width)),
+                        constant_values=jnp.inf)
+        d = d.reshape(d.shape[0], n_g, g)
+        return d, d.min(axis=-1)
+
+    def take(rows, sel):
+        # the rows of groups ``sel`` [q, k] of ``rows`` [q, n_g, g]
+        return jnp.take_along_axis(rows, sel[..., None], axis=1)
+
+    def best(mins):
+        # the k least, ties to the lower position, in position order
+        return jnp.sort(jax.lax.top_k(-mins, k)[1], axis=1)
+
+    def merge(carry, start, width):
+        # The tiles go from the last down and the first carry's groups are
+        # empty ones past every row, so the tile's rows precede the
+        # carried ones: the minima concatenate in row order, and ties go to
+        # the lower row.
+        c_mins, c_rows, c_first = carry
+        rows, mins = groups(start, width)
+        n_t = mins.shape[1]
+        mins = jnp.concatenate([mins, c_mins], axis=1)
+        sel = best(mins)
+        in_tile = sel < n_t
+        ts, cs = jnp.minimum(sel, n_t - 1), jnp.maximum(sel - n_t, 0)
+        return (jnp.take_along_axis(mins, sel, 1),
+                jnp.where(in_tile[..., None], take(rows, ts),
+                          jnp.take_along_axis(c_rows, cs[..., None], 1)),
+                jnp.where(in_tile, start + ts * g,
+                          jnp.take_along_axis(c_first, cs, 1)))
+
+    carry = (jnp.full((nq, k), jnp.inf, jnp.float32),
+             jnp.full((nq, k, g), jnp.inf, jnp.float32),
+             jnp.full((nq, k), n_db, jnp.int32))
+    n_full, rem = divmod(n_db, db_tile)
+    if rem:
+        carry = merge(carry, n_full * db_tile, rem)
+    carry, _ = jax.lax.scan(
+        lambda c, t: (merge(c, t * db_tile, db_tile), None), carry,
+        jnp.arange(n_full - 1, -1, -1))
+    _, rows, first_row = carry
+    pos = (first_row[..., None] + jnp.arange(g, dtype=first_row.dtype)
+           ).reshape(nq, k * g)
+    v, sel = jax.lax.top_k(-rows.reshape(nq, k * g), k)
+    return (-v if select_min else v), jnp.take_along_axis(pos, sel, axis=1)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("metric", "metric_arg", "k", "q_tile", "db_tile",
@@ -136,41 +251,34 @@ _FAST_SCAN_METRICS = (
 def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
              q_tile, db_tile, budget, has_filter: bool = False,
              fast_scan: bool = False, refine_mult: int = 4,
-             select_recall: float = 1.0):
+             select_recall: float = 1.0, n_valid=None):
+    """Exact kNN core, tiled over queries and database rows. ``n_valid``
+    (may be traced) masks rows at or past it as padding: the last shard of
+    a row-sharded collection (``parallel.sharded.knn``)."""
     nq, dim = queries.shape
-    ndb = dataset.shape[0]
     minimize = is_min_close(metric)
 
     def _sel(vals, kk, sel_min):
         return select_k_maybe_approx(vals, kk, sel_min, select_recall)
-    use_cached_norms = db_norms is not None and metric in (
-        DistanceType.L2Expanded,
-        DistanceType.L2SqrtExpanded,
-        DistanceType.CosineExpanded,
-    )
+    use_cached_norms = db_norms is not None and metric in NORM_METRICS
 
-    n_db_tiles = cdiv(ndb, db_tile)
-    db_pad = n_db_tiles * db_tile - ndb
     n_q_tiles = cdiv(nq, q_tile)
     q_pad = n_q_tiles * q_tile - nq
 
     qp = jnp.pad(queries, ((0, q_pad), (0, 0)))
-    # Pad DB once; padded rows get +inf (or -inf for max-close) distances.
-    dbp = jnp.pad(dataset, ((0, db_pad), (0, 0)))
     need_norms = use_cached_norms or (
         fast_scan and metric != DistanceType.InnerProduct)
     if use_cached_norms:
-        dbn = jnp.pad(db_norms, (0, db_pad))
+        dbn = db_norms
     elif need_norms:
-        dbn = row_norms_sq(dbp)
+        dbn = row_norms_sq(dataset)
     else:
         dbn = None
-    pad_bad = jnp.arange(n_db_tiles * db_tile) >= ndb
     bad_fill = jnp.inf if minimize else -jnp.inf
     # Fast scan over-selects candidates; exact fp32 re-rank recovers them.
     k_scan = min(refine_mult * k, db_tile) if fast_scan else min(k, db_tile)
     # Refine pool must still hold >= k candidates when db_tile < k; the
-    # merged pool has n_db_tiles*k_scan >= k entries, so this never exceeds it.
+    # pooled tiles hold >= min(k_scan, n_db) >= k entries.
     k_refine = max(k_scan, k)
 
     def _filter_pass(ids):
@@ -178,14 +286,21 @@ def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
         words = filter_words[jnp.minimum(ids // 32, filter_words.shape[0] - 1)]
         return ((words >> (ids % 32).astype(jnp.uint32)) & 1).astype(bool)
 
+    def _bad_rows(ids):
+        """Rows that never answer: padding past ``n_valid`` and rows the
+        bitset filter clears (reference: bitset_filter,
+        sample_filter_types.hpp:55-82)."""
+        bad = jnp.zeros(ids.shape, bool) if n_valid is None else ids >= n_valid
+        return bad | ~_filter_pass(ids) if has_filter else bad
+
     def q_body(qt):
         # Query-tile norms hoisted out of the db-tile loop (analog of the
         # reference's rowNorm precompute, detail/knn_brute_force.cuh:97-136).
         qt_norms = row_norms_sq(qt) if need_norms else None
         qt_bf = qt.astype(jnp.bfloat16) if fast_scan else None
 
-        def db_body(t):
-            db_t = jax.lax.dynamic_slice_in_dim(dbp, t * db_tile, db_tile, 0)
+        def tile_dist(start, width):
+            db_t = jax.lax.dynamic_slice_in_dim(dataset, start, width, 0)
             if fast_scan:
                 # Single-pass bf16 MXU matmul (the TPU analog of the
                 # reference's TF32/CUTLASS fast path, dispatch_sm80.cuh):
@@ -199,16 +314,16 @@ def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
                     d = inner_product(qt_bf, db_bf)
                 elif metric == DistanceType.CosineExpanded:
                     dbn_t = jax.lax.dynamic_slice_in_dim(
-                        dbn, t * db_tile, db_tile, 0)
+                        dbn, start, width, 0)
                     d = cosine_expanded(qt_bf, db_bf, x_norms=qt_norms,
                                         y_norms=dbn_t)
                 else:
                     dbn_t = jax.lax.dynamic_slice_in_dim(
-                        dbn, t * db_tile, db_tile, 0)
+                        dbn, start, width, 0)
                     d = l2_expanded(qt_bf, db_bf, sqrt=False,
                                     x_norms=qt_norms, y_norms=dbn_t)
             elif use_cached_norms:
-                dbn_t = jax.lax.dynamic_slice_in_dim(dbn, t * db_tile, db_tile, 0)
+                dbn_t = jax.lax.dynamic_slice_in_dim(dbn, start, width, 0)
                 if metric == DistanceType.CosineExpanded:
                     d = cosine_expanded(qt, db_t, x_norms=qt_norms, y_norms=dbn_t)
                 else:
@@ -218,33 +333,23 @@ def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
                     )
             else:
                 d = pairwise_core(qt, db_t, metric, metric_arg, budget)
-            bad = jax.lax.dynamic_slice_in_dim(pad_bad, t * db_tile, db_tile, 0)
-            if has_filter:
-                # bitset prefilter in the tile epilogue (reference:
-                # bitset_filter, sample_filter_types.hpp:55-82)
-                bad = bad | ~_filter_pass(t * db_tile + jnp.arange(db_tile))
-            d = jnp.where(bad[None, :], bad_fill, d)
-            v, i = _sel(d, k_scan, minimize)
-            return v, i + t * db_tile
+            if n_valid is not None or has_filter:
+                bad = _bad_rows(start + jnp.arange(width))
+                d = jnp.where(bad[None, :], bad_fill, d)
+            return d
 
-        tile_v, tile_i = jax.lax.map(db_body, jnp.arange(n_db_tiles))
-        # Merge parts: concat candidates over tiles, re-select (the analog of
-        # knn_merge_parts' pairwise heap merge).
-        kk = tile_v.shape[-1]
-        all_v = jnp.moveaxis(tile_v, 0, 1).reshape(q_tile, n_db_tiles * kk)
-        all_i = jnp.moveaxis(tile_i, 0, 1).reshape(q_tile, n_db_tiles * kk)
+        all_v, all_i = _scan_tiles(q_tile, dataset.shape[0], db_tile, k_scan,
+                                   tile_dist, minimize, select_recall)
         if fast_scan:
             # Exact fp32 re-rank of the scanned candidates (reference analog:
             # neighbors::refine over a coarse candidate list).
             _, sel = _sel(all_v, min(k_refine, all_v.shape[-1]), minimize)
             cand_i = jnp.take_along_axis(all_i, sel, axis=1)
-            cand_vecs = jnp.take(dbp, cand_i, axis=0)  # [q_tile, k_ref, dim]
+            cand_vecs = jnp.take(dataset, cand_i, axis=0)  # [q_tile, k_ref, dim]
             exact = gathered_distances(qt, cand_vecs, metric)
             # Re-mask padded/filtered rows (their gathered distance is real).
-            bad_rows = jnp.take(pad_bad, cand_i)
-            if has_filter:
-                bad_rows = bad_rows | ~_filter_pass(cand_i)
-            exact = jnp.where(bad_rows, bad_fill, exact)
+            if n_valid is not None or has_filter:
+                exact = jnp.where(_bad_rows(cand_i), bad_fill, exact)
             v, sel2 = select_k(exact, k, select_min=minimize)
             return v, jnp.take_along_axis(cand_i, sel2, axis=1)
         v, sel = select_k(all_v, k, select_min=minimize)

@@ -118,7 +118,7 @@ def _fused_l2_argmin_pallas(x, y, x_norms, y_norms, tm: int, tn: int,
 # Pallas kernel is now a MEASURED decision recorded by tools/pallas_probe.py
 # into PALLAS_PROBE_<platform>.json ("fused" section, per-family
 # ``fused_wins`` verdicts). The artifact self-arms exactly like the
-# SELECT_K_TABLE / TOPK_PAD tables (repo root + cwd scan, env override
+# TOPK_PAD tables (repo root + cwd scan, env override
 # loaded last and loudly) so a hardware window's probe run flips the
 # dispatch for subsequent runs with no env plumbing.
 

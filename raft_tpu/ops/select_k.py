@@ -38,36 +38,26 @@ class SelectAlgo(enum.Enum):
     TWO_PHASE = "two_phase"  # per-tile top-k, then merge (wide rows)
     PALLAS = "pallas"  # streaming k-extraction kernel (small k, wide rows)
     APPROX = "approx"  # TPU PartialReduce (lax.approx_min_k), recall<1
-    SCREEN = "screen"  # exact: certified threshold + exhaustive extraction
 
 
 _TILE = 16384
 
 # ---------------------------------------------------------------- AUTO table
 #
-# AUTO picks DIRECT vs TWO_PHASE from a MEASURED per-platform crossover
-# table (VERDICT r2 #6: the old hardcoded 65536 was a guess): for each
-# k-band, the row width above which the tiled path wins. Produced by
-# ``tools/select_k_bench.py`` on the target backend (IVF-critical shapes:
-# batch 2048, k ∈ {10..256}, widths up to 512k — the reference's radix
-# vs warpsort decision space, detail/select_k-inl.cuh:48); override the
-# shipped tables with RAFT_TPU_SELECTK_TABLE=<artifact.json>. Platforms
-# without a measured table fall back to the "default" entry.
-#
-# Shipped CPU table measured on this image (SELECT_K_TABLE_cpu.json:
-# DIRECT won at every width ≤ 262144 and every k ≤ 256 — XLA:CPU's top_k
-# is already partial, so tiling only adds a merge pass). The "default"
-# (TPU et al) entry is provisional until tools/select_k_bench.py runs on
-# the chip.
+# AUTO picks DIRECT vs TWO_PHASE from a per-platform crossover table (the
+# reference's choose_select_k_algorithm, detail/select_k-inl.cuh:48): for
+# each k-band, the row width above which the tiled path wins, written here
+# by the PR that measured it (``tools/select_k_bench.py`` prints one).
+# Platforms without a table take the "default" entry.
 _NEVER = 1 << 62
 _BUILTIN_TABLES = {
     # k_max → min row width at which TWO_PHASE beats DIRECT
+    # XLA:CPU's top_k is already partial: tiling only adds a merge pass
     "cpu": {"inf": _NEVER},
-    # Measured on v5e 2026-07-31 (SELECT_K_TABLE_tpu.json, batch 2048,
-    # widths 4096-131072, k 10-256): DIRECT won everywhere except
-    # k=256 at width >= 131072, where TWO_PHASE's flat ~175 ms beats
-    # DIRECT's k-linear growth (208 ms). APPROX is 10-40x faster still
-    # but is opt-in via search params (recall < 1).
+    # v5e, batch 2048, widths 4096-131072, k 10-256: DIRECT won everywhere
+    # but k=256 at width >= 131072 (TWO_PHASE's flat ~175 ms against
+    # DIRECT's 208 ms). Exact scans over wider rows never rank a whole
+    # tile (brute_force's group minima).
     "tpu": {"128": _NEVER, "256": 131072, "inf": 131072},
     "default": {"32": 65536, "256": 65536, "inf": 131072},
 }
@@ -77,12 +67,10 @@ _auto_table_cache: Optional[dict] = None
 def _scan_artifacts(tables: dict, prefix: str, env_var: str, extract):
     """Fill ``tables`` (platform -> table) from measured artifacts:
     ``<prefix>_*.json`` at the repo root (anchored via __file__, so the
-    choice can't depend on launch directory) and in cwd — these self-arm
-    with no env plumbing (the benchmark queue drops them during a
-    hardware window; the driver's bench.py run then picks the measured
-    behavior). Malformed ambient artifacts are skipped; the ``env_var``
-    override is loaded LAST and OUTSIDE the try (explicit requests fail
-    loudly and win over ambient artifacts)."""
+    choice can't depend on launch directory) and in cwd. Malformed ambient
+    artifacts are skipped; the ``env_var`` override is loaded LAST and
+    OUTSIDE the try (explicit requests fail loudly and win over ambient
+    artifacts)."""
     import glob
 
     repo_root = os.path.dirname(os.path.dirname(
@@ -108,9 +96,7 @@ def _scan_artifacts(tables: dict, prefix: str, env_var: str, extract):
 def _load_auto_table() -> dict:
     global _auto_table_cache
     if _auto_table_cache is None:
-        _auto_table_cache = _scan_artifacts(
-            dict(_BUILTIN_TABLES), "SELECT_K_TABLE",
-            "RAFT_TPU_SELECTK_TABLE", lambda art: art["crossovers"])
+        _auto_table_cache = dict(_BUILTIN_TABLES)
     return _auto_table_cache
 
 
@@ -221,21 +207,12 @@ def _pad_k(n: int, k: int) -> int:
     return min(n, best[1]) if best else k
 
 
-def _resolve_auto(n: int, k: int, floating: bool = True) -> "SelectAlgo":
+def _resolve_auto(n: int, k: int) -> "SelectAlgo":
     tables = _load_auto_table()
     table = tables.get(_platform_key(), tables["default"])
-    # nested form: {"two_phase": {k-bands}, "screen": {k-bands}};
-    # flat {k-bands} = two_phase-only (pre-r4 artifacts)
-    nested = "screen" in table or "two_phase" in table
-    screen_tab = table.get("screen")
-    tp_tab = table.get("two_phase", {}) if nested else table
     if k * 4 > n:
         return SelectAlgo.DIRECT
-    if screen_tab and floating:
-        band = _band(screen_tab, k)
-        if band is not None and n >= band:
-            return SelectAlgo.SCREEN
-    band = _band(tp_tab, k)
+    band = _band(table, k)
     if band is None or n < band:
         return SelectAlgo.DIRECT
     return SelectAlgo.TWO_PHASE
@@ -266,71 +243,6 @@ def _approx(values: jax.Array, k: int, select_min: bool,
     back sorted like DIRECT's."""
     fn = jax.lax.approx_min_k if select_min else jax.lax.approx_max_k
     return fn(values, k, recall_target=recall_target)
-
-
-def _screen(values: jax.Array, k: int, select_min: bool, k_pad: int = 0):
-    """Exact selection via a certified threshold + exhaustive extraction —
-    the TPU answer to the reference's one-pass radix select
-    (detail/select_radix.cuh:54-67). lax.top_k on TPU runs at a few GB/s
-    effective at IVF shapes (SELECT_K_TABLE_tpu.json: 112 ms for
-    [2048, 4096] k=10 on v5e) because it sorts; this path replaces the
-    sort over the full width with memory-bound passes plus a tiny sort:
-
-    1. τ := kth-smallest of ``lax.approx_min_k(x, m)``'s output, m ≈ 2k.
-       The approx result is m actual elements at distinct positions, and
-       the kth order statistic of ANY k+ distinct elements is ≥ the row's
-       true kth value — so τ ≥ τ* holds REGARDLESS of approx recall; the
-       PartialReduce only has to be fast, never right.
-    2. mask := x ≤ τ (⊇ the true top-k since every winner is ≤ τ* ≤ τ);
-       candidate positions recovered exhaustively from cumsum(mask) by
-       binary search (first index where the running count reaches j) —
-       log₂(n) vectorized gathers, no scatter (TPU scatter serializes).
-    3. The ≤ m_buf survivors get one stable [batch, m_buf] sort (ties
-       break by position, matching top_k) and a [:, :k] slice.
-
-    Rows where count(x ≤ τ) overflows m_buf (heavy value ties, or rows of
-    pure +inf padding) divert the WHOLE batch to DIRECT via lax.cond —
-    exactness never depends on the screen being tight. Expected count is
-    ~k/recall ≈ 1.05k, so m_buf = 2k+64 makes the fallback a rare-tail
-    event on real distance data.
-    """
-    if not select_min:
-        v, i = _screen(-values, k, True, k_pad)
-        return -v, i
-    x = values
-    batch, n = x.shape
-    m = min(n, max(2 * k, k + 16))
-    m_buf = min(n, max(2 * k + 64, m))
-    # Never-selectable entries (+inf IVF pad tails / bitset-filtered
-    # candidates, NaN — but NOT -inf, which min-selection must keep) are
-    # clamped to finfo.max for the threshold pass: a row whose valid
-    # candidates are sparse but still ≥ k then gets a FINITE certified τ
-    # and takes the fast path — with τ = +inf such rows would divert the
-    # whole batch to DIRECT on every call (e.g. under a 95%-removed
-    # filter). Only rows with fewer than k selectable values (τ = FMAX)
-    # or a pathological approx miss still hit the fallback.
-    fmax = jnp.asarray(jnp.finfo(x.dtype).max, x.dtype)
-    xc = jnp.where(x <= fmax, x, fmax)  # False for +inf and NaN only
-    av, _ = jax.lax.approx_min_k(xc, m)  # sorted ascending, distinct pos
-    tau = av[:, k - 1]
-    mask = xc <= tau[:, None]
-    cs = jnp.cumsum(mask.astype(jnp.int32), axis=1)
-    c = cs[:, -1]
-
-    def extract(_):
-        targets = jnp.arange(1, m_buf + 1, dtype=cs.dtype)
-        pos = jax.vmap(
-            lambda row: jnp.searchsorted(row, targets, side="left"))(cs)
-        posc = jnp.minimum(pos, n - 1).astype(jnp.int32)
-        vals = jnp.take_along_axis(x, posc, axis=1)
-        valid = targets[None, :] <= c[:, None]
-        vals = jnp.where(valid, vals, jnp.inf)
-        sv, si = jax.lax.sort((vals, posc), dimension=1, is_stable=True,
-                              num_keys=1)
-        return sv[:, :k], si[:, :k]
-
-    return jax.lax.cond(jnp.all(c <= m_buf), extract,
-                        lambda _: _direct(x, k, True, k_pad), operand=None)
 
 
 def _two_phase(values: jax.Array, k: int, select_min: bool):
@@ -366,11 +278,6 @@ def _select_k_jit(values, k, select_min, algo, recall=0.95, k_pad=0):
                                interpret=_platform_key() != "tpu")
     if algo == SelectAlgo.APPROX:
         return _approx(values, k, select_min, recall)
-    if algo == SelectAlgo.SCREEN:
-        # int rows can't ride approx_min_k / inf-padding; they take DIRECT
-        if jnp.issubdtype(values.dtype, jnp.floating):
-            return _screen(values, k, select_min, k_pad)
-        return _direct(values, k, select_min, k_pad)
     if algo == SelectAlgo.DIRECT:
         return _direct(values, k, select_min, k_pad)
     return _two_phase(values, k, select_min)
@@ -417,16 +324,15 @@ def select_k(
         raise ValueError(f"k={k} > row length {values.shape[-1]}")
     if algo == SelectAlgo.AUTO:
         # Resolve BEFORE the jit boundary: the concrete algo is the compile
-        # key, so later set_auto_table()/RAFT_TPU_SELECTK_TABLE changes
-        # apply to fresh calls instead of being baked into a cached AUTO
-        # trace. (AUTO never picks PALLAS — its extraction is O(k) serial
-        # rounds, wrong for the IVF k=64-256 band.)
-        algo = _resolve_auto(values.shape[-1], int(k),
-                             jnp.issubdtype(values.dtype, jnp.floating))
+        # key, so later set_auto_table() changes apply to fresh calls
+        # instead of being baked into a cached AUTO trace. (AUTO never
+        # picks PALLAS — its extraction is O(k) serial rounds, wrong for
+        # the IVF k=64-256 band.)
+        algo = _resolve_auto(values.shape[-1], int(k))
     # pad rules resolve pre-jit too: the padded k is part of the compile
     # key, so installing/dropping TOPK_PAD rules retraces fresh calls
-    k_pad = _pad_k(values.shape[-1], int(k)) if pad_rules and algo in (
-        SelectAlgo.DIRECT, SelectAlgo.SCREEN) else 0
+    k_pad = _pad_k(values.shape[-1], int(k)) if (
+        pad_rules and algo == SelectAlgo.DIRECT) else 0
     # capture-only explain note: this body runs at TRACE time inside the
     # jitted search cores (once per compiled shape, not per call), so it
     # attaches the resolved algo/pad to the active explain capture but
@@ -486,16 +392,15 @@ def select_k_filtered(
     return v, i, n_filtered
 
 
-def select_k_plan(n: int, k: int, floating: bool = True,
-                  pad_rules: bool = True) -> dict:
-    """The resolution ``select_k`` would make for a [*, n] float/int row at
-    this k, WITHOUT running it: ``{"algo", "k_pad"}`` from the measured
+def select_k_plan(n: int, k: int, pad_rules: bool = True) -> dict:
+    """The resolution ``select_k`` would make for a [*, n] row at this k,
+    WITHOUT running it: ``{"algo", "k_pad"}`` from the measured
     AUTO table and TOPK_PAD rules. The dry-run surface ``tools/explain.py``
     prints so an operator can see the selection plan of a query shape
     before paying a compile."""
-    algo = _resolve_auto(int(n), int(k), bool(floating))
-    k_pad = _pad_k(int(n), int(k)) if pad_rules and algo in (
-        SelectAlgo.DIRECT, SelectAlgo.SCREEN) else 0
+    algo = _resolve_auto(int(n), int(k))
+    k_pad = _pad_k(int(n), int(k)) if (
+        pad_rules and algo == SelectAlgo.DIRECT) else 0
     return {"algo": algo.name, "k_pad": int(k_pad)}
 
 

@@ -208,8 +208,7 @@ class Comms:
     # tree, neighbor ring — produces the identical replicated output, and
     # that output is bit-identical to ``select_k(allgather(v), k)`` + id
     # gather (select_k's engines are all position-stable on ties: DIRECT
-    # is lax.top_k, TWO_PHASE merges tile-ordered survivors, SCREEN sorts
-    # (value, pos) stably). Peak cross-chip bytes drop from S·nq·kk to
+    # is lax.top_k, TWO_PHASE merges tile-ordered survivors). Peak cross-chip bytes drop from S·nq·kk to
     # nq·k·log₂S (tree) / nq·kk per step (ring).
 
     def tree_topk_merge(self, v, i, k: int, select_min: bool = True):

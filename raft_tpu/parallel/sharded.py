@@ -35,7 +35,8 @@ from raft_tpu.core.resources import Resources, ensure_resources
 from raft_tpu.obs import explain as obs_explain
 from raft_tpu.obs import metrics as obs_metrics
 from raft_tpu.obs import spans as obs_spans
-from raft_tpu.ops.distance import DistanceType, resolve_metric, pairwise_core
+from raft_tpu.ops.distance import (DistanceType, pairwise_core,
+                                   resolve_metric, row_norms_sq)
 from raft_tpu.ops.select_k import refine_multiplier, select_k
 from raft_tpu.parallel.comms import Comms
 from raft_tpu.utils.shape import cdiv
@@ -402,9 +403,11 @@ _PLAN_SOLVES = obs_metrics.REGISTRY.counter(
 
 
 def plan_cache_clear() -> None:
-    """Test hook: drop every cached PlacementPlan."""
+    """Test hook: drop every cached PlacementPlan (and the programs built
+    from them)."""
     with _PLAN_LOCK:
         _PLAN_CACHE.clear()
+    _knn_program.cache_clear()
 
 
 def merge_dispatch_explained(merge_mode: str, size: int):
@@ -548,19 +551,26 @@ def knn(
     """Exact kNN over a row-sharded dataset: local brute force per shard +
     ICI merge (the SPMD analog of MNMG brute_force over raft::comms).
 
+    Each chip scans its shard with ``brute_force``'s tiled core: only one
+    [queries, tile] distance block is live at a time, and each tile's
+    top-k is ``select_k``'s exact AUTO choice for the tile's width.
+
     ``dataset`` may already be sharded over ``comms.axis``; otherwise it is
     placed with row sharding here. ``merge_mode`` picks the cross-chip
     top-k merge (docs/sharding.md): "auto" routes the streaming tree/ring
     ladder, "allgather" the legacy full-slab merge — all bit-identical.
     Returns replicated (distances, indices) with global row ids.
     """
+    from raft_tpu.neighbors import brute_force
+
     _SHARDED_SEARCHES.labels("brute_force").inc()
-    ensure_resources(res)
+    res = ensure_resources(res)
     m = resolve_metric(metric)
     minimize = m != DistanceType.InnerProduct
     queries = jnp.asarray(queries)
     dataset = jnp.asarray(dataset)
     n, dim = dataset.shape
+    nq = queries.shape[0]
     size = comms.size
     shard = cdiv(n, size)
     n_pad = shard * size
@@ -570,37 +580,61 @@ def knn(
     q = comms.shard(queries, P(None, None))
 
     kk = min(k, shard)
-
-    def local_scan(q_rep, x_loc):
-        rank = comms.rank()
-        base = rank * shard
-        d = pairwise_core(q_rep, x_loc, m, 2.0, 1 << 30)
-        # mask padding rows of the last shard
-        local_ids = jnp.arange(shard) + base
-        d = jnp.where(local_ids[None, :] < n, d,
-                      jnp.inf if minimize else -jnp.inf)
-        v, i = select_k(d, kk, select_min=minimize)
-        gids = (i + base).astype(jnp.int32)
-        return v, gids
-
-    in_specs = (P(None, None), P(comms.axis, None))
+    budget = res.workspace_limit_bytes
+    q_tile, db_tile = brute_force.choose_tiles(nq, shard, dim, kk, budget)
     sink = _span_sink()
     if sink is not None:
         return _instrumented_search(
-            comms, local_scan, in_specs, (q, x), "brute_force",
-            queries.shape[0], min(k, size * kk), minimize, sink)
+            comms, _knn_local_scan(comms, m, n, shard, kk, q_tile, db_tile,
+                                   budget),
+            (P(None, None), P(comms.axis, None)), (q, x), "brute_force",
+            nq, min(k, size * kk), minimize, sink)
 
     plan = plan_sharded_search(
         comms, "brute_force", n, tuple(range(0, n_pad + 1, shard)),
-        queries.shape[0], k, kk, "xla", merge_mode=merge_mode)
+        nq, k, kk, "xla", merge_mode=merge_mode,
+        tiles={"q_tile": q_tile, "db_tile": db_tile})
     _record_plan(plan, merge_mode, {"metric": m.name})
+    return _knn_program(comms, plan, m, budget)(q, x)
+
+
+def _knn_local_scan(comms: Comms, metric: DistanceType, n: int, shard: int,
+                    kk: int, q_tile: int, db_tile: int, budget: int):
+    """Each chip's part of ``knn``: ``brute_force``'s tiled exact core over
+    its shard, rows past ``n`` (the last shard's padding) masked, ids made
+    global → the shard's ``kk`` best (values, ids)."""
+    from raft_tpu.neighbors import brute_force
+
+    def local_scan(q_rep, x_loc):
+        base = comms.rank() * shard
+        # the shard's norms once, as brute_force.build keeps them: a norm
+        # fused into each tile's distances may round by the tile's shape
+        norms = (row_norms_sq(x_loc) if metric in brute_force.NORM_METRICS
+                 else None)
+        v, i = brute_force.knn_core(
+            q_rep, x_loc, norms, jnp.zeros((0,), jnp.uint32), metric, 2.0,
+            kk, q_tile, db_tile, budget, n_valid=n - base)
+        return v, (i + base).astype(jnp.int32)
+
+    return local_scan
+
+
+@functools.lru_cache(maxsize=64)
+def _knn_program(comms: Comms, plan: PlacementPlan, metric: DistanceType,
+                 budget: int):
+    """The jitted SPMD program of one ``knn`` plan (local scan + merge):
+    calls with the same comms and plan reuse it, so a repeated shape
+    compiles once."""
+    tiles = dict(plan.tiles)
+    scan = _knn_local_scan(comms, metric, plan.n_rows, plan.bounds[1],
+                           plan.kk, tiles["q_tile"], tiles["db_tile"], budget)
+    minimize = metric != DistanceType.InnerProduct
 
     def local(q_rep, x_loc):
-        v, gids = local_scan(q_rep, x_loc)
-        return _plan_merge(comms, plan, v, gids, minimize)
+        return _plan_merge(comms, plan, *scan(q_rep, x_loc), minimize)
 
-    fn = comms.run(local, in_specs, (P(None, None), P(None, None)))
-    return jax.jit(fn)(q, x)
+    return jax.jit(comms.run(local, (P(None, None), P(comms.axis, None)),
+                             (P(None, None), P(None, None))))
 
 
 # ---------------------------------------------- sharded pairwise distance

@@ -130,17 +130,14 @@ def test_comms_t_surface():
 
 
 def test_round4_surface_names():
-    """Round-4 additions stay public: SCREEN select, sharded
-    checkpoint/resume, the native hnsw-role ef-search, config scaling."""
+    """Round-4 additions stay public: sharded checkpoint/resume, the
+    native hnsw-role ef-search, config scaling."""
     from raft_tpu.bench.runner import scale_config  # noqa: F401
     from raft_tpu.native import graph_greedy_search  # noqa: F401
-    from raft_tpu.ops.select_k import SelectAlgo
     from raft_tpu.parallel.sharded import (  # noqa: F401
         deserialize_ivf_flat, deserialize_ivf_pq, serialize_ivf_flat,
         serialize_ivf_pq)
     from raft_tpu.utils.shape import as_query_array  # noqa: F401
-
-    assert SelectAlgo.SCREEN.value == "screen"
 
 
 def test_imports_are_deprecation_clean():

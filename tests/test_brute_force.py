@@ -191,3 +191,51 @@ def test_choose_tiles_balanced():
             (n_db, db_tile, n_tiles)
         if n_tiles > 1:
             assert db_tile % 128 == 0
+
+
+@pytest.mark.parametrize("n,nq,k,db_tile,q_tile,case", [
+    (20_000, 16, 10, 2_048, 16, "plain"),
+    (30_000, 16, 64, 8_192, 16, "plain"),
+    (20_000, 16, 10, 2_048, 16, "sparse_filter"),
+    (20_000, 16, 10, 2_048, 16, "starved_filter"),
+    (20_000, 16, 32, 4_096, 16, "duplicate_rows"),
+    (20_000, 16, 10, 2_048, 16, "inner_product"),
+    (20_000, 20, 10, 2_048, 8, "query_tiles"),
+], ids=["k10", "k64", "sparse_filter", "starved_filter", "duplicate_rows",
+        "inner_product", "query_tiles"])
+def test_group_scan_matches_direct(monkeypatch, n, nq, k, db_tile, q_tile,
+                                   case):
+    """The group-minima scan (``_group_topk``) answers exactly as the
+    per-tile DIRECT top-k it replaces: the same ids, ties to the lower row,
+    the same float32 bits. Both run on the same tiles, so XLA:CPU rounds
+    the distances alike."""
+    import jax
+
+    from raft_tpu.core.bitset import Bitset
+
+    rng = np.random.default_rng(n + k)
+    db = rng.standard_normal((n, 8)).astype(np.float32)
+    if case == "duplicate_rows":
+        db = db[:300][rng.integers(0, 300, n)]
+    q = rng.standard_normal((nq, 8)).astype(np.float32)
+    metric = "inner_product" if case == "inner_product" else "sqeuclidean"
+    flt = None
+    if case.endswith("filter"):
+        keep = rng.random(n) < (0.05 if case == "sparse_filter" else 0.0)
+        keep[[17, 4_000, 19_999]] = True  # 3 rows pass a starved filter
+        flt = Bitset.from_mask(keep)
+    index = brute_force.build(db, metric=metric)
+    monkeypatch.setattr(brute_force, "_choose_tiles",
+                        lambda *a: (q_tile, db_tile))
+    traced = []
+    group_topk = brute_force._group_topk
+    monkeypatch.setattr(brute_force, "_group_topk",
+                        lambda *a: traced.append(a[1:4]) or group_topk(*a))
+    jax.clear_caches()
+    d, i = brute_force.search(index, q, k, filter=flt)
+    assert traced == [(n, db_tile, k)]
+    monkeypatch.setattr(brute_force, "GROUP", 1 << 30)  # per-tile DIRECT
+    jax.clear_caches()
+    want_d, want_i = brute_force.search(index, q, k, filter=flt)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(want_d))

@@ -32,6 +32,9 @@ PQ_DIM, PQ_LEN, BOOK = 64, 2, 256
 DEGREE, ITOPK = 32, 64
 #: device memory of one v5e chip
 HBM_BYTES = 16 << 30
+#: ``memory_stats()["bytes_limit"]`` of one v5e chip, of which
+#: ``Resources`` plans tiles in a quarter
+V5E_BYTES_LIMIT = 16_909_336_064
 
 
 @pytest.fixture(scope="module")
@@ -128,3 +131,29 @@ def test_ring_shift_compiles_on_a_2x2_mesh(topo):
     block = jax.ShapeDtypeStruct((3, 4 * NQ, K), jnp.float32,
                                  sharding=NamedSharding(mesh, P(None, "x")))
     _compile(ring, block)
+
+
+def test_sharded_knn_temp_fits_at_deep100m_shard(topo):
+    """``parallel.sharded.knn`` at the deep100m-exact cell's shape (1000
+    queries, 12.5M × 96 rows over a 2x2 mesh, k=100), with the tiles a
+    v5e's default ``Resources`` plans: the local scan is tiled, so each
+    chip's workspace stays a few tiles, not the [1000, 3.125M] distance
+    matrix (12.5 GB) the full-row scan needed."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from raft_tpu import Resources
+    from raft_tpu.parallel import comms as comms_mod
+    from raft_tpu.parallel import sharded
+
+    comms = comms_mod.init_comms(list(topo.devices), axis="data")
+    q = jax.ShapeDtypeStruct((1000, 96), jnp.float32,
+                             sharding=NamedSharding(comms.mesh, P()))
+    x = jax.ShapeDtypeStruct((12_500_000, 96), jnp.float32,
+                             sharding=NamedSharding(comms.mesh,
+                                                    P("data", None)))
+    res = Resources(workspace_limit_bytes=int(V5E_BYTES_LIMIT * 0.25))
+    compiled = jax.jit(lambda q, x: sharded.knn(comms, q, x, 100, res=res)
+                       ).lower(q, x).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 2 << 30, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= HBM_BYTES

@@ -37,8 +37,13 @@ def test_fails_alone_in_a_directory(tmp_path):
 
 @pytest.fixture()
 def smoke(monkeypatch):
+    from raft_tpu.core import resources
+
     monkeypatch.setenv("RAFT_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.syspath_prepend(str(REPO))
+    # the builds draw their keys from the process's default Resources: a
+    # fresh one, as in chip_smoke's own process, whatever ran before here
+    monkeypatch.setattr(resources, "_default_resources", None)
     import chip_smoke
 
     return chip_smoke

@@ -309,11 +309,11 @@ def test_artifacts_gate_reports_a_stale_pre_v3_probe(tmp_path):
 
 
 def test_artifacts_gate_flags_a_loader_rejected_table(tmp_path):
-    (tmp_path / "SELECT_K_TABLE_x.json").write_text(
-        json.dumps({"platform": "x", "crossovers": []}))
+    (tmp_path / "TOPK_PAD_x.json").write_text(
+        json.dumps({"platform": "x", "pad_rules": [{"n": 4096, "k": 10}]}))
     findings, _ = run_artifacts(str(tmp_path))
     rules = sorted({(f.rule, f.file) for f in findings})
-    assert ("A001", "SELECT_K_TABLE_x.json") in rules
+    assert ("A001", "TOPK_PAD_x.json") in rules
 
 
 def test_artifacts_gate_flags_unparseable_json(tmp_path):

@@ -79,6 +79,92 @@ def test_sharded_knn_unpadded_rows(comms):
     assert float(neighborhood_recall(np.asarray(i), np.asarray(i_ref))) >= 0.999
 
 
+@pytest.fixture(scope="module")
+def comms4():
+    return comms_mod.init_comms(jax.devices()[:4], axis="data")
+
+
+def _direct_reference(q, x, n_shards, k, db_tile, metric):
+    """The full-row DIRECT answer over the row-sharded collection: each
+    shard's distances in the scan's own tiles, from the shard's norms
+    (XLA:CPU rounds a dot by its shape, so the same tiles give the same
+    bits), padding rows masked, one ``lax.top_k`` over the whole row."""
+    from raft_tpu.ops.distance import (l2_expanded, pairwise_core,
+                                       resolve_metric, row_norms_sq)
+    from raft_tpu.ops.select_k import SelectAlgo, select_k
+    from raft_tpu.utils.shape import cdiv
+
+    m = resolve_metric(metric)
+    minimize = m != sharded.DistanceType.InnerProduct
+    n = x.shape[0]
+    shard = cdiv(n, n_shards)
+    xp = np.concatenate([x, np.zeros((shard * n_shards - n, x.shape[1]),
+                                     x.dtype)])
+    if minimize:
+        norms = [row_norms_sq(xp[r * shard:(r + 1) * shard])
+                 for r in range(n_shards)]
+        dist = jax.jit(lambda a, b, bn: l2_expanded(
+            a, b, False, x_norms=row_norms_sq(a), y_norms=bn))
+    else:
+        norms = [np.zeros(shard, np.float32)] * n_shards
+        dist = jax.jit(lambda a, b, bn: pairwise_core(a, b, m, 2.0, 1 << 30))
+    row = np.concatenate([
+        np.asarray(dist(q, xp[r * shard + s:r * shard + min(s + db_tile,
+                                                             shard)],
+                        norms[r][s:s + db_tile]))
+        for r in range(n_shards) for s in range(0, shard, db_tile)], axis=1)
+    row[:, n:] = np.inf if minimize else -np.inf
+    v, i = select_k(row, k, select_min=minimize, algo=SelectAlgo.DIRECT)
+    return np.asarray(v), np.asarray(i)
+
+
+@pytest.mark.parametrize("n,k,db_tile,metric,dup", [
+    (80_000, 10, 6_016, "sqeuclidean", False),
+    (79_990, 10, 6_016, "sqeuclidean", False),
+    (12_000, 300, 256, "sqeuclidean", False),
+    (120_000, 100, 12_928, "sqeuclidean", False),
+    (80_000, 50, 6_400, "sqeuclidean", True),
+    (80_000, 10, 6_016, "inner_product", False),
+], ids=["partial_last_tile", "padded_last_shard", "k_over_tile",
+        "k100_groups", "duplicate_rows", "inner_product"])
+def test_sharded_knn_local_scan_matches_direct(comms4, monkeypatch, n, k,
+                                               db_tile, metric, dup):
+    """The tiled local scan answers exactly as a DIRECT top-k over the
+    whole row: the same ids (ties to the lower row) and the same float32
+    bits, on four virtual devices."""
+    rng = np.random.default_rng(n + k)
+    if dup:
+        x = rng.standard_normal((300, 8)).astype(np.float32)[
+            rng.integers(0, 300, n)]
+    else:
+        x = rng.standard_normal((n, 8)).astype(np.float32)
+    q = rng.standard_normal((16, 8)).astype(np.float32)
+    monkeypatch.setattr(brute_force, "choose_tiles",
+                        lambda nq, *a: (nq, db_tile))
+    d, i = sharded.knn(comms4, q, x, k, metric=metric)
+    want_d, want_i = _direct_reference(q, x, 4, k, db_tile, metric)
+    np.testing.assert_array_equal(np.asarray(i), want_i)
+    np.testing.assert_array_equal(np.asarray(d), want_d)
+
+
+def test_sharded_knn_compiles_once_per_shape(comms4):
+    from raft_tpu.obs import device as obs_device
+
+    rng = np.random.default_rng(5)
+    # placed first, so that only the search itself can compile
+    x = comms4.shard(rng.standard_normal((4_008, 8)).astype(np.float32),
+                     P("data", None))
+    q = comms4.shard(rng.standard_normal((24, 8)).astype(np.float32),
+                     P(None, None))
+    sharded.ensure_resources(None)  # the default resources' own compiles
+    before = obs_device.compile_count()
+    outs = [sharded.knn(comms4, q, x, 7) for _ in range(3)]
+    jax.block_until_ready(outs)
+    assert obs_device.compile_count() - before == 1
+    np.testing.assert_array_equal(np.asarray(outs[0][1]),
+                                  np.asarray(outs[2][1]))
+
+
 def test_sharded_kmeans(comms):
     rng = np.random.default_rng(2)
     centers = rng.standard_normal((8, 16)) * 10

@@ -14,8 +14,7 @@ def _ref_select(values, k, select_min):
 
 
 @pytest.mark.parametrize(
-    "algo", [SelectAlgo.DIRECT, SelectAlgo.TWO_PHASE, SelectAlgo.SCREEN,
-             SelectAlgo.AUTO])
+    "algo", [SelectAlgo.DIRECT, SelectAlgo.TWO_PHASE, SelectAlgo.AUTO])
 @pytest.mark.parametrize(
     "shape,k",
     [((4, 100), 10), ((1, 17), 17), ((7, 2048), 256), ((3, 100000), 64)])
@@ -147,88 +146,19 @@ def test_auto_uses_measured_table():
         sk.set_auto_table("cpu", {"inf": sk._NEVER})
 
 
-def test_screen_exact_values_and_indices(rng):
-    """SCREEN is exact (values identical to a full sort) regardless of the
-    approx threshold's recall — the τ certificate only needs k distinct
-    elements (select_k.py _screen; reference bar: select_radix.cuh:54-67)."""
-    for (b, n, k) in [(7, 500, 10), (4, 4096, 64), (3, 32768, 256)]:
-        x = rng.standard_normal((b, n)).astype(np.float32)
-        v, i = select_k(x, k, algo=SelectAlgo.SCREEN)
-        v, i = np.asarray(v), np.asarray(i)
-        np.testing.assert_array_equal(v, np.sort(x, axis=1)[:, :k])
-        np.testing.assert_array_equal(np.take_along_axis(x, i, 1), v)
-        assert all(len(set(r)) == k for r in i)
-
-
-def test_screen_ties_and_inf_padding(rng):
-    # heavy ties overflow the candidate buffer -> certified lax.cond
-    # fallback to DIRECT; result must still be exact. 128 copies of 16
-    # distinct values, k=20: count(x <= tau) >= 128 > m_buf = 104, so
-    # the extract path CANNOT run — this pins the fallback branch.
-    x = np.repeat(rng.standard_normal((3, 16)).astype(np.float32), 128,
-                  axis=1)
-    v, _ = select_k(x, 20, algo=SelectAlgo.SCREEN)
-    np.testing.assert_array_equal(np.asarray(v), np.sort(x, 1)[:, :20])
-
-    # IVF pad convention: +inf tails, including an all-inf row
-    x = rng.standard_normal((4, 8192)).astype(np.float32)
-    x[:, 4000:] = np.inf
-    x[1, :] = np.inf
-    v, _ = select_k(x, 64, algo=SelectAlgo.SCREEN)
-    np.testing.assert_array_equal(np.asarray(v), np.sort(x, 1)[:, :64])
-    assert np.all(np.asarray(v)[1] == np.inf)
-
-
-def test_screen_filter_sparse_rows_and_neg_inf(rng):
-    """Rows where most candidates are +inf (heavy bitset filters) but ≥ k
-    survive get a finite certified τ via the FMAX clamp — and -inf values
-    (legal smallest in min-mode) must never be clamped away."""
-    x = rng.standard_normal((8, 16384)).astype(np.float32)
-    drop = rng.random((8, 16384)) < 0.95  # 95% filtered away
-    x = np.where(drop, np.inf, x).astype(np.float32)
-    v, i = select_k(x, 10, algo=SelectAlgo.SCREEN)
-    np.testing.assert_array_equal(np.asarray(v), np.sort(x, 1)[:, :10])
-    np.testing.assert_array_equal(
-        np.take_along_axis(x, np.asarray(i), 1), np.asarray(v))
-
-    y = rng.standard_normal((4, 4096)).astype(np.float32)
-    y[0, 7] = -np.inf
-    y[2, 100:110] = -np.inf
-    v, i = select_k(y, 16, algo=SelectAlgo.SCREEN)
-    np.testing.assert_array_equal(np.asarray(v), np.sort(y, 1)[:, :16])
-    assert np.asarray(v)[0, 0] == -np.inf and np.asarray(i)[0, 0] == 7
-
-
-def test_screen_int_dtype_falls_back(rng):
-    xi = rng.integers(0, 1000, (3, 256)).astype(np.int32)
-    v, _ = select_k(xi, 5, algo=SelectAlgo.SCREEN)
-    np.testing.assert_array_equal(np.asarray(v), np.sort(xi, 1)[:, :5])
-
-
-def test_auto_nested_screen_table():
-    """AUTO consumes the nested {two_phase, screen} crossover form the
-    r4 select_k_bench artifacts emit; SCREEN outranks TWO_PHASE where
-    both bands cover, and int dtypes never take SCREEN."""
+def test_auto_table_reads_no_json_file(tmp_path, monkeypatch):
+    """AUTO's tables live in code: a SELECT_K_TABLE_*.json in the working
+    directory or at the repo root switches nothing."""
     import importlib
+    import json
 
     sk = importlib.import_module("raft_tpu.ops.select_k")
-    sk.set_auto_table("cpu", {"two_phase": {"inf": 65536},
-                              "screen": {"64": 8192, "inf": 32768}})
-    try:
-        assert sk._resolve_auto(16384, 10) == sk.SelectAlgo.SCREEN
-        assert sk._resolve_auto(4096, 10) == sk.SelectAlgo.DIRECT
-        assert sk._resolve_auto(16384, 128) == sk.SelectAlgo.DIRECT
-        assert sk._resolve_auto(40000, 128) == sk.SelectAlgo.SCREEN
-        assert sk._resolve_auto(100000, 128) == sk.SelectAlgo.SCREEN
-        # int rows can't ride approx/inf-padding
-        assert sk._resolve_auto(16384, 10,
-                                floating=False) == sk.SelectAlgo.DIRECT
-        # screen-only nested table: two_phase never fires
-        sk.set_auto_table("cpu", {"screen": {"inf": 8192}})
-        assert sk._resolve_auto(16384, 10) == sk.SelectAlgo.SCREEN
-        assert sk._resolve_auto(4096, 10) == sk.SelectAlgo.DIRECT
-    finally:
-        sk.set_auto_table("cpu", {"inf": sk._NEVER})
+    monkeypatch.chdir(tmp_path)
+    (tmp_path / "SELECT_K_TABLE_cpu.json").write_text(json.dumps(
+        {"platform": "cpu", "crossovers": {"inf": 16}}))
+    monkeypatch.setattr(sk, "_auto_table_cache", None)
+    assert sk._resolve_auto(262144, 10) == sk.SelectAlgo.DIRECT
+    assert sk._load_auto_table() == sk._BUILTIN_TABLES
 
 
 def test_topk_pad_rules():

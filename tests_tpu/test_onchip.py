@@ -58,17 +58,20 @@ def test_fused_l2_argmin_matches_oracle(clustered):
 # --------------------------------------------------------------- select_k
 
 
-def test_screen_select_exact_on_chip(rng):
-    """SCREEN (approx-certified threshold + exhaustive extraction) must be
-    EXACT on the real PartialReduce hardware at IVF shapes."""
+def test_group_scan_exact_on_chip(rng):
+    """The exact brute-force scan's group minima (brute_force._group_topk)
+    answer as one DIRECT top-k over the whole row, bit for bit."""
+    from raft_tpu.neighbors import brute_force
+    from raft_tpu.ops.distance import l2_expanded, row_norms_sq
     from raft_tpu.ops.select_k import SelectAlgo, select_k
 
-    for (b, n, k) in [(512, 32768, 10), (256, 16384, 64), (128, 8192, 256)]:
-        x = rng.standard_normal((b, n)).astype(np.float32)
-        v, i = select_k(x, k, algo=SelectAlgo.SCREEN)
-        np.testing.assert_array_equal(np.asarray(v), np.sort(x, 1)[:, :k])
-        np.testing.assert_array_equal(
-            np.take_along_axis(x, np.asarray(i), 1), np.asarray(v))
+    db = rng.standard_normal((40_000, 32)).astype(np.float32)
+    q = rng.standard_normal((64, 32)).astype(np.float32)
+    v, i = brute_force.search(brute_force.build(db), q, 10)
+    row = l2_expanded(q, db, False, y_norms=row_norms_sq(db))
+    want_v, want_i = select_k(row, 10, algo=SelectAlgo.DIRECT)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(want_v))
 
 
 def test_approx_select_recall_on_chip(rng):

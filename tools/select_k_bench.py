@@ -7,13 +7,12 @@ and k ∈ {10, 32, 64, 128, 256} on whatever backend is active, times
 DIRECT vs TWO_PHASE vs (opt-in, small-k) PALLAS, and writes:
 
   - a full timing grid, and
-  - the per-k-band crossover widths in the exact format
-    ``raft_tpu.ops.select_k.set_auto_table`` / RAFT_TPU_SELECTK_TABLE
-    consume.
+  - the per-k-band crossover widths in the format of
+    ``raft_tpu.ops.select_k``'s ``_BUILTIN_TABLES`` (and
+    ``set_auto_table``), for a PR to write into that table.
 
 Run on TPU (a chip run): RAFT_TPU_BENCH_PLATFORM=default
-  python tools/select_k_bench.py --out SELECT_K_TABLE_tpu.json
-CPU (this image): python tools/select_k_bench.py --out SELECT_K_TABLE_cpu.json
+  python tools/select_k_bench.py --out select_k_tpu.json
 """
 
 import argparse
@@ -31,7 +30,7 @@ from raft_tpu.bench.timing import time_dispatches  # noqa: E402
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out", default="SELECT_K_TABLE.json")
+    ap.add_argument("--out", default="select_k_crossovers.json")
     ap.add_argument("--batch", type=int, default=2048)
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--widths", type=int, nargs="*",
@@ -54,8 +53,7 @@ def main():
     platform = jax.devices()[0].platform
     rng = np.random.default_rng(0)
     grid = []
-    algos = [SelectAlgo.DIRECT, SelectAlgo.TWO_PHASE, SelectAlgo.SCREEN,
-             SelectAlgo.APPROX]
+    algos = [SelectAlgo.DIRECT, SelectAlgo.TWO_PHASE, SelectAlgo.APPROX]
     if args.pallas:
         algos.append(SelectAlgo.PALLAS)
 
@@ -63,9 +61,8 @@ def main():
         """Write the artifact after every row: a timeout kill mid-sweep
         keeps the completed rows (minutes of compiles each).
         ``crossovers`` (in ``extra``) is only present once the grid is
-        COMPLETE — AUTO self-arms from artifacts at the repo root, and
-        sticky_crossover over a width-truncated grid could claim wins
-        the missing wider rows would refute."""
+        COMPLETE: sticky_crossover over a width-truncated grid could claim
+        wins the missing wider rows would refute."""
         art = {"platform": platform, "batch": args.batch, "grid": grid,
                "when": time.strftime("%Y-%m-%dT%H:%M:%S%z"), **extra}
         if partial:
@@ -127,17 +124,8 @@ def main():
         return out or None
 
     crossover_by_k = sticky_crossover("two_phase_ms")
-    screen_by_k = sticky_crossover("screen_ms")
-    tp_bands = band(crossover_by_k) or {"inf": 1 << 62}
-    screen_bands = band(screen_by_k)
-    # nested AUTO-table form (select_k._resolve_auto): SCREEN is checked
-    # first, TWO_PHASE second, DIRECT the fallback
-    bands = dict(tp_bands)
-    if screen_bands:
-        bands = {"two_phase": tp_bands, "screen": screen_bands}
-
-    write(partial=False, crossover_by_k=crossover_by_k,
-          screen_crossover_by_k=screen_by_k, crossovers=bands)
+    bands = band(crossover_by_k) or {"inf": 1 << 62}
+    write(partial=False, crossover_by_k=crossover_by_k, crossovers=bands)
     if os.path.exists(args.out + ".partial"):
         os.remove(args.out + ".partial")
     print(f"-> {args.out}\ncrossovers: {bands}")
