@@ -10,9 +10,11 @@ dataset and its norms (brute_force_types.hpp).
 TPU-native design: the distance tile is a bf16/fp32 ``dot_general`` on the MXU
 with the metric epilogue fused by XLA; per-tile top-k via ``select_k``; tiles
 merged pairwise by concatenating the k-candidate lists and re-selecting —
-identical math to knn_merge_parts but expressed as one more top-k. Query
-batches stream through a ``lax.map`` so HBM holds only [q_tile, db_tile]
-distances. Doubles as the exact ground-truth oracle for ANN tests (replacing
+identical math to knn_merge_parts but expressed as one more top-k. Exact
+scans rank 128-row group minima carried across the tiles instead
+(``_group_topk``); on a TPU one Pallas kernel makes each tile and its minima
+(``ops.pallas_kernels.group_scan_tile``). Query batches stream through a
+``lax.map`` so HBM holds only [q_tile, db_tile] distances. Doubles as the exact ground-truth oracle for ANN tests (replacing
 the reference's internal naive_knn.cuh:82).
 """
 
@@ -20,7 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +42,7 @@ from raft_tpu.ops.distance import (
     pairwise_core,
 )
 from raft_tpu.obs import explain as obs_explain
+from raft_tpu.obs import metrics as obs_metrics
 from raft_tpu.ops import pallas_kernels as pk
 from raft_tpu.ops.select_k import (refine_multiplier, select_k,
                                    select_k_maybe_approx)
@@ -134,8 +137,100 @@ _FAST_SCAN_METRICS = (
 )
 
 
+def _takes_groups(n_db: int, db_tile: int, k: int,
+                  select_recall: float) -> bool:
+    """Whether ``_scan_tiles`` takes ``_group_topk``: an exact scan whose
+    tiles hold at least ``k`` groups of ``GROUP`` rows, the tiles whole
+    groups unless there is one, so that only the last tile pads its last
+    group, past every row."""
+    return (select_recall >= 1.0 and k * GROUP <= db_tile <= n_db
+            and (db_tile % GROUP == 0 or db_tile == n_db))
+
+
+#: metrics whose tiles the group kernel makes (``pk.group_scan_tile``)
+_GROUP_KERNEL_METRICS = (
+    DistanceType.L2Expanded,
+    DistanceType.L2SqrtExpanded,
+    DistanceType.InnerProduct,
+)
+
+#: platforms the group kernel runs on: compiled on a TPU, under the Mosaic
+#: interpreter on any other listed here (parity tests add "cpu")
+_GROUP_KERNEL_PLATFORMS = ("tpu",)
+
+_GROUP_SCAN_PLANS = obs_metrics.REGISTRY.counter(
+    "raft_tpu_group_scan_plans_total",
+    "Exact group-minima scans planned (at trace time) by tile producer.",
+    ("producer",))
+
+
+class GroupScan(NamedTuple):
+    """How an exact group scan makes its tiles (``plan_group_scan``):
+    ``producer`` "pallas" (``pk.group_scan_tile``, ``gb`` groups a step,
+    reading the collection's [dim, rows] view when ``lanes_rows``, under
+    the interpreter when ``interpret``) or "xla" (``_tile_groups``), and
+    the ``obs.explain`` reason code."""
+    producer: str
+    reason: str
+    gb: int = 0
+    lanes_rows: bool = False
+    interpret: bool = False
+
+
+def _k_scan(k: int, db_tile: int, fast_scan: bool, refine_mult: int) -> int:
+    """Candidates the scan keeps: the fast scan over-selects them, and its
+    exact fp32 re-rank recovers the k best."""
+    return min(refine_mult * k, db_tile) if fast_scan else min(k, db_tile)
+
+
+def plan_group_scan(metric: DistanceType, dtype, device, q_tile: int,
+                    n_db: int, db_tile: int, dim: int, k: int,
+                    fast_scan: bool = False, refine_mult: int = 1,
+                    select_recall: float = 1.0) -> Optional[GroupScan]:
+    """The tile producer of ``_knn_jit``'s scan of ``n_db`` rows on
+    ``device`` (None when the scan does not take the group minima): the
+    kernel where every clause holds, else XLA's tile with the reason code
+    of the first that fails. Callers record it (``record_group_scan``) and
+    pass it to ``_knn_jit``, so the record names what runs."""
+    if not _takes_groups(n_db, db_tile,
+                         _k_scan(k, db_tile, fast_scan, refine_mult),
+                         select_recall):
+        return None
+    if device.platform not in _GROUP_KERNEL_PLATFORMS:
+        return GroupScan("xla", "tpu_absent")
+    if fast_scan:
+        return GroupScan("xla", "fast_scan")
+    if metric not in _GROUP_KERNEL_METRICS:
+        return GroupScan("xla", "unsupported_metric")
+    if jnp.dtype(dtype) != jnp.float32:
+        return GroupScan("xla", "not_float32")
+    lanes_rows = pk.rows_on_lanes(device, dtype, (n_db, dim))
+    gb = pk.plan_group_scan(q_tile, db_tile, dim, lanes_rows,
+                            aligned_to=db_tile if db_tile < n_db else 0)
+    if not gb:
+        return GroupScan("xla", "query_tile_vmem")
+    return GroupScan("pallas", "group_kernel", gb, lanes_rows,
+                     device.platform != "tpu")
+
+
+def record_group_scan(plan: Optional[GroupScan], q_tile: int, db_tile: int,
+                      params: dict) -> None:
+    """Emit an exact group scan's tile producer as an explain record
+    (family ``brute_force_group_scan``); nothing for a scan without
+    group minima (``plan`` None)."""
+    if plan is None:
+        return
+    obs_explain.record_dispatch(
+        "brute_force_group_scan", "auto", plan.producer, plan.reason,
+        params=params,
+        plan={"q_tile": q_tile, "db_tile": db_tile,
+              "rows_per_step": plan.gb * GROUP,
+              "rows_on_lanes": plan.lanes_rows,
+              "interpret": plan.interpret})
+
+
 def _scan_tiles(nq: int, n_db: int, db_tile: int, k: int, tile_dist,
-                select_min: bool, select_recall: float):
+                select_min: bool, select_recall: float, groups=None):
     """The database-tile loop of every brute-force scan. ``tile_dist(start,
     width)`` gives the [nq, width] distances of rows ``[start, start +
     width)``; this runs it over whole tiles of ``db_tile`` rows and once
@@ -144,16 +239,16 @@ def _scan_tiles(nq: int, n_db: int, db_tile: int, k: int, tile_dist,
     tiled_brute_force_knn, detail/knn_brute_force.cuh). Returns candidates
     ``(values, row ids)`` [nq, m], m ≥ k when n_db ≥ k, holding the k best.
 
-    An exact scan whose tiles hold at least ``k`` groups of ``GROUP`` rows
-    takes ``_group_topk``, which never ranks a whole tile; the tiles must
-    be whole groups unless there is one, so that only the last tile pads
-    its last group, past every row. Otherwise each tile keeps its
-    ``min(k, width)`` best by ``select_k`` (APPROX below ``select_recall``
-    1) and the candidates are pooled in row order (the analog of
+    An exact scan that ``_takes_groups`` runs ``_group_topk``, which never
+    ranks a whole tile, over ``groups`` (the group kernel's tiles) or
+    else over ``tile_dist``'s. Otherwise each tile keeps its ``min(k,
+    width)`` best by ``select_k`` (APPROX below ``select_recall`` 1) and
+    the candidates are pooled in row order (the analog of
     knn_merge_parts)."""
-    if (select_recall >= 1.0 and k * GROUP <= db_tile <= n_db
-            and (db_tile % GROUP == 0 or db_tile == n_db)):
-        return _group_topk(nq, n_db, db_tile, k, tile_dist, select_min)
+    if _takes_groups(n_db, db_tile, k, select_recall):
+        return _group_topk(nq, n_db, db_tile, k,
+                           groups or _tile_groups(tile_dist, select_min),
+                           select_min)
 
     def tile_topk(start, width):
         v, i = select_k_maybe_approx(tile_dist(start, width), min(k, width),
@@ -174,31 +269,41 @@ def _scan_tiles(nq: int, n_db: int, db_tile: int, k: int, tile_dist,
     return jnp.concatenate(vs, axis=1), jnp.concatenate(ids, axis=1)
 
 
-def _group_topk(nq: int, n_db: int, db_tile: int, k: int, tile_dist,
+def _tile_groups(tile_dist, select_min: bool):
+    """XLA's producer of a tile's groups for ``_group_topk``: the tile's
+    values (distances, negated when selecting the largest: negation is
+    exact) as [nq, n_g, GROUP], the remainder's pad +inf past every row,
+    and each group's minimum [nq, n_g]."""
+    def groups(start, width):
+        d = tile_dist(start, width)
+        d = d if select_min else -d
+        n_g = cdiv(width, GROUP)
+        if n_g * GROUP > width:
+            d = jnp.pad(d, ((0, 0), (0, n_g * GROUP - width)),
+                        constant_values=jnp.inf)
+        d = d.reshape(d.shape[0], n_g, GROUP)
+        return d, d.min(axis=-1)
+
+    return groups
+
+
+def _group_topk(nq: int, n_db: int, db_tile: int, k: int, groups,
                 select_min: bool):
     """Exact top-k by group minima, carried across the tiles; the same
     values and ids as one ``lax.top_k`` over the whole row, ties to the
     lower row.
 
-    Rows fall in groups of ``GROUP``. The k groups of least minimum (ties
-    to the lower group) hold the k best rows: a row outside them is no
-    better than any of those k minima, which are k distinct rows ranked
-    ahead of it. The carry holds the k best groups so far (minimum, the
-    rows' distances, first row), in row order; each tile's group minima
-    compete with it in one top-k over width / GROUP + k values, and one
-    top-k over the k·GROUP kept distances ends the scan. Minima are taken
-    of ``-distance`` when selecting the largest: negation is exact."""
+    ``groups(start, width)`` gives a tile's values as [nq, n_g, GROUP]
+    (least is best) and each group's minimum [nq, n_g] (``_tile_groups``
+    or the group kernel). The k groups of least minimum (ties to the
+    lower group) hold the k best rows: a row outside them is no better
+    than any of those k minima, which are k distinct rows ranked ahead of
+    it. The carry holds the k best groups so far (minimum, the rows'
+    values, first row), in row order; each tile's group minima compete
+    with it in one top-k over width / GROUP + k values, and one top-k over
+    the k·GROUP kept values ends the scan; the values of a scan selecting
+    the largest are negated back."""
     g = GROUP
-
-    def groups(start, width):
-        d = tile_dist(start, width)
-        d = d if select_min else -d
-        n_g = cdiv(width, g)
-        if n_g * g > width:  # the remainder: its pad lies past every row
-            d = jnp.pad(d, ((0, 0), (0, n_g * g - width)),
-                        constant_values=jnp.inf)
-        d = d.reshape(d.shape[0], n_g, g)
-        return d, d.min(axis=-1)
 
     def take(rows, sel):
         # the rows of groups ``sel`` [q, k] of ``rows`` [q, n_g, g]
@@ -246,15 +351,18 @@ def _group_topk(nq: int, n_db: int, db_tile: int, k: int, tile_dist,
     jax.jit,
     static_argnames=("metric", "metric_arg", "k", "q_tile", "db_tile",
                      "budget", "has_filter", "fast_scan", "refine_mult",
-                     "select_recall"),
+                     "select_recall", "group_scan"),
 )
 def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
              q_tile, db_tile, budget, has_filter: bool = False,
              fast_scan: bool = False, refine_mult: int = 4,
-             select_recall: float = 1.0, n_valid=None):
+             select_recall: float = 1.0, n_valid=None,
+             group_scan: Optional[GroupScan] = None):
     """Exact kNN core, tiled over queries and database rows. ``n_valid``
     (may be traced) masks rows at or past it as padding: the last shard of
-    a row-sharded collection (``parallel.sharded.knn``)."""
+    a row-sharded collection (``parallel.sharded.knn``). ``group_scan``
+    (``plan_group_scan``) names the producer of a group scan's tiles;
+    without it they are XLA's."""
     nq, dim = queries.shape
     minimize = is_min_close(metric)
 
@@ -266,7 +374,17 @@ def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
     q_pad = n_q_tiles * q_tile - nq
 
     qp = jnp.pad(queries, ((0, q_pad), (0, 0)))
-    need_norms = use_cached_norms or (
+    n_db = dataset.shape[0]
+    k_scan = _k_scan(k, db_tile, fast_scan, refine_mult)
+    # Refine pool must still hold >= k candidates when db_tile < k; the
+    # pooled tiles hold >= min(k_scan, n_db) >= k entries.
+    k_refine = max(k_scan, k)
+    kernel = False
+    if _takes_groups(n_db, db_tile, k_scan, select_recall):
+        kernel = group_scan is not None and group_scan.producer == "pallas"
+        _GROUP_SCAN_PLANS.labels("pallas" if kernel else "xla").inc()
+    l2_kernel = kernel and metric != DistanceType.InnerProduct
+    need_norms = use_cached_norms or l2_kernel or (
         fast_scan and metric != DistanceType.InnerProduct)
     if use_cached_norms:
         dbn = db_norms
@@ -275,11 +393,6 @@ def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
     else:
         dbn = None
     bad_fill = jnp.inf if minimize else -jnp.inf
-    # Fast scan over-selects candidates; exact fp32 re-rank recovers them.
-    k_scan = min(refine_mult * k, db_tile) if fast_scan else min(k, db_tile)
-    # Refine pool must still hold >= k candidates when db_tile < k; the
-    # pooled tiles hold >= min(k_scan, n_db) >= k entries.
-    k_refine = max(k_scan, k)
 
     def _filter_pass(ids):
         """Packed-bitset test for row ids (shared by scan + refine)."""
@@ -293,11 +406,32 @@ def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
         bad = jnp.zeros(ids.shape, bool) if n_valid is None else ids >= n_valid
         return bad | ~_filter_pass(ids) if has_filter else bad
 
+    if kernel:
+        # one term a database row: its squared norm for L2, 0 for inner
+        # product, +inf where the filter clears it
+        row_terms = dbn if l2_kernel else jnp.zeros((n_db,), jnp.float32)
+        if has_filter:
+            row_terms = jnp.where(_filter_pass(jnp.arange(n_db)), row_terms,
+                                  jnp.inf)
+
     def q_body(qt):
         # Query-tile norms hoisted out of the db-tile loop (analog of the
         # reference's rowNorm precompute, detail/knn_brute_force.cuh:97-136).
         qt_norms = row_norms_sq(qt) if need_norms else None
         qt_bf = qt.astype(jnp.bfloat16) if fast_scan else None
+
+        def kernel_groups(start, width):
+            end = start + width
+            tile, mins = pk.group_scan_tile(
+                qt, qt_norms if l2_kernel else jnp.zeros((q_tile,)), dataset,
+                row_terms, start,
+                end if n_valid is None else jnp.minimum(n_valid, end),
+                width=width, gb=group_scan.gb,
+                lanes_rows=group_scan.lanes_rows, l2=l2_kernel,
+                sqrt=metric == DistanceType.L2SqrtExpanded,
+                negate=not minimize, filtered=has_filter,
+                interpret=group_scan.interpret)
+            return jnp.swapaxes(tile, 0, 1), mins
 
         def tile_dist(start, width):
             db_t = jax.lax.dynamic_slice_in_dim(dataset, start, width, 0)
@@ -338,8 +472,9 @@ def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
                 d = jnp.where(bad[None, :], bad_fill, d)
             return d
 
-        all_v, all_i = _scan_tiles(q_tile, dataset.shape[0], db_tile, k_scan,
-                                   tile_dist, minimize, select_recall)
+        all_v, all_i = _scan_tiles(q_tile, n_db, db_tile, k_scan, tile_dist,
+                                   minimize, select_recall,
+                                   kernel_groups if kernel else None)
         if fast_scan:
             # Exact fp32 re-rank of the scanned candidates (reference analog:
             # neighbors::refine over a coarse candidate list).
@@ -519,6 +654,11 @@ def search(index: Index, queries, k: int, filter=None,
                 q_cap = max(
                     8, res.workspace_limit_bytes // (4 * max(per_row, 1)))
                 q_tile = min(q_tile, q_cap - q_cap % 8 or 8)
+            group_scan = plan_group_scan(
+                index.metric, index.dataset.dtype, res.device, q_tile,
+                index.size, db_tile, index.dim, k, fast_scan, refine_mult,
+                select_recall)
+            record_group_scan(group_scan, q_tile, db_tile, ex_params)
             # fused was dispatchable but this request's shape wasn't
             # eligible -> the matrix clause outranks the dispatch verdict
             reason = ineligible if (use_fused and ineligible) else dreason
@@ -535,7 +675,7 @@ def search(index: Index, queries, k: int, filter=None,
                 index.metric, index.metric_arg,
                 k, q_tile, db_tile, res.workspace_limit_bytes,
                 filter is not None, fast_scan, refine_mult,
-                select_recall=float(select_recall),
+                select_recall=float(select_recall), group_scan=group_scan,
             )
     if explain:
         return v[:nq], i[:nq], cap.last

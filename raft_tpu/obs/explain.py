@@ -76,6 +76,12 @@ REASONS = frozenset({
     "k_gt_1024",               # k above the VMEM top-k carry bound
     "non_float_dtype",         # integer dataset (no float carry)
     "lut_params_unsupported",  # fused-LUT regime needs pq_bits=8 etc.
+    # exact group scan's tile producer (brute_force.plan_group_scan;
+    # "tpu_absent"/"fast_scan" above are shared with it)
+    "group_kernel",            # TPU: the Pallas kernel makes each tile
+    "unsupported_metric",      # metric outside L2/L2Sqrt/inner product
+    "not_float32",             # data or queries not float32
+    "query_tile_vmem",         # the query tile cannot stay in VMEM
     # sharded cross-chip merge dispatch (parallel/sharded.py merge_mode;
     # "forced"/"fused_loses" above are shared with the merge ladder)
     "merge_tree",              # auto: log₂S ppermute tree merge (default)

@@ -704,6 +704,182 @@ def fused_l2_topk(x, y, k: int, x_norms=None, y_norms=None,
                               bool(interpret))
 
 
+# ------------------------------------------- exact scan: a tile's groups
+#
+# The exact brute-force scan (neighbors.brute_force._group_topk) ranks
+# 128-row groups by their minima and gathers the kept groups' rows. In
+# XLA the distance tile comes out with the rows on the lanes, and both
+# readers want it laid out again in HBM: the minima reduce across the
+# lanes, the gather wants the groups major. This kernel makes each
+# [queries, rows] block in VMEM, writes it as [groups, queries, 128] (the
+# order the gather reads) and reduces each group's 128 lanes there, so
+# the tile is written once and never relaid out.
+
+#: rows per group: one 128-lane vreg row (``brute_force.GROUP``)
+SCAN_GROUP = 128
+
+
+def rows_on_lanes(device, dtype, shape) -> bool:
+    """Whether ``device`` keeps a [rows, dim] array of ``shape`` with its
+    rows on the lanes (layout ``{0,1}``), so that its [dim, rows]
+    transpose is a bitcast. A v5e does so where that pads less than
+    rows-major: f32 [n, 96] yes, [n, 128] and [n, 768] no."""
+    from jax.experimental.layout import Layout
+
+    layout = Layout.from_pjrt_layout(device.client.get_default_layout(
+        jnp.dtype(dtype), tuple(shape), device))
+    return layout.major_to_minor[-1] == 0
+
+
+def group_scan_vmem_terms(q_tile: int, dim: int, lanes_rows: bool) -> dict:
+    """The VMEM of one ``group_scan_tile`` grid step, as the terms of
+    ``core.resources.solve_vmem_tiles`` (outer: the step's rows; inner:
+    the resident queries). Every pipelined block twice: a cell's tile
+    block and, once more, its distances (``cell_bytes``); a row's block
+    of the database — [dim, rows] 8-sublane padded, or [rows, dim]
+    lane-padded — and its norm row (``outer_bytes``); a query's [q, dim]
+    row, norm and minima, each lane-padded (``inner_bytes``). Against the
+    v5e compiler's scoped VMEM: 0.1% over at the exact-kNN cell's step
+    (1000 queries × 512 rows × 96), 5–86% over at eight other steps, and
+    1–3% under where at most 64 queries sit beside 768- to 1024-wide
+    rows; the 12 MiB budget leaves 4 MiB below the 16 MiB limit."""
+    row = _sublanes(dim) * 4 if lanes_rows else _lanes(dim) * 4
+    return {"cell_bytes": 12,
+            "outer_bytes": 2 * (row + 8 * 4),
+            "inner_bytes": 2 * (_lanes(dim) * 4 + 2 * 128 * 4)}
+
+
+def plan_group_scan(q_tile: int, width: int, dim: int, lanes_rows: bool,
+                    aligned_to: int = 0,
+                    vmem_budget: Optional[int] = None) -> int:
+    """Groups per grid step of ``group_scan_tile`` (0: the query tile
+    cannot stay resident, so the kernel cannot run): the most rows a step
+    that the VMEM solve (``group_scan_vmem_terms``) fits beside the whole
+    query tile, in a power of two of groups (a step's minima land in one
+    128-lane block) that divides ``aligned_to`` rows when it is given (a
+    tile's start must be a whole number of steps)."""
+    from raft_tpu.core.resources import solve_vmem_tiles
+
+    budget = DEFAULT_VMEM_BUDGET if vmem_budget is None else int(vmem_budget)
+    q = _sublanes(q_tile)
+    rows, tq = solve_vmem_tiles(
+        budget, **group_scan_vmem_terms(q_tile, dim, lanes_rows),
+        inner_max=q,
+        outer_cap=SCAN_GROUP * SCAN_GROUP,
+        outer_multiple=SCAN_GROUP,
+        inner_multiple=8,
+    )
+    if tq < q:
+        return 0
+    gb = 1
+    limit = min(rows, round_up_to(max(int(width), 1), SCAN_GROUP))
+    while 2 * gb * SCAN_GROUP <= limit and (
+            not aligned_to or aligned_to % (2 * gb * SCAN_GROUP) == 0):
+        gb *= 2
+    return gb
+
+
+def _group_scan_kernel(s_ref, q_ref, qn_ref, x_ref, row_ref, tile_ref,
+                       min_ref, *, gb: int, lanes_rows: bool, l2: bool,
+                       sqrt: bool, negate: bool, filtered: bool):
+    """One step: the [q, gb·128] distances of ``gb`` groups on the MXU
+    (from a [dim, rows] block, or a [rows, dim] one when the rows are not
+    on the lanes) and the scan's epilogue (the ``l2_expanded`` order;
+    negated when selecting the largest; +inf past ``limit`` and where the
+    row term is +inf, the rows a filter clears), written groups-major;
+    each group's minimum, reduced across its lanes, lands in its lane of
+    the resident [q, 128] minima block."""
+    j = pl.program_id(0)
+    tn = gb * SCAN_GROUP
+    dots = jax.lax.dot_general(
+        q_ref[...], x_ref[...], (((1,), (0 if lanes_rows else 1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )  # [q, tn]
+    row = row_ref[...]
+    if l2:
+        d = jnp.maximum(qn_ref[...] + row - 2.0 * dots, 0.0)
+        d = jnp.sqrt(d) if sqrt else d
+    else:
+        d = dots
+    d = -d if negate else d
+    first = (s_ref[0] + j) * tn
+    bad = jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1) + first >= s_ref[1]
+    if filtered:
+        bad = bad | (row == jnp.inf)
+    d = jnp.where(bad, jnp.inf, d)
+    lane = jax.lax.broadcasted_iota(jnp.int32, min_ref.shape, 1)
+    at = (j % (SCAN_GROUP // gb)) * gb
+    mins = min_ref[...]
+    for g in range(gb):
+        part = d[:, g * SCAN_GROUP:(g + 1) * SCAN_GROUP]
+        tile_ref[g] = part
+        mins = jnp.where(lane == at + g,
+                         jnp.min(part, axis=1, keepdims=True), mins)
+    min_ref[...] = mins
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "width", "gb", "lanes_rows", "l2", "sqrt", "negate", "filtered",
+    "interpret"))
+def group_scan_tile(queries, q_norms, data, row_terms, start, limit, *,
+                    width: int, gb: int, lanes_rows: bool, l2: bool,
+                    sqrt: bool, negate: bool, filtered: bool,
+                    interpret: bool = False):
+    """The exact scan's tile ``[start, start + width)`` of the database
+    ``data`` [n, dim], by groups of ``SCAN_GROUP`` rows → ``(tile [n_g,
+    q, 128], minima [q, n_g])``, n_g = ⌈width / 128⌉: the values the scan
+    ranks (distances, negated when ``negate``) and each group's minimum.
+
+    The kernel reads ``data`` in the layout it has, never a copy:
+    ``lanes_rows`` (``rows_on_lanes``) reads its [dim, n] transpose, a
+    bitcast there; else [rows, dim] blocks. ``row_terms`` [n] is the
+    rows' squared norms for L2 (``l2``), zeros for inner product, +inf at
+    rows a filter clears (``filtered``). Rows at or past ``limit`` —
+    padding of a sharded collection, the last group's pad — read +inf.
+    ``start`` is a whole number of ``gb·128``-row steps
+    (``plan_group_scan``); a block past the end of ``data`` is read
+    clipped and masked."""
+    nq, dim = queries.shape
+    n_g = -(-int(width) // SCAN_GROUP)
+    tn = gb * SCAN_GROUP
+    spb = SCAN_GROUP // gb  # steps a 128-group minima block
+    scalars = jnp.stack([jnp.asarray(start, jnp.int32) // tn,
+                         jnp.asarray(limit, jnp.int32)])
+    data = data.astype(jnp.float32)
+    if lanes_rows:
+        data, x_spec = data.T, pl.BlockSpec((dim, tn),
+                                            lambda j, s: (0, s[0] + j))
+    else:
+        x_spec = pl.BlockSpec((tn, dim), lambda j, s: (s[0] + j, 0))
+    tile, mins = pl.pallas_call(
+        functools.partial(_group_scan_kernel, gb=gb, lanes_rows=lanes_rows,
+                          l2=l2, sqrt=sqrt, negate=negate,
+                          filtered=filtered),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(-(-n_g // gb),),
+            in_specs=[
+                pl.BlockSpec((nq, dim), lambda j, s: (0, 0)),
+                pl.BlockSpec((nq, 1), lambda j, s: (0, 0)),
+                x_spec,
+                pl.BlockSpec((1, tn), lambda j, s: (0, s[0] + j)),
+            ],
+            out_specs=[
+                pl.BlockSpec((gb, nq, SCAN_GROUP), lambda j, s: (j, 0, 0)),
+                pl.BlockSpec((nq, SCAN_GROUP), lambda j, s: (0, j // spb)),
+            ]),
+        out_shape=(
+            jax.ShapeDtypeStruct((n_g, nq, SCAN_GROUP), jnp.float32),
+            jax.ShapeDtypeStruct((nq, round_up_to(n_g, SCAN_GROUP)),
+                                 jnp.float32)),
+        interpret=interpret,
+    )(scalars, queries.astype(jnp.float32),
+      q_norms.astype(jnp.float32).reshape(nq, 1), data,
+      row_terms.reshape(1, -1))
+    return tile, mins[:, :n_g]
+
+
 # ------------------------------------------------------- fused ivf top-k
 
 

@@ -582,11 +582,17 @@ def knn(
     kk = min(k, shard)
     budget = res.workspace_limit_bytes
     q_tile, db_tile = brute_force.choose_tiles(nq, shard, dim, kk, budget)
+    group_scan = brute_force.plan_group_scan(
+        m, jnp.promote_types(queries.dtype, dataset.dtype),
+        comms.mesh.devices.flat[0], q_tile, shard, db_tile, dim, kk)
+    brute_force.record_group_scan(
+        group_scan, q_tile, db_tile,
+        {"nq": nq, "k": k, "metric": m.name, "sharded": size})
     sink = _span_sink()
     if sink is not None:
         return _instrumented_search(
             comms, _knn_local_scan(comms, m, n, shard, kk, q_tile, db_tile,
-                                   budget),
+                                   budget, group_scan),
             (P(None, None), P(comms.axis, None)), (q, x), "brute_force",
             nq, min(k, size * kk), minimize, sink)
 
@@ -595,14 +601,16 @@ def knn(
         nq, k, kk, "xla", merge_mode=merge_mode,
         tiles={"q_tile": q_tile, "db_tile": db_tile})
     _record_plan(plan, merge_mode, {"metric": m.name})
-    return _knn_program(comms, plan, m, budget)(q, x)
+    return _knn_program(comms, plan, m, budget, group_scan)(q, x)
 
 
 def _knn_local_scan(comms: Comms, metric: DistanceType, n: int, shard: int,
-                    kk: int, q_tile: int, db_tile: int, budget: int):
+                    kk: int, q_tile: int, db_tile: int, budget: int,
+                    group_scan):
     """Each chip's part of ``knn``: ``brute_force``'s tiled exact core over
-    its shard, rows past ``n`` (the last shard's padding) masked, ids made
-    global → the shard's ``kk`` best (values, ids)."""
+    its shard (tiles by ``group_scan``, ``brute_force.plan_group_scan``),
+    rows past ``n`` (the last shard's padding) masked, ids made global →
+    the shard's ``kk`` best (values, ids)."""
     from raft_tpu.neighbors import brute_force
 
     def local_scan(q_rep, x_loc):
@@ -613,7 +621,8 @@ def _knn_local_scan(comms: Comms, metric: DistanceType, n: int, shard: int,
                  else None)
         v, i = brute_force.knn_core(
             q_rep, x_loc, norms, jnp.zeros((0,), jnp.uint32), metric, 2.0,
-            kk, q_tile, db_tile, budget, n_valid=n - base)
+            kk, q_tile, db_tile, budget, n_valid=n - base,
+            group_scan=group_scan)
         return v, (i + base).astype(jnp.int32)
 
     return local_scan
@@ -621,13 +630,14 @@ def _knn_local_scan(comms: Comms, metric: DistanceType, n: int, shard: int,
 
 @functools.lru_cache(maxsize=64)
 def _knn_program(comms: Comms, plan: PlacementPlan, metric: DistanceType,
-                 budget: int):
+                 budget: int, group_scan):
     """The jitted SPMD program of one ``knn`` plan (local scan + merge):
     calls with the same comms and plan reuse it, so a repeated shape
     compiles once."""
     tiles = dict(plan.tiles)
     scan = _knn_local_scan(comms, metric, plan.n_rows, plan.bounds[1],
-                           plan.kk, tiles["q_tile"], tiles["db_tile"], budget)
+                           plan.kk, tiles["q_tile"], tiles["db_tile"], budget,
+                           group_scan)
     minimize = metric != DistanceType.InnerProduct
 
     def local(q_rep, x_loc):

@@ -239,3 +239,214 @@ def test_group_scan_matches_direct(monkeypatch, n, nq, k, db_tile, q_tile,
     want_d, want_i = brute_force.search(index, q, k, filter=flt)
     np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
     np.testing.assert_array_equal(np.asarray(d), np.asarray(want_d))
+
+
+# ------------------------------------------- the group kernel, interpreted
+
+
+def _ulp_tol(q, db):
+    """4 ulp of ‖q‖² + ‖x‖², the size of the terms an expanded distance
+    cancels: XLA:CPU rounds the interpreted kernel's [q, d]·[d, rows]
+    block and XLA's [q, d]·[rows, d]ᵀ dot apart by a few of them (on a
+    v5e the two were bit-equal, PERF.md)."""
+    return 4 * np.spacing(np.float32((q * q).sum(1).max()
+                                     + (db * db).sum(1).max()))
+
+
+_TILE_CASES = [
+    ("remainder", 4_096, 904, 4),
+    ("n_valid", 0, 2_048, 2),
+    ("sparse_filter", 2_048, 2_048, 4),
+    ("starved_filter", 2_048, 2_048, 8),
+    ("inner_product", 0, 2_048, 4),
+    ("l2sqrt", 1_024, 1_024, 1),
+    ("duplicate_rows", 0, 4_096, 8),
+]
+
+
+@pytest.mark.parametrize("case,start,width,gb", _TILE_CASES + [
+    (c + "-rows_major", s, w, g) for c, s, w, g in _TILE_CASES])
+def test_group_scan_tile_matches_xla_tile(case, start, width, gb):
+    """``pk.group_scan_tile`` (Mosaic interpreter) against XLA's tile
+    producer (``_tile_groups``) on the same rows, reading the collection's
+    [dim, rows] view or, for ``-rows_major``, its [rows, dim] blocks: +inf
+    at the same places (the last group's pad, rows past ``n_valid``, rows a
+    filter clears), finite values within a few ulp, each minimum its
+    group's least value, and the same groups ranked first."""
+    import jax.numpy as jnp
+
+    from raft_tpu.ops import pallas_kernels as pk
+    from raft_tpu.ops.distance import inner_product, l2_expanded
+
+    case, _, major = case.partition("-")
+    rng = np.random.default_rng(width + gb)
+    n = 5_000
+    db = rng.standard_normal((n, 8)).astype(np.float32)
+    if case == "duplicate_rows":
+        db = db[:300][rng.integers(0, 300, n)]
+    q = rng.standard_normal((16, 8)).astype(np.float32)
+    qn, xn = (q * q).sum(1), (db * db).sum(1)
+    limit = 1_500 if case == "n_valid" else start + width
+    keep = np.ones(n, bool)
+    if case.endswith("filter"):
+        keep = rng.random(n) < (0.05 if case == "sparse_filter" else 0.0)
+        keep[[2_100, 3_000]] = True
+    l2 = case != "inner_product"
+    terms = np.where(keep, xn if l2 else 0.0, np.inf).astype(np.float32)
+    tile, mins = pk.group_scan_tile(
+        q, qn, db, terms, start, limit, width=width, gb=gb,
+        lanes_rows=not major, l2=l2, sqrt=case == "l2sqrt", negate=not l2,
+        filtered=case.endswith("filter"), interpret=True)
+    rows = np.swapaxes(np.asarray(tile), 0, 1)
+
+    def tile_dist(s, w):
+        x = jnp.asarray(db[s:s + w])
+        d = (l2_expanded(q, x, case == "l2sqrt", y_norms=xn[s:s + w]) if l2
+             else inner_product(jnp.asarray(q), x))
+        ids = np.arange(s, s + w)
+        return jnp.where((ids >= limit) | ~keep[ids], np.inf if l2
+                         else -np.inf, d)
+
+    want_rows, want_mins = brute_force._tile_groups(tile_dist, l2)(start,
+                                                                  width)
+    want_rows = np.asarray(want_rows)
+    assert rows.shape == want_rows.shape
+    np.testing.assert_array_equal(np.isinf(rows), np.isinf(want_rows))
+    fin = np.isfinite(want_rows)
+    np.testing.assert_allclose(rows[fin], want_rows[fin], rtol=0,
+                               atol=_ulp_tol(q, db))
+    np.testing.assert_array_equal(np.asarray(mins), rows.min(-1))
+    kk = min(10, mins.shape[1])
+    np.testing.assert_array_equal(
+        np.argsort(np.asarray(mins), 1, kind="stable")[:, :kk],
+        np.argsort(np.asarray(want_mins), 1, kind="stable")[:, :kk])
+
+
+@pytest.mark.parametrize("n,nq,k,db_tile,q_tile,case", [
+    (20_000, 16, 10, 2_048, 16, "plain"),
+    (30_000, 16, 64, 8_192, 16, "plain"),
+    (20_000, 16, 10, 2_048, 16, "sparse_filter"),
+    (20_000, 16, 10, 2_048, 16, "starved_filter"),
+    (20_000, 16, 32, 4_096, 16, "duplicate_rows"),
+    (20_000, 16, 10, 2_048, 16, "inner_product"),
+    (20_000, 16, 10, 2_560, 16, "l2sqrt"),
+    (20_000, 20, 10, 2_048, 8, "query_tiles"),
+    (20_000, 16, 10, 2_048, 16, "plain-lanes_rows"),
+    (20_000, 16, 10, 2_048, 16, "sparse_filter-lanes_rows"),
+    (20_000, 16, 32, 4_096, 16, "duplicate_rows-lanes_rows"),
+    (20_000, 16, 10, 2_048, 16, "inner_product-lanes_rows"),
+], ids=["k10", "k64", "sparse_filter", "starved_filter", "duplicate_rows",
+        "inner_product", "l2sqrt", "query_tiles", "k10_lanes_rows",
+        "sparse_filter_lanes_rows", "duplicate_rows_lanes_rows",
+        "inner_product_lanes_rows"])
+def test_group_kernel_search_matches_xla(monkeypatch, n, nq, k, db_tile,
+                                         q_tile, case):
+    """The exact scan over the group kernel's tiles (interpreted) answers
+    as over XLA's: the same ids, ties to the lower row, distances within a
+    few ulp; the search records the producer and the planning counter
+    counts it. The CPU keeps the collection rows-major, so the kernel
+    reads [rows, dim] blocks; ``-lanes_rows`` cases take the layout of a
+    narrow collection on a TPU, and the kernel reads the [dim, rows] view."""
+    import jax
+
+    from raft_tpu.core.bitset import Bitset
+    from raft_tpu.obs import explain as obs_explain
+    from raft_tpu.obs.metrics import REGISTRY
+    from raft_tpu.ops import pallas_kernels as pk
+
+    case, _, lanes = case.partition("-")
+    rng = np.random.default_rng(n + k)
+    db = rng.standard_normal((n, 8)).astype(np.float32)
+    q = rng.standard_normal((nq, 8)).astype(np.float32)
+    if case == "duplicate_rows":
+        # small integers: every distance is exact whatever the dot's
+        # shape, so the many ties must go to the lower row on both paths
+        db = rng.integers(-3, 4, (300, 8)).astype(np.float32)[
+            rng.integers(0, 300, n)]
+        q = rng.integers(-3, 4, (nq, 8)).astype(np.float32)
+    metric = {"inner_product": "inner_product",
+              "l2sqrt": "euclidean"}.get(case, "sqeuclidean")
+    flt = None
+    if case.endswith("filter"):
+        keep = rng.random(n) < (0.05 if case == "sparse_filter" else 0.0)
+        keep[[17, 4_000, 19_999]] = True
+        flt = Bitset.from_mask(keep)
+    index = brute_force.build(db, metric=metric)
+    monkeypatch.setattr(brute_force, "_choose_tiles",
+                        lambda *a: (q_tile, db_tile))
+    jax.clear_caches()
+    want_d, want_i = brute_force.search(index, q, k, filter=flt)
+    monkeypatch.setattr(brute_force, "_GROUP_KERNEL_PLATFORMS",
+                        ("tpu", "cpu"))
+    monkeypatch.setattr(pk, "rows_on_lanes", lambda *a: bool(lanes))
+    plans = REGISTRY.get("raft_tpu_group_scan_plans_total")
+    before = dict((key, c.value) for key, c in plans.collect())
+    jax.clear_caches()
+    with obs_explain.capture() as cap:
+        d, i = brute_force.search(index, q, k, filter=flt)
+    scan = [r for r in cap.records if r.family == "brute_force_group_scan"]
+    assert [(r.engine, r.reason) for r in scan] == [("pallas", "group_kernel")]
+    assert scan[0].plan["rows_on_lanes"] == bool(lanes)
+    assert scan[0].plan["interpret"]
+    after = dict((key, c.value) for key, c in plans.collect())
+    assert after[("pallas",)] - before.get(("pallas",), 0) == 1
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
+    np.testing.assert_allclose(np.asarray(d), np.asarray(want_d), rtol=0,
+                               atol=_ulp_tol(q, db))
+
+
+@pytest.mark.parametrize("metric,dtype,fast,dim,want", [
+    ("sqeuclidean", np.float32, False, 96, ("pallas", "group_kernel")),
+    ("inner_product", np.float32, False, 96, ("pallas", "group_kernel")),
+    ("sqeuclidean", np.float32, True, 96, ("xla", "fast_scan")),
+    ("cosine", np.float32, False, 96, ("xla", "unsupported_metric")),
+    ("sqeuclidean", "bfloat16", False, 96, ("xla", "not_float32")),
+    ("sqeuclidean", np.float32, False, 8_192, ("xla", "query_tile_vmem")),
+], ids=["l2", "inner_product", "fast_scan", "cosine", "bfloat16",
+        "wide_rows"])
+def test_group_scan_producer_reasons(monkeypatch, metric, dtype, fast, dim,
+                                     want):
+    """Where the group kernel engages, and the reason code of each place
+    it does not; off the kernel's platforms the XLA tile stays."""
+    import jax
+
+    from raft_tpu.ops.distance import resolve_metric
+
+    m = resolve_metric(metric)
+    args = (m, dtype, jax.devices("cpu")[0], 1_000, 3_125_000, 208_384, dim,
+            100, fast, 4)
+    assert brute_force.plan_group_scan(*args)[:2] == ("xla", "tpu_absent")
+    monkeypatch.setattr(brute_force, "_GROUP_KERNEL_PLATFORMS",
+                        ("tpu", "cpu"))
+    plan = brute_force.plan_group_scan(*args)
+    assert plan[:2] == want
+    assert (plan.gb > 0) == (plan.producer == "pallas")
+    assert plan.interpret == (plan.producer == "pallas")
+    # a scan that never takes the group minima has no plan
+    assert brute_force.plan_group_scan(*args[:7], 2_000, fast, 4) is None
+
+
+def test_plan_group_scan_steps_fit_and_align():
+    """A step is a power of two of groups that fits the VMEM budget with
+    the whole query tile, by the terms the planner gives the solver, and
+    divides the tile so each tile starts on a step; the deep cell's
+    208,384-row tiles (1,628 groups) take 4."""
+    from raft_tpu.ops import pallas_kernels as pk
+
+    assert pk.plan_group_scan(1_000, 208_384, 96, True,
+                              aligned_to=208_384) == 4
+    assert pk.plan_group_scan(1_000, 210_048, 96, True,
+                              aligned_to=210_048) == 1
+    for q_tile, width, dim, lanes_rows in [
+            (8, 1_000_000, 96, True), (64, 1_000_000, 96, True),
+            (1_024, 3_000, 768, False), (200, 300_000, 128, False),
+            (64, 1_000_000, 128, False), (1_000, 208_384, 96, True)]:
+        gb = pk.plan_group_scan(q_tile, width, dim, lanes_rows)
+        assert gb and gb & (gb - 1) == 0 and 128 % gb == 0
+        assert gb * 128 <= max(width, 128) + 127
+        t = pk.group_scan_vmem_terms(q_tile, dim, lanes_rows)
+        rows, q = gb * 128, pk._sublanes(q_tile)
+        assert (t["outer_bytes"] * rows + t["inner_bytes"] * q
+                + t["cell_bytes"] * rows * q) <= pk.DEFAULT_VMEM_BUDGET
+    assert pk.plan_group_scan(1_024, 100_000, 8_192, True) == 0
+    assert pk.plan_group_scan(1_024, 100_000, 8_192, False) == 0

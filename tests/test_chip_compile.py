@@ -157,3 +157,144 @@ def test_sharded_knn_temp_fits_at_deep100m_shard(topo):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= 2 << 30, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= HBM_BYTES
+
+
+#: compiled temp a chip of the deep cell's program with XLA's tile
+#: producer (described-v5e compile before the group kernel)
+DEEP_XLA_TILE_TEMP = 1_756_326_400
+
+
+def _assert_no_large_copy(txt: str, collection, limit: int):
+    """No ``copy`` or ``transpose`` in the optimized HLO ``txt`` writes
+    the collection (``collection`` [rows, dim], either way round) or any
+    f32 array of ``limit`` elements or more."""
+    import re
+
+    for ln in txt.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%\S+ = .*? (copy|copy-start|transpose)\(",
+                      ln)
+        if not op:
+            continue
+        for dims in re.findall(r"f32\[([\d,]+)\]", ln.split(op.group(1))[0]):
+            shape = [int(d) for d in dims.split(",")]
+            assert shape not in (list(collection), list(collection)[::-1]), \
+                ln[:200]
+            assert np.prod(shape) < limit, ln[:200]
+
+
+def _kernel_vmem_fits(txt: str, q_tile: int, dim: int, plan):
+    """Every Mosaic kernel of ``txt`` takes no more scoped VMEM than the
+    terms the step planner solved with give for its step."""
+    import re
+
+    kernels = [ln for ln in txt.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels, "no group kernel in the program"
+    t = pk.group_scan_vmem_terms(q_tile, dim, plan.lanes_rows)
+    rows, q = plan.gb * 128, pk._sublanes(q_tile)
+    planned = (t["outer_bytes"] * rows + t["inner_bytes"] * q
+               + t["cell_bytes"] * rows * q)
+    assert planned <= pk.DEFAULT_VMEM_BUDGET
+    for ln in kernels:
+        used = re.search(r'"used_scoped_memory_configs":\[\{[^}]*"size":'
+                         r'"(\d+)"', ln)
+        assert used and int(used.group(1)) <= planned, ln[:200]
+
+
+def test_sharded_knn_group_kernel_at_deep100m_shard(topo):
+    """The deep100m-exact cell's program as a v5e compiles it, with each
+    distance tile and its group minima made by the group kernel: the
+    kernel is there, within its planned VMEM; no copy holds a
+    [1000, ~208k] distance tile (the tile is written groups-major and the
+    gather reads it as a bitcast) or the f32[3125000, 96] shard (the
+    kernel reads its [96, rows] view, a bitcast of the v5e's layout of a
+    96-wide array); and the temp is no more than with XLA's tile."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from raft_tpu import Resources
+    from raft_tpu.neighbors import brute_force
+    from raft_tpu.obs import explain as obs_explain
+    from raft_tpu.parallel import comms as comms_mod
+    from raft_tpu.parallel import sharded
+
+    comms = comms_mod.init_comms(list(topo.devices), axis="data")
+    q = jax.ShapeDtypeStruct((1000, 96), jnp.float32,
+                             sharding=NamedSharding(comms.mesh, P()))
+    x = jax.ShapeDtypeStruct((12_500_000, 96), jnp.float32,
+                             sharding=NamedSharding(comms.mesh,
+                                                    P("data", None)))
+    res = Resources(workspace_limit_bytes=int(V5E_BYTES_LIMIT * 0.25))
+    with obs_explain.capture() as cap:
+        compiled = jax.jit(lambda q, x: sharded.knn(comms, q, x, 100,
+                                                    res=res)
+                           ).lower(q, x).compile()
+    sharded.plan_cache_clear()
+    (rec,) = [r for r in cap.records if r.family == "brute_force_group_scan"]
+    assert (rec.engine, rec.reason) == ("pallas", "group_kernel")
+    assert rec.plan["rows_on_lanes"] and not rec.plan["interpret"]
+    plan = brute_force.GroupScan("pallas", "group_kernel",
+                                 rec.plan["rows_per_step"] // 128, True)
+    assert plan.gb == 4
+    txt = compiled.as_text()
+    _kernel_vmem_fits(txt, 1000, 96, plan)
+    _assert_no_large_copy(txt, (3_125_000, 96), 1000 * 200_000)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= DEEP_XLA_TILE_TEMP, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("nq,n,dim,k,lanes_rows", [
+    (1000, 1_000_000, 128, 100, False),
+    (64, 1_000_000, 128, 10, False),
+    (64, 1_000_000, 96, 10, True),
+    (64, 500_000, 768, 10, False),
+    (64, 500_000, 960, 10, True),
+], ids=["sift_1000q", "sift_64q", "deep_64q", "768d_64q", "960d_64q"])
+def test_exact_scan_group_kernel_reads_collection_in_place(
+        topo, nq, n, dim, k, lanes_rows):
+    """The single-chip exact scan with the group kernel, as a v5e compiles
+    it at the width of each collection: the kernel reads the collection in
+    the layout the chip gives it — the [dim, rows] view where that is a
+    bitcast (``pk.rows_on_lanes``: rows on the lanes), [rows, dim] blocks
+    where it is not (SIFT's 128, 768) — so no copy or transpose of the
+    collection, nor of a distance tile, is made; up to 128 wide, each
+    kernel fits its planned VMEM."""
+    from jax.sharding import SingleDeviceSharding
+
+    from raft_tpu.neighbors import brute_force
+    from raft_tpu.ops.distance import DistanceType
+
+    dev = topo.devices[0]
+    one = SingleDeviceSharding(dev)
+    budget = int(V5E_BYTES_LIMIT * 0.25)
+    q_tile, db_tile = brute_force.choose_tiles(nq, n, dim, k, budget)
+    plan = brute_force.plan_group_scan(DistanceType.L2Expanded, jnp.float32,
+                                       dev, q_tile, n, db_tile, dim, k)
+    assert plan.producer == "pallas" and plan.lanes_rows == lanes_rows
+    compiled = jax.jit(lambda q, x, xn: brute_force.knn_core(
+        q, x, xn, jnp.zeros((0,), jnp.uint32), DistanceType.L2Expanded, 2.0,
+        k, q_tile, db_tile, budget, group_scan=plan)).lower(
+        *(jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
+          for s in ((nq, dim), (n, dim), (n,)))).compile()
+    txt = compiled.as_text()
+    if dim <= 128:  # wider rows beside 64 queries: the model is 1–3% under
+        _kernel_vmem_fits(txt, q_tile, dim, plan)
+    _assert_no_large_copy(txt, (n, dim), 50_000_000)
+
+
+@pytest.mark.parametrize("dim", [96, 100, 127, 128, 129, 256, 768, 960])
+def test_rows_on_lanes_is_the_v5e_parameter_layout(topo, dim):
+    """``pk.rows_on_lanes`` reads the layout the v5e compiler gives a
+    [rows, dim] float32 argument: rows on the lanes (``{0,1}``) where that
+    pads less than rows-major."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    dev = topo.devices[0]
+    x = jax.ShapeDtypeStruct((1_000_000, dim), jnp.float32,
+                             sharding=SingleDeviceSharding(dev))
+    txt = jax.jit(lambda x: x.sum(0)).lower(x).compile().as_text()
+    layout = re.search(r"entry_computation_layout=\{\(f32\[[\d,]+\]\{([\d,]+)",
+                       txt).group(1)
+    assert pk.rows_on_lanes(dev, jnp.float32, (1_000_000, dim)) == (
+        layout == "0,1"), layout

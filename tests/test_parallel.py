@@ -147,6 +147,46 @@ def test_sharded_knn_local_scan_matches_direct(comms4, monkeypatch, n, k,
     np.testing.assert_array_equal(np.asarray(d), want_d)
 
 
+@pytest.mark.parametrize("n,k,db_tile,metric", [
+    (79_990, 10, 6_016, "sqeuclidean"),
+    (120_000, 100, 12_928, "sqeuclidean"),
+    (80_000, 10, 6_400, "inner_product"),
+], ids=["padded_last_shard", "k100_groups", "inner_product"])
+def test_sharded_knn_group_kernel_matches_xla(comms4, monkeypatch, n, k,
+                                              db_tile, metric):
+    """The local scans over the group kernel's tiles (interpreted, inside
+    the shard_map) answer as over XLA's: the last shard's padding rows
+    never answer, the same ids, distances within 4 ulp of ‖q‖² + ‖x‖²
+    (XLA:CPU rounds the two dots apart by a few; bit-equal on a v5e)."""
+    from raft_tpu.obs import explain as obs_explain
+    from raft_tpu.obs.metrics import REGISTRY
+
+    rng = np.random.default_rng(n + k)
+    x = rng.standard_normal((n, 8)).astype(np.float32)
+    q = rng.standard_normal((16, 8)).astype(np.float32)
+    monkeypatch.setattr(brute_force, "choose_tiles",
+                        lambda nq, *a: (nq, db_tile))
+    want_d, want_i = sharded.knn(comms4, q, x, k, metric=metric)
+    monkeypatch.setattr(brute_force, "_GROUP_KERNEL_PLATFORMS",
+                        ("tpu", "cpu"))
+    sharded.plan_cache_clear()
+    jax.clear_caches()
+    plans = REGISTRY.get("raft_tpu_group_scan_plans_total")
+    before = dict((key, c.value) for key, c in plans.collect())
+    with obs_explain.capture() as cap:
+        d, i = sharded.knn(comms4, q, x, k, metric=metric)
+    assert [r.engine for r in cap.records
+            if r.family == "brute_force_group_scan"] == ["pallas"]
+    after = dict((key, c.value) for key, c in plans.collect())
+    assert after[("pallas",)] - before.get(("pallas",), 0) == 1
+    assert int(np.asarray(i).max()) < n
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
+    tol = 4 * np.spacing(np.float32((q * q).sum(1).max()
+                                    + (x * x).sum(1).max()))
+    np.testing.assert_allclose(np.asarray(d), np.asarray(want_d), rtol=0,
+                               atol=tol)
+
+
 def test_sharded_knn_compiles_once_per_shape(comms4):
     from raft_tpu.obs import device as obs_device
 

@@ -58,20 +58,51 @@ def test_fused_l2_argmin_matches_oracle(clustered):
 # --------------------------------------------------------------- select_k
 
 
-def test_group_scan_exact_on_chip(rng):
-    """The exact brute-force scan's group minima (brute_force._group_topk)
-    answer as one DIRECT top-k over the whole row, bit for bit."""
+@pytest.mark.parametrize("nq,n,dim,k,metric", [
+    (64, 40_000, 32, 10, "sqeuclidean"),
+    (1000, 420_000, 96, 100, "sqeuclidean"),
+    (1000, 300_000, 96, 100, "inner_product"),
+    (64, 300_000, 128, 10, "sqeuclidean"),
+    (1000, 420_000, 128, 100, "sqeuclidean"),
+    (1000, 300_000, 128, 100, "inner_product"),
+], ids=["small", "two_tiles", "inner_product", "sift_width_small",
+        "sift_width_two_tiles", "sift_width_inner_product"])
+def test_group_scan_exact_on_chip(rng, nq, n, dim, k, metric):
+    """The exact brute-force scan's group minima (brute_force._group_topk),
+    over the tiles and minima of the group kernel (pk.group_scan_tile),
+    answer as one DIRECT top-k over the whole row: the same ids, ties to
+    the lower row, and distances within 2 ulp of ‖q‖² + ‖x‖², the size of
+    the terms the expanded distance cancels (the kernel's dot and XLA's
+    may round apart; on a v5e they were bit-equal, PERF.md). The chip
+    keeps a 32- or 96-wide collection with its rows on the lanes, and the
+    kernel reads its [dim, rows] view; a 128-wide one rows-major, read in
+    [rows, dim] blocks."""
     from raft_tpu.neighbors import brute_force
-    from raft_tpu.ops.distance import l2_expanded, row_norms_sq
+    from raft_tpu.obs import explain as obs_explain
+    from raft_tpu.ops.distance import (inner_product, l2_expanded,
+                                       row_norms_sq)
     from raft_tpu.ops.select_k import SelectAlgo, select_k
 
-    db = rng.standard_normal((40_000, 32)).astype(np.float32)
-    q = rng.standard_normal((64, 32)).astype(np.float32)
-    v, i = brute_force.search(brute_force.build(db), q, 10)
-    row = l2_expanded(q, db, False, y_norms=row_norms_sq(db))
-    want_v, want_i = select_k(row, 10, algo=SelectAlgo.DIRECT)
+    centers = rng.standard_normal((64, dim)).astype(np.float32) * 3.0
+    db = centers[rng.integers(0, 64, n)] + rng.standard_normal(
+        (n, dim)).astype(np.float32)
+    q = centers[rng.integers(0, 64, nq)] + rng.standard_normal(
+        (nq, dim)).astype(np.float32)
+    with obs_explain.capture() as cap:
+        v, i = brute_force.search(brute_force.build(db, metric=metric), q, k)
+    scan = [r for r in cap.records if r.family == "brute_force_group_scan"]
+    assert [r.engine for r in scan] == ["pallas"], cap.briefs()
+    assert scan[0].plan["rows_on_lanes"] == (dim < 128)
+    if metric == "inner_product":
+        row = inner_product(jnp.asarray(q), jnp.asarray(db))
+    else:
+        row = l2_expanded(q, db, False, y_norms=row_norms_sq(db))
+    want_v, want_i = select_k(row, k, select_min=metric != "inner_product",
+                              algo=SelectAlgo.DIRECT)
+    scale = float((q * q).sum(1).max() + (db * db).sum(1).max())
     np.testing.assert_array_equal(np.asarray(i), np.asarray(want_i))
-    np.testing.assert_array_equal(np.asarray(v), np.asarray(want_v))
+    np.testing.assert_allclose(np.asarray(v), np.asarray(want_v), rtol=0,
+                               atol=2 * np.spacing(np.float32(scale)))
 
 
 def test_approx_select_recall_on_chip(rng):
