@@ -439,6 +439,7 @@ ATTRIBUTION_CALLS = frozenset({
     "raft_tpu.obs.explain.record_dispatch",
     "raft_tpu.obs.explain.note_select_k",
     "raft_tpu.parallel.sharded._record_plan",
+    "raft_tpu.neighbors.ivf_pq._record_scan",
     "raft_tpu.planner.adaptive.record_choice",
 })
 #: packages whose dispatch sites must be attributed
@@ -481,7 +482,8 @@ def rule_unattributed_dispatch(mod: ModuleInfo) -> list:
             dotted = mod.resolve(node.func)
             if dotted and "." not in dotted:
                 # bare call to a module-local helper (plan_sharded_search
-                # and _record_plan live beside their call sites)
+                # and _record_plan/_record_scan live beside their call
+                # sites)
                 dotted = f"{mod.modname}.{dotted}"
             if dotted in DISPATCH_CALLS:
                 dispatch_nodes.append(node)

@@ -25,6 +25,10 @@ TPU-native design:
   stream (each code spans ≤ 2 bytes); scores come from a flat LUT gather and
   a sum over subspaces. ``lut_dtype``/``internal_distance_dtype`` map to
   fp32/bf16 (fp8 LUTs are emulated with bf16 — TPUs have no fp8 gather win).
+- **Precision**: the float contractions of the scan, the LUT build and the
+  encoder take their precision from the dtypes the caller states
+  (:func:`contraction_precision`): ``HIGHEST`` where all are float32,
+  ``DEFAULT`` (one bfloat16 pass on a TPU) where one is bfloat16 or fp8.
 - **Codebook training**: one jitted Lloyd-EM body ``lax.map``-ed across
   subspaces (PER_SUBSPACE) or across clusters (PER_CLUSTER), trained on
   rotated residuals, weights masking ragged membership — one compile serves
@@ -56,9 +60,31 @@ from raft_tpu.ops.select_k import select_k, select_k_maybe_approx
 from raft_tpu.neighbors import list_packing
 from raft_tpu.neighbors.brute_force import fused_ineligible_reason
 from raft_tpu.obs import explain as obs_explain
+from raft_tpu.obs import metrics as obs_metrics
 from raft_tpu.ops import rng as rrng
 from raft_tpu.utils.shape import (as_query_array, balanced_tile, cdiv, pad_rows,
                                   query_bucket)
+
+_SCAN_PLANS = obs_metrics.REGISTRY.counter(
+    "raft_tpu_ivf_pq_scan_plans_total",
+    "ivf_pq search dispatches by engine and the precision of its float "
+    "contractions.",
+    ("engine", "precision"))
+
+
+def contraction_precision(*dtypes) -> jax.lax.Precision:
+    """The precision of a float contraction of the scan, the LUT build or
+    the encoder, from the dtypes the caller stated for it
+    (``internal_distance_dtype`` and the cache's or the LUT's dtype, as
+    RAFT's ``internalDistanceDtype``): ``HIGHEST`` where every one is
+    float32, else ``DEFAULT``. A TPU computes a float32 contraction at
+    ``DEFAULT`` in one bfloat16 pass, and the expanded ADC form
+    ``‖q_res‖² − 2·q_res·dec + ‖dec‖²`` cancels, so that pass costs
+    percents of the k-th distance; a bfloat16 or fp8 operand holds no
+    more than the one pass keeps."""
+    if all(jnp.dtype(d) == jnp.float32 for d in dtypes):
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
 
 
 class CodebookGen(enum.IntEnum):
@@ -135,7 +161,8 @@ class SearchParams:
     scan_mode: str = "auto"
     # dtype of the decoded scan cache: bf16 (default; halves scan HBM
     # traffic, ~1e-3 recall cost — the reference's fp16/fp8-LUT trade) or
-    # float32 (bit-exact vs the LUT path).
+    # float32 (with a float32 internal_distance_dtype the scan contracts at
+    # HIGHEST: the LUT engine's distances to float32 rounding).
     scan_cache_dtype: object = jnp.bfloat16
     # <1.0 routes internal top-k through the TPU PartialReduce engine
     # (ops.select_k APPROX) at this per-element recall target; exact by
@@ -441,8 +468,12 @@ def ensure_scan_cache(index: Index, dtype=jnp.bfloat16) -> None:
     """Build the decoded-residual scan cache if absent (idempotent).
 
     bf16 (default) halves scan HBM traffic for ~1e-3 recall — the same
-    precision/bandwidth trade the reference's fp16/fp8 LUTs make; pass
-    ``dtype=jnp.float32`` for bit-exact parity with the LUT path."""
+    precision/bandwidth trade the reference's fp16/fp8 LUTs make, and the
+    scan over it contracts at ``DEFAULT``. ``dtype=jnp.float32`` keeps the
+    decoded residuals whole; with a float32 ``internal_distance_dtype``
+    the scan then contracts at ``HIGHEST`` (:func:`contraction_precision`)
+    and gives the LUT engine's distances to float32 rounding, on a TPU as
+    on the CPU."""
     if index.list_codes is None:
         return
     if (index.list_decoded is not None
@@ -510,6 +541,7 @@ def _encode_jit(x, labels, centers, rotation, codebooks, per_cluster: bool,
     pad = n_tiles * row_tile - n
     xp = jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0)))
     lp = jnp.pad(labels, (0, pad))
+    prec = contraction_precision(codebooks.dtype)
 
     def tile_body(args):
         xt, lt = args
@@ -523,12 +555,14 @@ def _encode_jit(x, labels, centers, rotation, codebooks, per_cluster: bool,
         if per_cluster:
             cb = codebooks[lt]  # [t, book, l]
             dots = jnp.einsum("tsl,tcl->tsc", sub, cb,
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=prec)
             cn = jnp.sum(cb * cb, -1)  # [t, book]
             d = cn[:, None, :] - 2.0 * dots
         else:
             dots = jnp.einsum("tsl,scl->tsc", sub, codebooks,
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=prec)
             cn = jnp.sum(codebooks * codebooks, -1)  # [s, book]
             d = cn[None, :, :] - 2.0 * dots
         return jnp.argmin(d, axis=-1).astype(jnp.int32)  # [t, s]
@@ -791,15 +825,18 @@ def _merge_pq_overflow(index: Index, new_codes_np, new_labels_np,
 
 def _pq_overflow_scan(q_rot, overflow_decoded, overflow_norms,
                       overflow_indices, filter_words,
-                      metric: DistanceType, has_filter: bool, bad_fill):
+                      metric: DistanceType, has_filter: bool, bad_fill,
+                      precision: jax.lax.Precision):
     """Distances of one query tile against the decoded overflow block
     (FULL rotated vectors: center + residual — see ensure_overflow_decoded)
-    in the same squared-L2 / IP space as the probed-list scan: [t, O]
-    distances + broadcast ids, ready for the final select_k."""
+    in the same squared-L2 / IP space as the probed-list scan, contracted
+    at the scan's ``precision``: [t, O] distances + broadcast ids, ready
+    for the final select_k."""
     dots = jax.lax.dot_general(
         q_rot, overflow_decoded.astype(jnp.float32),
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=precision,
     )  # [t, O]
     if metric == DistanceType.InnerProduct:
         od = dots  # q_rot·v = q·center + q_rot·dec (rotation orthonormal)
@@ -823,13 +860,17 @@ def _search_cache_core(queries, centers, rotation, list_decoded,
                        pallas_interpret: bool = False,
                        overflow_decoded=None, overflow_norms=None,
                        overflow_indices=None, has_overflow: bool = False,
-                 select_recall: float = 1.0):
+                       select_recall: float = 1.0,
+                       dist_dtype: str = "float32"):
     """ADC scan over the decoded-residual cache: identical distances to the
     LUT formulation (||q_res − dec||² expands to ||q_res||² − 2 q_res·dec +
-    ||dec||²), evaluated as one batched matvec per probe on the MXU."""
+    ||dec||²), evaluated as one batched matvec per probe on the MXU, at the
+    precision the cache's dtype and ``dist_dtype`` (the internal distance
+    dtype) state (:func:`contraction_precision`)."""
     nq, dim = queries.shape
     n_lists, list_pad, rot_dim = list_decoded.shape
     minimize = metric != DistanceType.InnerProduct
+    prec = contraction_precision(list_decoded.dtype, dist_dtype)
 
     def _sel(vals, kk, sel_min):
         return select_k_maybe_approx(vals, kk, sel_min, select_recall)
@@ -891,7 +932,8 @@ def _search_cache_core(queries, centers, rotation, list_decoded,
             # score = q·center + q_rot·dec
             dots = jnp.einsum("td,tpld->tpl", q_rot,
                               g_dec.astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=prec)
             base = jnp.take_along_axis(dots_c, probes, axis=1)
             d = base[:, :, None] + dots
         else:
@@ -900,7 +942,8 @@ def _search_cache_core(queries, centers, rotation, list_decoded,
             qr_res = q_rot[:, None, :] - centers_rot[probes]  # [t, P, rot]
             dots = jnp.einsum("tpd,tpld->tpl", qr_res,
                               g_dec.astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=prec)
             qn = jnp.sum(qr_res * qr_res, -1)  # [t, P]
             d = qn[:, :, None] - 2.0 * dots + g_n
 
@@ -917,7 +960,7 @@ def _search_cache_core(queries, centers, rotation, list_decoded,
             od, oi = _pq_overflow_scan(q_rot, overflow_decoded,
                                        overflow_norms, overflow_indices,
                                        filter_words, metric, has_filter,
-                                       bad_fill)
+                                       bad_fill, prec)
             flat_d = jnp.concatenate([flat_d, od], axis=1)
             flat_i = jnp.concatenate([flat_i, oi], axis=1)
             n_cand += od.shape[1]
@@ -945,7 +988,7 @@ _search_cache_jit = jax.jit(
     _search_cache_core,
     static_argnames=("metric", "k", "n_probes", "q_tile", "has_filter",
                      "use_pallas", "pallas_interpret", "has_overflow",
-                     "select_recall"),
+                     "select_recall", "dist_dtype"),
 )
 
 #: public traceable-core names — the cross-package contract for the
@@ -977,13 +1020,16 @@ def _search_lut_core(queries, centers, rotation, codebooks, list_codes,
     VALUES are bit-identical to the single-pass shape (each candidate's
     contraction is elementwise the same); only tie ORDER among equal
     distances can differ, because the running merge re-ranks ties by
-    carry position rather than global flat index."""
+    carry position rather than global flat index. The LUT build and the
+    overflow block contract at the precision ``lut_dtype`` and
+    ``dist_dtype`` state (:func:`contraction_precision`)."""
     nq, dim = queries.shape
     n_lists, list_pad, _ = list_codes.shape
     pq_len = codebooks.shape[2]
     book = codebooks.shape[1]
     minimize = metric != DistanceType.InnerProduct
     p_tile = probe_tile if 0 < probe_tile < n_probes else n_probes
+    prec = contraction_precision(lut_dtype, dist_dtype)
 
     def _sel(vals, kk, sel_min):
         return select_k_maybe_approx(vals, kk, sel_min, select_recall)
@@ -1037,11 +1083,13 @@ def _search_lut_core(queries, centers, rotation, codebooks, list_codes,
             if per_cluster:
                 cb_p = codebooks[probes_blk]  # [t, pt, book, l]
                 dots = jnp.einsum("tpsl,tpcl->tpsc", sub, cb_p,
-                                  preferred_element_type=jnp.float32)
+                                  preferred_element_type=jnp.float32,
+                                  precision=prec)
                 cbn = cb_norms[probes_blk][:, :, None, :]
             else:
                 dots = jnp.einsum("tpsl,scl->tpsc", sub, codebooks,
-                                  preferred_element_type=jnp.float32)
+                                  preferred_element_type=jnp.float32,
+                                  precision=prec)
                 cbn = cb_norms[None, None, :, :]  # [1, 1, s, book]
             if metric == DistanceType.InnerProduct:
                 # score = q·center + Σ_s q_sub·cb[code_s]
@@ -1132,7 +1180,7 @@ def _search_lut_core(queries, centers, rotation, codebooks, list_codes,
             od, oi = _pq_overflow_scan(q_rot, overflow_decoded,
                                        overflow_norms, overflow_indices,
                                        filter_words, metric, has_filter,
-                                       bad_fill)
+                                       bad_fill, prec)
             flat_d = jnp.concatenate([flat_d, od], axis=1)
             flat_i = jnp.concatenate([flat_i, oi], axis=1)
             n_cand += od.shape[1]
@@ -1196,14 +1244,16 @@ def _coarse_probes_rot(queries, centers, rotation, n_probes: int):
 def _fused_merge_overflow(v, i, q_rot, overflow_decoded, overflow_norms,
                           overflow_indices, k: int):
     """Merge the kernel's VMEM-carry survivors with the XLA overflow scan
-    (squared space on both sides). Selection already happened in-kernel,
+    (squared space on both sides; at ``HIGHEST``, as the kernels
+    contract). Selection already happened in-kernel,
     so the merge select runs with ``pad_rules=False`` — TOPK_PAD models an
     HBM slab select and must not re-pad the short candidate list
     (ISSUE 10)."""
     od, oi = _pq_overflow_scan(q_rot, overflow_decoded, overflow_norms,
                                overflow_indices,
                                jnp.zeros((0,), jnp.uint32),
-                               DistanceType.L2Expanded, False, jnp.inf)
+                               DistanceType.L2Expanded, False, jnp.inf,
+                               jax.lax.Precision.HIGHEST)
     return select_k(jnp.concatenate([v, od], axis=1), k, select_min=True,
                     indices=jnp.concatenate([i, oi], axis=1),
                     pad_rules=False)
@@ -1408,6 +1458,18 @@ def resolve_scan_mode(n_lists: int, list_pad: int, rot_dim: int,
     return "cache" if packed_bytes + cache_bytes <= budget else "lut"
 
 
+def _record_scan(requested: str, engine: str, reason: str, params: dict,
+                 plan: dict, precision: jax.lax.Precision) -> None:
+    """A search dispatch's explain record, whose plan names the precision
+    of the engine's float contractions, and its count in
+    ``raft_tpu_ivf_pq_scan_plans_total``."""
+    name = precision.name.lower()
+    _SCAN_PLANS.labels(engine, name).inc()
+    obs_explain.record_dispatch("ivf_pq", requested, engine, reason,
+                                params=params,
+                                plan={**plan, "precision": name})
+
+
 @tracing.range("ivf_pq.search")
 def search(
     index: Index,
@@ -1483,11 +1545,10 @@ def search(
                     list_pad, index.rot_dim, int(k),
                     jnp.dtype(index.list_decoded.dtype).itemsize,
                     n_probes=n_probes)
-                obs_explain.record_dispatch(
-                    "ivf_pq", requested, "pallas_cache", dreason,
-                    params=ex_params,
-                    plan={"memory_model": "cache", "pad_tile": pad_tile,
-                          "interpret": fused_interp})
+                _record_scan(requested, "pallas_cache", dreason, ex_params,
+                             {"memory_model": "cache", "pad_tile": pad_tile,
+                              "interpret": fused_interp},
+                             jax.lax.Precision.HIGHEST)
                 v, i = _search_fused_cache_jit(
                     queries, index.centers, index.rotation,
                     index.list_decoded, index.decoded_norms,
@@ -1503,11 +1564,10 @@ def search(
                 pad_tile = pk.plan_fused_pq_tile(
                     list_pad, index.pq_dim, 1 << index.pq_bits,
                     index.codebooks.shape[2], int(k))
-                obs_explain.record_dispatch(
-                    "ivf_pq", requested, "pallas_lut", dreason,
-                    params=ex_params,
-                    plan={"memory_model": "lut", "pad_tile": pad_tile,
-                          "interpret": fused_interp})
+                _record_scan(requested, "pallas_lut", dreason, ex_params,
+                             {"memory_model": "lut", "pad_tile": pad_tile,
+                              "interpret": fused_interp},
+                             jax.lax.Precision.HIGHEST)
                 v, i = _search_fused_lut_jit(
                     queries, index.centers, index.rotation, index.codebooks,
                     index.list_codes, index.list_indices, index.list_sizes,
@@ -1543,14 +1603,16 @@ def search(
                 # dists
                 q_tile = plan_cache_tiles(n_probes, list_pad, index.rot_dim,
                                           res.workspace_limit_bytes)
-                obs_explain.record_dispatch(
-                    "ivf_pq", requested, "cache", reason, params=ex_params,
-                    plan={"memory_model": "cache",
-                          "memory_auto": memory_resolved,
-                          "q_tile": q_tile,
-                          "predicted_workspace_bytes": q_tile *
-                          cache_bytes_per_query(n_probes, list_pad,
-                                                index.rot_dim)})
+                dist_dtype = jnp.dtype(params.internal_distance_dtype).name
+                _record_scan(requested, "cache", reason, ex_params,
+                             {"memory_model": "cache",
+                              "memory_auto": memory_resolved,
+                              "q_tile": q_tile,
+                              "predicted_workspace_bytes": q_tile *
+                              cache_bytes_per_query(n_probes, list_pad,
+                                                    index.rot_dim)},
+                             contraction_precision(index.list_decoded.dtype,
+                                                   dist_dtype))
                 v, i = _search_cache_jit(
                     queries, index.centers, index.rotation,
                     index.list_decoded, index.decoded_norms,
@@ -1566,6 +1628,7 @@ def search(
                     index.overflow_decoded, index.overflow_norms,
                     index.overflow_indices, has_overflow,
                     select_recall=float(params.select_recall),
+                    dist_dtype=dist_dtype,
                 )
             else:
                 # workspace: the TRUE peak live set of the scan body (LUT
@@ -1573,22 +1636,21 @@ def search(
                 # lut_bytes_per_query_probe), solved jointly into
                 # (q_tile, probe_tile) so the engine never materializes more
                 # than the budget however large n·n_probes grow
+                lut_dtype = jnp.dtype(params.lut_dtype)
+                dist_dtype = jnp.dtype(params.internal_distance_dtype)
                 q_tile, probe_tile = plan_lut_tiles(
                     n_probes, list_pad, index.pq_dim, index.pq_bits,
-                    res.workspace_limit_bytes,
-                    jnp.dtype(params.lut_dtype).itemsize,
-                    jnp.dtype(params.internal_distance_dtype).itemsize)
-                obs_explain.record_dispatch(
-                    "ivf_pq", requested, "lut", reason, params=ex_params,
-                    plan={"memory_model": "lut",
-                          "memory_auto": memory_resolved,
-                          "q_tile": q_tile, "probe_tile": probe_tile,
-                          "predicted_workspace_bytes": q_tile * probe_tile *
-                          lut_bytes_per_query_probe(
-                              list_pad, index.pq_dim, index.pq_bits,
-                              jnp.dtype(params.lut_dtype).itemsize,
-                              jnp.dtype(params.internal_distance_dtype)
-                              .itemsize)})
+                    res.workspace_limit_bytes, lut_dtype.itemsize,
+                    dist_dtype.itemsize)
+                _record_scan(requested, "lut", reason, ex_params,
+                             {"memory_model": "lut",
+                              "memory_auto": memory_resolved,
+                              "q_tile": q_tile, "probe_tile": probe_tile,
+                              "predicted_workspace_bytes": q_tile *
+                              probe_tile * lut_bytes_per_query_probe(
+                                  list_pad, index.pq_dim, index.pq_bits,
+                                  lut_dtype.itemsize, dist_dtype.itemsize)},
+                             contraction_precision(lut_dtype, dist_dtype))
                 v, i = _search_jit(
                     queries, index.centers, index.rotation, index.codebooks,
                     index.list_codes, index.list_indices, index.list_sizes,
@@ -1596,8 +1658,7 @@ def search(
                     else jnp.zeros((0,), jnp.uint32),
                     index.metric, int(k), n_probes, q_tile, per_cluster,
                     index.pq_dim, index.pq_bits, filter is not None,
-                    jnp.dtype(params.lut_dtype).name, jnp.dtype(
-                        params.internal_distance_dtype).name,
+                    lut_dtype.name, dist_dtype.name,
                     index.overflow_decoded, index.overflow_norms,
                     index.overflow_indices, has_overflow,
                     select_recall=float(params.select_recall),

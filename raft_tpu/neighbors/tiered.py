@@ -577,6 +577,7 @@ def tiered_scan_core(queries, centers, rotation, arena_dec, arena_norms,
     nq, dim = queries.shape
     slots, list_pad, rot_dim = arena_dec.shape
     minimize = metric != DistanceType.InnerProduct
+    prec = ivf_pq.contraction_precision(arena_dec.dtype)
 
     def _sel(vals, kk, sel_min):
         return select_k_maybe_approx(vals, kk, sel_min, select_recall)
@@ -612,7 +613,8 @@ def tiered_scan_core(queries, centers, rotation, arena_dec, arena_norms,
             g_dec = arena_dec[slotp]  # [t, P, pad, rot] bf16
             dots = jnp.einsum("td,tpld->tpl", q_rot,
                               g_dec.astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=prec)
             base = jnp.take_along_axis(dots_c, probes, axis=1)
             d = base[:, :, None] + dots
         else:
@@ -621,7 +623,8 @@ def tiered_scan_core(queries, centers, rotation, arena_dec, arena_norms,
             qr_res = q_rot[:, None, :] - centers_rot[probes]  # [t, P, rot]
             dots = jnp.einsum("tpd,tpld->tpl", qr_res,
                               g_dec.astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=prec)
             qn = jnp.sum(qr_res * qr_res, -1)  # [t, P]
             d = qn[:, :, None] - 2.0 * dots + g_n
 
@@ -634,7 +637,7 @@ def tiered_scan_core(queries, centers, rotation, arena_dec, arena_norms,
         if has_overflow:
             od, oi = ivf_pq._pq_overflow_scan(
                 q_rot, overflow_decoded, overflow_norms, overflow_indices,
-                jnp.zeros((0,), jnp.uint32), metric, False, bad_fill)
+                jnp.zeros((0,), jnp.uint32), metric, False, bad_fill, prec)
             flat_d = jnp.concatenate([flat_d, od], axis=1)
             flat_i = jnp.concatenate([flat_i, oi], axis=1)
             n_cand += od.shape[1]

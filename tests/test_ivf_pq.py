@@ -532,3 +532,142 @@ def test_resolve_scan_mode_lut_at_1m_shape_with_fitting_tiles():
     q16, p16 = ivf_pq.plan_lut_tiles(n_probes, list_pad, pq_dim, pq_bits,
                                      (16 << 30) // 4)
     assert q16 * p16 * per_qp <= (16 << 30) // 4
+
+
+# ------------------------------------------------ precision of the contractions
+
+_F32, _BF16 = jnp.float32, jnp.bfloat16
+
+
+def _dot_precisions(lowered) -> list:
+    """The operand precision of each ``dot_general`` of a lowered program,
+    in program order (an op without the attribute is at DEFAULT)."""
+    import re
+
+    out = []
+    for line in lowered.as_text().splitlines():
+        if "stablehlo.dot_general" in line:
+            m = re.search(r"precision = \[(\w+),", line)
+            out.append(m.group(1) if m else "DEFAULT")
+    return out
+
+
+def _lower_scan(engine: str, overflow: bool, table, dist):
+    """The cache or LUT engine lowered at a tiny size, its cache or LUT
+    in ``table`` and its distances in ``dist``; the overflow block (8
+    rows) is decoded in the table's dtype, as ``search`` decodes it."""
+    import jax
+
+    from raft_tpu.ops.distance import DistanceType
+
+    def s(shape, dt=_F32):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    n_lists, pad, rot, dim, pq_dim = 8, 16, 16, 16, 8
+    n_over = 8 if overflow else 0
+    head = (s((24, dim)), s((n_lists, dim)), s((rot, dim)))
+    tail = (s((n_lists, pad), jnp.int32), s((n_lists,), jnp.int32),
+            s((0,), jnp.uint32))
+    over = dict(overflow_decoded=s((n_over, rot), table),
+                overflow_norms=s((n_over,)),
+                overflow_indices=s((n_over,), jnp.int32),
+                has_overflow=overflow)
+    common = dict(metric=DistanceType.L2Expanded, k=5, n_probes=3,
+                  q_tile=8, has_filter=False)
+    if engine == "cache":
+        return ivf_pq._search_cache_jit.lower(
+            *head, s((n_lists, pad, rot), table), s((n_lists, pad)), *tail,
+            use_pallas=False, pallas_interpret=False,
+            dist_dtype=jnp.dtype(dist).name, **common, **over)
+    return ivf_pq._search_jit.lower(
+        *head, s((pq_dim, 256, rot // pq_dim)),
+        s((n_lists, pad, pq_dim), jnp.uint8), *tail, per_cluster=False,
+        pq_dim=pq_dim, pq_bits=8, lut_dtype=jnp.dtype(table).name,
+        dist_dtype=jnp.dtype(dist).name, **common, **over)
+
+
+@pytest.mark.parametrize("dtypes,want", [
+    ((_F32,), "HIGHEST"),
+    ((_F32, "float32"), "HIGHEST"),
+    ((_BF16, _F32), "DEFAULT"),
+    ((_F32, "bfloat16"), "DEFAULT"),
+    ((jnp.float8_e4m3fn, _F32), "DEFAULT"),
+])
+def test_contraction_precision_follows_stated_dtypes(dtypes, want):
+    assert ivf_pq.contraction_precision(*dtypes).name == want
+
+
+@pytest.mark.parametrize("engine", ["cache", "lut"])
+@pytest.mark.parametrize("overflow", [False, True],
+                         ids=["lists", "overflow"])
+def test_float32_scan_lowers_every_contraction_at_highest(engine, overflow):
+    """With float32 stated throughout, every contraction of the engine —
+    the coarse step, the cache scan or the LUT build, and the overflow
+    block — is lowered at HIGHEST: on a TPU a DEFAULT float32 dot is one
+    bfloat16 pass, which the CPU suite cannot see in the answers."""
+    got = _dot_precisions(_lower_scan(engine, overflow, _F32, _F32))
+    assert got == ["HIGHEST"] * (4 + overflow)
+
+
+@pytest.mark.parametrize("engine", ["cache", "lut"])
+@pytest.mark.parametrize("overflow", [False, True],
+                         ids=["lists", "overflow"])
+def test_half_scan_lowers_its_contractions_at_default(engine, overflow):
+    """The bfloat16 path (cache or LUT and internal distances bfloat16)
+    keeps its one-pass contractions: only the three coarse steps, which
+    are always float32, carry HIGHEST."""
+    got = _dot_precisions(_lower_scan(engine, overflow, _BF16, _BF16))
+    assert got[:3] == ["HIGHEST"] * 3
+    assert got[3:] == ["DEFAULT"] * (1 + overflow)
+
+
+@pytest.mark.parametrize("per_cluster", [False, True],
+                         ids=["per_subspace", "per_cluster"])
+def test_encoder_lowers_at_highest(per_cluster):
+    """The build's encoder picks each row's code by an expanded distance
+    to the float32 codebooks, so it contracts at HIGHEST too."""
+    import jax
+
+    n, dim, n_lists, pq_dim = 64, 16, 8, 8
+    books = (n_lists if per_cluster else pq_dim, 256, dim // pq_dim)
+    lowered = ivf_pq._encode_jit.lower(
+        jax.ShapeDtypeStruct((n, dim), _F32),
+        jax.ShapeDtypeStruct((n,), jnp.int32),
+        jax.ShapeDtypeStruct((n_lists, dim), _F32),
+        jax.ShapeDtypeStruct((dim, dim), _F32),
+        jax.ShapeDtypeStruct(books, _F32), per_cluster, 32)
+    assert _dot_precisions(lowered) == ["HIGHEST", "HIGHEST"]
+
+
+@pytest.fixture(scope="module")
+def pq_index(data):
+    db, _ = data
+    return ivf_pq.build(db, ivf_pq.IndexParams(n_lists=32, pq_dim=16,
+                                               kmeans_n_iters=4))
+
+
+@pytest.mark.parametrize("engine,dtype,want", [
+    ("cache", _F32, "highest"),
+    ("cache", _BF16, "default"),
+    ("lut", _F32, "highest"),
+    ("lut", _BF16, "default"),
+])
+def test_search_records_and_counts_its_precision(pq_index, data, engine,
+                                                 dtype, want):
+    """Each dispatch names the precision of its float contractions in the
+    explain record's plan and counts it in
+    ``raft_tpu_ivf_pq_scan_plans_total{engine,precision}``."""
+    from raft_tpu.obs.metrics import REGISTRY
+
+    _, q = data
+    params = ivf_pq.SearchParams(n_probes=8, scan_mode=engine,
+                                 lut_dtype=dtype, scan_cache_dtype=dtype,
+                                 internal_distance_dtype=dtype)
+    plans = REGISTRY.get("raft_tpu_ivf_pq_scan_plans_total")
+    before = dict((key, c.value) for key, c in plans.collect())
+    _, ids, rec = ivf_pq.search(pq_index, q, 10, params, explain=True)
+    after = dict((key, c.value) for key, c in plans.collect())
+    assert (rec.engine, rec.plan["precision"]) == (engine, want)
+    assert after[(engine, want)] - before.get((engine, want), 0) == 1
+    assert sum(after.values()) - sum(before.values()) == 1
+    assert (np.asarray(ids) >= 0).all()

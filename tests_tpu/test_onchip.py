@@ -225,6 +225,70 @@ def test_ivf_pq_cache_engine_recall(pq_index, clustered, gt):
     assert rc >= rl - 0.03, f"cache engine {rc:.4f} vs lut {rl:.4f}"
 
 
+@pytest.fixture(scope="module")
+def sift_width_pq():
+    """100k × 128 clustered rows in an index of SIFT1M's cell's shape but
+    256 lists (pq_dim 64, 8-bit codes), 512 queries, and the queries'
+    exact k-th distances (float64)."""
+    from raft_tpu.neighbors import ivf_pq
+
+    rng = np.random.default_rng(25)
+    centers = rng.standard_normal((256, 128)).astype(np.float32) * 4.0
+    base = centers[rng.integers(0, 256, 100_000)] + rng.standard_normal(
+        (100_000, 128)).astype(np.float32)
+    queries = centers[rng.integers(0, 256, 512)] + rng.standard_normal(
+        (512, 128)).astype(np.float32)
+    index = ivf_pq.build(base, ivf_pq.IndexParams(n_lists=256, pq_dim=64,
+                                                  pq_bits=8))
+    b64 = base.astype(np.float64)
+    bn = (b64 * b64).sum(1)
+    kth = np.concatenate([np.partition(
+        (q * q).sum(1)[:, None] + bn[None] - 2.0 * q @ b64.T, 9, 1)[:, 9]
+        for q in np.array_split(queries.astype(np.float64), 8)])
+    return index, queries, kth
+
+
+@pytest.mark.parametrize("engine,dtype,float_path", [
+    ("cache", jnp.float32, True),
+    ("lut", jnp.float32, True),
+    ("cache", jnp.bfloat16, False),
+], ids=["cache_f32", "lut_f32", "cache_bf16"])
+def test_ivf_pq_adc_error_on_chip(sift_width_pq, engine, dtype, float_path):
+    """Each reported distance against the float64 ADC distance of the same
+    id's codes (benchmark/references/ivf_pq_adc.py), over the query's
+    exact k-th distance — the number that decides the ivfpq-sift1m-batch
+    cell's ``correct``. The float32 engines hold 1e-4 (they contract at
+    HIGHEST); the bfloat16 cache, one bfloat16 pass, reads above 1e-3, so
+    the limit separates the two."""
+    import json
+
+    from benchmark import harness
+    from raft_tpu.neighbors import ivf_pq
+
+    index, queries, kth = sift_width_pq
+    params = ivf_pq.SearchParams(n_probes=32, scan_mode=engine,
+                                 lut_dtype=dtype, scan_cache_dtype=dtype,
+                                 internal_distance_dtype=dtype)
+    d, i = ivf_pq.search(index, queries, 10, params)
+    names = ("centers", "rotation", "codebooks", "list_codes",
+             "list_indices", "list_sizes", "overflow_codes",
+             "overflow_labels", "overflow_indices")
+    view = dict(zip(names, jax.device_get(
+        [getattr(index, n) for n in names])))
+    view.update(n_rows=index.n_rows, pq_dim=index.pq_dim,
+                pq_bits=index.pq_bits, per_cluster=False)
+    adc = harness.load_module("references", "ivf_pq_adc").distances(
+        view, queries, np.asarray(i))
+    err = float((np.abs(np.asarray(d, np.float64) - adc)
+                 / kth[:, None]).max())
+    print(json.dumps({"test": "ivf_pq_adc_error", "engine": engine,
+                      "dtype": jnp.dtype(dtype).name, "adc_error": err}))
+    if float_path:
+        assert err <= 1e-4, err
+    else:
+        assert err > 1e-3, err
+
+
 def test_ivf_pq_approx_select_recall(pq_index, clustered, gt):
     """select_recall=0.95 (APPROX selection inside the search) on real
     PartialReduce hardware."""
