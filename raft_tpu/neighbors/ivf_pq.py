@@ -41,7 +41,7 @@ import contextlib
 import dataclasses
 import enum
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,9 +61,10 @@ from raft_tpu.neighbors import list_packing
 from raft_tpu.neighbors.brute_force import fused_ineligible_reason
 from raft_tpu.obs import explain as obs_explain
 from raft_tpu.obs import metrics as obs_metrics
+from raft_tpu.ops import pallas_kernels as pk
 from raft_tpu.ops import rng as rrng
 from raft_tpu.utils.shape import (as_query_array, balanced_tile, cdiv, pad_rows,
-                                  query_bucket)
+                                  query_bucket, round_up_to)
 
 _SCAN_PLANS = obs_metrics.REGISTRY.counter(
     "raft_tpu_ivf_pq_scan_plans_total",
@@ -852,6 +853,55 @@ def _pq_overflow_scan(q_rot, overflow_decoded, overflow_norms,
     return od, oi
 
 
+def _cache_probes(qt, rotation, centers_rot, metric: DistanceType,
+                  n_probes: int, sel):
+    """A query tile's rotation [t, rot], its dots with the rotated centres
+    [t, L] and its ``n_probes`` lists (nearest, or of largest dot for
+    inner product, by ``sel``): the decoded-cache cores' coarse step."""
+    q_rot = jax.lax.dot_general(
+        qt, rotation, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    dots_c = jax.lax.dot_general(
+        q_rot, centers_rot, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    if metric == DistanceType.InnerProduct:
+        _, probes = sel(dots_c, n_probes, False)
+    else:
+        cn = jnp.sum(centers_rot * centers_rot, -1)
+        _, probes = sel(cn[None, :] - 2.0 * dots_c, n_probes, True)
+    return q_rot, dots_c, probes
+
+
+def _cache_answers(flat_d, flat_i, q_rot, overflow_decoded, overflow_norms,
+                   overflow_indices, filter_words, metric: DistanceType,
+                   k: int, has_filter: bool, has_overflow: bool, precision,
+                   sel):
+    """The k best of a query tile's candidates ``flat_d``/``flat_i`` [t, n]
+    and the overflow block's, by ``sel``: padded past the candidates
+    (the worst value, id -1), the sqrt taken for L2Sqrt."""
+    minimize = metric != DistanceType.InnerProduct
+    bad_fill = jnp.inf if minimize else -jnp.inf
+    if has_overflow:
+        od, oi = _pq_overflow_scan(q_rot, overflow_decoded, overflow_norms,
+                                   overflow_indices, filter_words, metric,
+                                   has_filter, bad_fill, precision)
+        flat_d = jnp.concatenate([flat_d, od], axis=1)
+        flat_i = jnp.concatenate([flat_i, oi], axis=1)
+    kk = min(k, flat_d.shape[1])
+    v, sel_i = sel(flat_d, kk, minimize)
+    i_out = jnp.take_along_axis(flat_i, sel_i, axis=1)
+    if kk < k:
+        v = jnp.pad(v, ((0, 0), (0, k - kk)), constant_values=bad_fill)
+        i_out = jnp.pad(i_out, ((0, 0), (0, k - kk)), constant_values=-1)
+    if metric == DistanceType.L2SqrtExpanded:
+        v = jnp.sqrt(jnp.maximum(v, 0.0))
+    return v, i_out
+
+
 def _search_cache_core(queries, centers, rotation, list_decoded,
                        decoded_norms, list_indices, list_sizes, filter_words,
                        metric: DistanceType, k: int, n_probes: int,
@@ -887,30 +937,14 @@ def _search_cache_core(queries, centers, rotation, list_decoded,
     valid_slot = jnp.arange(list_pad)[None, :] < list_sizes[:, None]
 
     def q_body(qt):
-        q_rot = jax.lax.dot_general(
-            qt, rotation, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        dots_c = jax.lax.dot_general(
-            q_rot, centers_rot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        if metric == DistanceType.InnerProduct:
-            _, probes = _sel(dots_c, n_probes, False)
-        else:
-            cn = jnp.sum(centers_rot * centers_rot, -1)
-            _, probes = _sel(cn[None, :] - 2.0 * dots_c, n_probes, True)
-
+        q_rot, dots_c, probes = _cache_probes(qt, rotation, centers_rot,
+                                              metric, n_probes, _sel)
         g_idx = list_indices[probes]
         g_valid = valid_slot[probes]
         if use_pallas:
             # fused probe-gather + scan kernel: each probed list slab is
             # DMA'd straight into VMEM (scalar-prefetch block index); the
             # [t, P, pad, rot] gather intermediate never exists in HBM
-            from raft_tpu.ops import pallas_kernels as pk
-
             if metric == DistanceType.InnerProduct:
                 qv = jnp.broadcast_to(
                     q_rot[:, None, :],
@@ -954,26 +988,10 @@ def _search_cache_core(queries, centers, rotation, list_decoded,
         d = jnp.where(ok, d, bad_fill)
 
         n_cand = n_probes * list_pad
-        flat_d = d.reshape(qt.shape[0], n_cand)
-        flat_i = g_idx.reshape(qt.shape[0], n_cand)
-        if has_overflow:
-            od, oi = _pq_overflow_scan(q_rot, overflow_decoded,
-                                       overflow_norms, overflow_indices,
-                                       filter_words, metric, has_filter,
-                                       bad_fill, prec)
-            flat_d = jnp.concatenate([flat_d, od], axis=1)
-            flat_i = jnp.concatenate([flat_i, oi], axis=1)
-            n_cand += od.shape[1]
-        kk = min(k, n_cand)
-        v, sel = _sel(flat_d, kk, minimize)
-        i_out = jnp.take_along_axis(flat_i, sel, axis=1)
-        if kk < k:
-            v = jnp.pad(v, ((0, 0), (0, k - kk)), constant_values=bad_fill)
-            i_out = jnp.pad(i_out, ((0, 0), (0, k - kk)),
-                            constant_values=-1)
-        if metric == DistanceType.L2SqrtExpanded:
-            v = jnp.sqrt(jnp.maximum(v, 0.0))
-        return v, i_out
+        return _cache_answers(
+            d.reshape(qt.shape[0], n_cand), g_idx.reshape(qt.shape[0], n_cand),
+            q_rot, overflow_decoded, overflow_norms, overflow_indices,
+            filter_words, metric, k, has_filter, has_overflow, prec, _sel)
 
     if n_q_tiles == 1:
         vals, idxs = q_body(qp)
@@ -997,6 +1015,245 @@ _search_cache_jit = jax.jit(
 #: (R004 layering, docs/analysis.md)
 search_cache_core = _search_cache_core
 encode_core = _encode_jit
+
+
+# ------------------------------------------- decoded cache, list-major
+#
+# ``_search_cache_core`` is query-major: each query copies its probed
+# slabs out of the cache, so a list probed by many queries of a batch is
+# read once for each of them. The list-major core orders the batch's
+# (query, list) pairs by list and contracts each probed list once against
+# every query that probes it (``pk.list_scan``), then finds each query's
+# exact top-k by the minima of 128-slot groups (the ``brute_force
+# ._group_topk`` scheme). It serves the single-chip cache engine on a TPU
+# (``plan_list_scan``); the query-major core serves other platforms, the
+# sharded engines and the tiered arena.
+
+#: platforms the list-major core runs on: compiled on a TPU, under the
+#: Mosaic interpreter on any other listed here (parity tests add "cpu")
+_LIST_MAJOR_PLATFORMS = ("tpu",)
+
+
+class ListScan(NamedTuple):
+    """A list-major scan's plan (``plan_list_scan``): ``block_rows`` query
+    rows a block, ``n_blocks`` blocks and ``super_tile`` queries a
+    super-tile, ``n_super`` super-tiles, under the interpreter when
+    ``interpret``."""
+    block_rows: int
+    n_blocks: int
+    super_tile: int
+    n_super: int
+    interpret: bool
+
+
+def list_scan_blocks(n_pairs: int, n_lists: int, block_rows: int) -> int:
+    """Blocks that hold ``n_pairs`` (query, list) pairs, each list's run
+    padded to whole blocks of ``block_rows``, whatever the skew: a list's
+    ``c`` pairs take ⌈c / T⌉ ≤ (c + T − 1) / T blocks, and at most
+    ``min(n_lists, n_pairs)`` lists are probed."""
+    m = min(n_lists, n_pairs)
+    return (n_pairs + m * (block_rows - 1)) // block_rows
+
+
+def list_scan_bytes(super_tile: int, n_probes: int, n_lists: int,
+                    list_pad: int, rot_dim: int, block_rows: int, k: int,
+                    n_overflow: int) -> int:
+    """Live set of one super-tile of the list-major core: the blocks'
+    distances, minima and query rows, each pair's gathered minima row and
+    plan, and the kept groups' and the overflow block's candidates."""
+    nb = list_scan_blocks(super_tile * n_probes, n_lists, block_rows)
+    n_g = pk.list_scan_groups(list_pad)
+    kg = min(k, n_probes * n_g)
+    return (nb * block_rows * (pk.SCAN_GROUP * 4 * (n_g + 1) + rot_dim * 4)
+            + super_tile * n_probes * (pk.SCAN_GROUP * 4 + 32)
+            + super_tile * (kg * pk.SCAN_GROUP + n_overflow) * 12)
+
+
+def _block_rows_for(n_pairs: int, n_lists: int) -> int:
+    """Query rows a block: the power of two in [8, 128] that holds a
+    list's mean run of pairs."""
+    t = 8
+    while t < 128 and t * n_lists < n_pairs:
+        t *= 2
+    return t
+
+
+def plan_list_scan(platform: str, nq: int, n_probes: int, n_lists: int,
+                   list_pad: int, rot_dim: int, cache_itemsize: int, k: int,
+                   n_overflow: int, workspace_limit_bytes: int):
+    """Whether the decoded-cache engine scans list-major:
+    ``(ListScan, "list_kernel")`` on the listed platforms at any batch
+    size (on a v5e it was faster at every size measured, 8–10,000
+    queries, docs/tuning.md), else ``(None, reason)``: ``tpu_absent``,
+    ``short_lists`` (a list shorter than one 128-slot group) or
+    ``list_vmem`` (a list's slab and one 8-row block overflow the
+    kernel's VMEM). Super-tiles are as many as the workspace's live set
+    (``list_scan_bytes``) and the prefetched block table need."""
+    if platform not in _LIST_MAJOR_PLATFORMS:
+        return None, "tpu_absent"
+    if list_pad < pk.SCAN_GROUP:
+        return None, "short_lists"
+
+    def fits_vmem(t):
+        return pk.list_scan_vmem_bytes(t, list_pad, rot_dim,
+                                       cache_itemsize) \
+            <= pk.DEFAULT_VMEM_BUDGET
+
+    n_super = 1
+    while True:
+        st = nq if n_super == 1 else round_up_to(cdiv(nq, n_super), 8)
+        t = _block_rows_for(st * n_probes, n_lists)
+        while t > 8 and not fits_vmem(t):
+            t //= 2
+        if not fits_vmem(t):
+            return None, "list_vmem"
+        nb = list_scan_blocks(st * n_probes, n_lists, t)
+        if st <= 8 or (
+                nb * 4 <= pk.SMEM_PREFETCH_BYTES
+                and list_scan_bytes(st, n_probes, n_lists, list_pad, rot_dim,
+                                    t, k, n_overflow)
+                <= workspace_limit_bytes):
+            return ListScan(t, nb, st, cdiv(nq, st),
+                            platform != "tpu"), "list_kernel"
+        n_super += 1
+
+
+def _list_scan_slots(list_pad: int):
+    """The slot of each lane of each group of ``pk.list_scan`` [n_g, 128],
+    and whether the lane is the slot's own: the last group ends at
+    ``list_pad``, so its lanes that repeat the group before are not."""
+    starts = pk.list_scan_group_starts(list_pad)
+    slot = starts[:, None] + np.arange(pk.SCAN_GROUP)[None, :]
+    return slot, slot >= np.arange(len(starts))[:, None] * pk.SCAN_GROUP
+
+
+def _list_major_plan(probes, n_lists: int, block_rows: int, n_blocks: int):
+    """The blocks of a super-tile's probes [nq, P]: its pairs ordered by
+    list (a stable sort, so by query within a list), each list's run
+    padded to whole blocks of ``block_rows``. Returns ``block_list``
+    [NB] (a block's list; blocks past the last used repeat its list, so
+    no slab is fetched for them), ``n_used`` [1], ``block_queries`` [NB,
+    T] (a slot's query, -1 where it is padding) and ``pair_at`` [nq, P]
+    (each pair's slot, ``block · T + row``)."""
+    nq, n_probes = probes.shape
+    n_pairs = nq * n_probes
+    t = block_rows
+    lists = probes.reshape(-1).astype(jnp.int32)
+    pair = jnp.arange(n_pairs, dtype=jnp.int32)
+    s_list, s_pair = jax.lax.sort((lists, pair), num_keys=1,
+                                  is_stable=True)
+    counts = jnp.zeros((n_lists,), jnp.int32).at[lists].add(1)
+    n_blk = (counts + t - 1) // t
+    first_pair = jnp.cumsum(counts) - counts
+    first_blk = jnp.cumsum(n_blk) - n_blk
+    rank = pair - first_pair[s_list]
+    at = (first_blk[s_list] + rank // t) * t + rank % t
+    block_queries = jnp.full((n_blocks * t,), -1, jnp.int32).at[at].set(
+        s_pair // n_probes, unique_indices=True).reshape(n_blocks, t)
+    pair_at = jnp.zeros((n_pairs,), jnp.int32).at[s_pair].set(
+        at, unique_indices=True).reshape(nq, n_probes)
+    heads = jnp.zeros((n_blocks,), jnp.int32).at[
+        jnp.where(n_blk > 0, first_blk, n_blocks)].max(
+        jnp.arange(n_lists, dtype=jnp.int32), mode="drop")
+    block_list = jax.lax.cummax(heads)
+    return block_list, jnp.sum(n_blk)[None], block_queries, pair_at
+
+
+def _search_cache_lists_core(queries, centers, rotation, list_decoded,
+                             decoded_norms, list_indices, list_sizes,
+                             filter_words, metric: DistanceType, k: int,
+                             n_probes: int, block_rows: int, super_tile: int,
+                             has_filter: bool, overflow_decoded=None,
+                             overflow_norms=None, overflow_indices=None,
+                             has_overflow: bool = False,
+                             select_recall: float = 1.0,
+                             dist_dtype: str = "float32",
+                             interpret: bool = False):
+    """The decoded-cache ADC scan, list-major: the query-major core's
+    probes, distances and answers (up to float rounding, ties to the lower
+    probe and slot as there), with each probed list read once a
+    super-tile of ``super_tile`` queries. Per super-tile: the coarse
+    probe; the plan (``_list_major_plan``); one ``pk.list_scan`` over the
+    blocks at the precision the cache's dtype and ``dist_dtype`` state
+    (:func:`contraction_precision`); the k groups of least minimum among
+    each query's ``n_probes`` lists, which hold its k best slots; the
+    overflow block; one final select. Ids are -1 where fewer than k
+    candidates pass."""
+    nq, dim = queries.shape
+    n_lists, list_pad, _ = list_decoded.shape
+    minimize = metric != DistanceType.InnerProduct
+    prec = contraction_precision(list_decoded.dtype, dist_dtype)
+    bad_fill = jnp.inf if minimize else -jnp.inf
+    g_w = pk.SCAN_GROUP
+    n_g = pk.list_scan_groups(list_pad)
+    t = block_rows
+    n_blocks = list_scan_blocks(super_tile * n_probes, n_lists, t)
+    kg = min(k, n_probes * n_g)
+
+    def _sel(vals, kk, sel_min):
+        return select_k_maybe_approx(vals, kk, sel_min, select_recall)
+
+    centers_rot = jax.lax.dot_general(
+        centers, rotation, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    # each group's slots, ids and row terms
+    slot, fresh = _list_scan_slots(list_pad)
+    ok = (slot[None] < list_sizes[:, None, None]) & fresh[None]
+    ids_g = list_indices[:, slot]  # [L, n_g, 128]
+    if has_filter:
+        ok = ok & bitset_filter_mask(ids_g, filter_words)
+    row_terms = jnp.where(ok, decoded_norms[:, slot] if minimize else 0.0,
+                          jnp.inf)
+    ids_g = ids_g.reshape(n_lists * n_g, g_w)
+
+    def s_body(qs):
+        q_rot, _, probes = _cache_probes(qs, rotation, centers_rot, metric,
+                                         n_probes, _sel)
+        block_list, n_used, block_queries, pair_at = _list_major_plan(
+            probes, n_lists, t, n_blocks)
+        rows = q_rot[jnp.maximum(block_queries, 0)]  # [NB, T, rot]
+        dist, mins = pk.list_scan(block_list, n_used, rows, centers_rot,
+                                  list_decoded, row_terms, l2=minimize,
+                                  precision=prec, interpret=interpret)
+        # the kg groups of least minimum of each query, in (probe, group)
+        # order, so that ties go to the lower probe and slot
+        q_mins = mins.reshape(n_blocks * t, g_w)[pair_at][..., :n_g]
+        sel = jnp.sort(jax.lax.top_k(
+            -q_mins.reshape(qs.shape[0], n_probes * n_g), kg)[1], axis=1)
+        p, g = sel // n_g, sel % n_g
+        at = jnp.take_along_axis(pair_at, p, axis=1)
+        vals = dist.reshape(n_blocks * n_g * t, g_w)[
+            ((at // t) * n_g + g) * t + at % t]
+        ids = ids_g[jnp.take_along_axis(probes, p, axis=1) * n_g + g]
+        flat_d = vals.reshape(qs.shape[0], kg * g_w)
+        v, i_out = _cache_answers(
+            flat_d if minimize else -flat_d,
+            ids.reshape(qs.shape[0], kg * g_w), q_rot, overflow_decoded,
+            overflow_norms, overflow_indices, filter_words, metric, k,
+            has_filter, has_overflow, prec, _sel)
+        return v, jnp.where(v == bad_fill, -1, i_out)
+
+    n_super = cdiv(nq, super_tile)
+    qp = jnp.pad(queries.astype(jnp.float32),
+                 ((0, n_super * super_tile - nq), (0, 0)))
+    if n_super == 1:
+        vals, idxs = s_body(qp)
+    else:
+        vals, idxs = jax.lax.map(s_body,
+                                 qp.reshape(n_super, super_tile, dim))
+        vals = vals.reshape(-1, k)
+        idxs = idxs.reshape(-1, k)
+    return vals[:nq], idxs[:nq]
+
+
+_search_cache_lists_jit = jax.jit(
+    _search_cache_lists_core,
+    static_argnames=("metric", "k", "n_probes", "block_rows", "super_tile",
+                     "has_filter", "has_overflow", "select_recall",
+                     "dist_dtype", "interpret"),
+)
 
 
 def _search_lut_core(queries, centers, rotation, codebooks, list_codes,
@@ -1272,8 +1529,6 @@ def _search_fused_cache_core(queries, centers, rotation, list_decoded,
     an in-kernel top-k carry — the [nq, P, pad] candidate slab never
     exists in HBM and no TOPK_PAD padding applies to the fine scan.
     Unclamped, exactly like the XLA cache engine (ADC space)."""
-    from raft_tpu.ops import pallas_kernels as pk
-
     list_pad = list_decoded.shape[1]
     q_rot, centers_rot, probes = _coarse_probes_rot(
         queries, centers, rotation, n_probes)
@@ -1311,8 +1566,6 @@ def _search_fused_lut_core(queries, centers, rotation, codebooks,
     the one-hot code accumulation feeding the same VMEM top-k carry —
     neither the [nq, P, s, book] LUT nor the [nq, P, pad] candidate slab
     ever materializes in HBM (``ops.pallas_kernels.fused_pq_topk``)."""
-    from raft_tpu.ops import pallas_kernels as pk
-
     list_pad = list_codes.shape[1]
     q_rot, centers_rot, probes = _coarse_probes_rot(
         queries, centers, rotation, n_probes)
@@ -1504,8 +1757,6 @@ def search(
     if has_overflow:
         ensure_overflow_decoded(index, params.scan_cache_dtype)
     per_cluster = index.params.codebook_kind == CodebookGen.PER_CLUSTER
-    from raft_tpu.ops import pallas_kernels as pk
-
     # ---- fused Pallas scan+select (the VMEM top-k carry). Fallback
     # matrix (docs/tuning.md): L2 metrics, no filter, small k; the fused
     # LUT regime additionally needs byte codes (pq_bits=8), PER_SUBSPACE
@@ -1599,37 +1850,75 @@ def search(
                 reason = dreason
             if scan_mode == "cache":  # resolve_scan_mode never says "auto"
                 ensure_scan_cache(index, params.scan_cache_dtype)
-                # workspace: gathered decoded cache [t,P,pad,rot] bf16 +
-                # dists
-                q_tile = plan_cache_tiles(n_probes, list_pad, index.rot_dim,
-                                          res.workspace_limit_bytes)
                 dist_dtype = jnp.dtype(params.internal_distance_dtype).name
-                _record_scan(requested, "cache", reason, ex_params,
-                             {"memory_model": "cache",
-                              "memory_auto": memory_resolved,
-                              "q_tile": q_tile,
-                              "predicted_workspace_bytes": q_tile *
-                              cache_bytes_per_query(n_probes, list_pad,
-                                                    index.rot_dim)},
-                             contraction_precision(index.list_decoded.dtype,
-                                                   dist_dtype))
-                v, i = _search_cache_jit(
-                    queries, index.centers, index.rotation,
-                    index.list_decoded, index.decoded_norms,
-                    index.list_indices, index.list_sizes,
-                    filter.words if filter is not None
-                    else jnp.zeros((0,), jnp.uint32),
-                    index.metric, int(k), n_probes, q_tile,
-                    filter is not None,
-                    # unfused ivf_scan routes only on a measured probe
-                    # verdict (PALLAS_PROBE "fused" table); the env flag is
-                    # retired
-                    pk.fused_crossover("ivf_scan"), False,
-                    index.overflow_decoded, index.overflow_norms,
-                    index.overflow_indices, has_overflow,
-                    select_recall=float(params.select_recall),
-                    dist_dtype=dist_dtype,
-                )
+                prec = contraction_precision(index.list_decoded.dtype,
+                                             dist_dtype)
+                words = (filter.words if filter is not None
+                         else jnp.zeros((0,), jnp.uint32))
+                bucket = queries.shape[0]
+                lists, why = plan_list_scan(
+                    res.device.platform, bucket, n_probes, index.n_lists,
+                    list_pad, index.rot_dim,
+                    jnp.dtype(index.list_decoded.dtype).itemsize, int(k),
+                    index.overflow_indices.shape[0],
+                    res.workspace_limit_bytes)
+                if lists is not None:
+                    rows = lists.n_blocks * lists.block_rows
+                    _record_scan(
+                        requested, "cache_lists", why, ex_params,
+                        {"memory_model": "cache",
+                         "memory_auto": memory_resolved,
+                         "block_rows": lists.block_rows,
+                         "n_blocks": lists.n_blocks,
+                         "super_tiles": lists.n_super,
+                         "padded_row_share": 1.0 - lists.super_tile
+                         * n_probes / rows,
+                         "interpret": lists.interpret,
+                         "predicted_workspace_bytes": list_scan_bytes(
+                             lists.super_tile, n_probes, index.n_lists,
+                             list_pad, index.rot_dim, lists.block_rows,
+                             int(k), index.overflow_indices.shape[0])},
+                        prec)
+                    v, i = _search_cache_lists_jit(
+                        queries, index.centers, index.rotation,
+                        index.list_decoded, index.decoded_norms,
+                        index.list_indices, index.list_sizes, words,
+                        index.metric, int(k), n_probes, lists.block_rows,
+                        lists.super_tile, filter is not None,
+                        index.overflow_decoded, index.overflow_norms,
+                        index.overflow_indices, has_overflow,
+                        select_recall=float(params.select_recall),
+                        dist_dtype=dist_dtype, interpret=lists.interpret,
+                    )
+                else:
+                    # workspace: gathered decoded cache [t,P,pad,rot] +
+                    # dists
+                    q_tile = plan_cache_tiles(n_probes, list_pad,
+                                              index.rot_dim,
+                                              res.workspace_limit_bytes)
+                    _record_scan(requested, "cache", why, ex_params,
+                                 {"memory_model": "cache",
+                                  "memory_auto": memory_resolved,
+                                  "q_tile": q_tile,
+                                  "predicted_workspace_bytes": q_tile *
+                                  cache_bytes_per_query(n_probes, list_pad,
+                                                        index.rot_dim)},
+                                 prec)
+                    v, i = _search_cache_jit(
+                        queries, index.centers, index.rotation,
+                        index.list_decoded, index.decoded_norms,
+                        index.list_indices, index.list_sizes, words,
+                        index.metric, int(k), n_probes, q_tile,
+                        filter is not None,
+                        # unfused ivf_scan routes only on a measured probe
+                        # verdict (PALLAS_PROBE "fused" table); the env
+                        # flag is retired
+                        pk.fused_crossover("ivf_scan"), False,
+                        index.overflow_decoded, index.overflow_norms,
+                        index.overflow_indices, has_overflow,
+                        select_recall=float(params.select_recall),
+                        dist_dtype=dist_dtype,
+                    )
             else:
                 # workspace: the TRUE peak live set of the scan body (LUT
                 # build + code gather + unpack/score temporaries —

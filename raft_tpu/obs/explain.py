@@ -82,6 +82,11 @@ REASONS = frozenset({
     "unsupported_metric",      # metric outside L2/L2Sqrt/inner product
     "not_float32",             # data or queries not float32
     "query_tile_vmem",         # the query tile cannot stay in VMEM
+    # ivf_pq decoded-cache core (ivf_pq.plan_list_scan; "tpu_absent"
+    # above is shared with it)
+    "list_kernel",             # TPU: list-major, the Pallas list scan
+    "short_lists",             # query-major: list_pad under 128 slots
+    "list_vmem",               # query-major: a slab overflows the VMEM
     # sharded cross-chip merge dispatch (parallel/sharded.py merge_mode;
     # "forced"/"fused_loses" above are shared with the merge ladder)
     "merge_tree",              # auto: log₂S ppermute tree merge (default)

@@ -38,6 +38,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -878,6 +879,147 @@ def group_scan_tile(queries, q_norms, data, row_terms, start, limit, *,
       q_norms.astype(jnp.float32).reshape(nq, 1), data,
       row_terms.reshape(1, -1))
     return tile, mins[:, :n_g]
+
+
+# ------------------------------------- ivf_pq decoded cache, list-major
+#
+# The list-major scan of ivf_pq's decoded cache (neighbors.ivf_pq
+# ``_search_cache_lists_core``): the (query, probed list) pairs of a batch
+# are ordered by list and cut into blocks of ``T`` query rows, each block
+# of one list. A grid step contracts a block's rows against its list's
+# whole slab on the MXU; blocks of one list are consecutive, so their slab
+# block index repeats and Pallas fetches each probed list once.
+
+
+def list_scan_groups(list_pad: int) -> int:
+    """Groups of ``SCAN_GROUP`` slots a list is cut into by
+    ``list_scan``."""
+    return -(-int(list_pad) // SCAN_GROUP)
+
+
+def list_scan_group_starts(list_pad: int) -> np.ndarray:
+    """The first slot of each group of ``list_scan``: whole groups from
+    slot 0, the last one ending at ``list_pad`` (it overlaps the one
+    before when ``list_pad`` is not a whole number of groups; its lanes
+    before ``(n_g - 1)·128`` repeat earlier slots and are masked by the
+    caller's row terms), so every group reads 128 slots of the slab at a
+    sublane-aligned offset."""
+    n_g = list_scan_groups(list_pad)
+    return np.minimum(np.arange(n_g) * SCAN_GROUP, list_pad - SCAN_GROUP)
+
+
+def list_scan_vmem_bytes(t: int, list_pad: int, rot: int,
+                         itemsize: int = 4) -> int:
+    """VMEM of one ``list_scan`` grid step: every pipelined block twice —
+    the block's [T, rot] query rows, its list's centre row, the list's
+    [list_pad, rot] slab and [n_g, 128] row terms, the [n_g, T, 128]
+    distances and the [T, 128] minima written back — plus the step's
+    residuals, one group's upcast slab rows, distances and minima."""
+    n_g = list_scan_groups(list_pad)
+    rl = _lanes(rot)
+    blocks = (t * rl * 4 + 8 * rl * 4 + _sublanes(list_pad) * rl * itemsize
+              + _sublanes(n_g) * SCAN_GROUP * 4
+              + n_g * t * SCAN_GROUP * 4 + t * SCAN_GROUP * 4)
+    return (2 * blocks + t * rl * 4 + SCAN_GROUP * rl * 4
+            + 3 * t * SCAN_GROUP * 4)
+
+
+def _list_scan_kernel(s_ref, n_ref, q_ref, c_ref, dec_ref, row_ref,
+                      dist_ref, min_ref, *, starts, l2: bool, precision):
+    """One block: its rows' residuals against the list's centre (L2) or
+    the rows themselves with their centre term (inner product), contracted
+    against each group of the list's slab on the MXU at ``precision``;
+    the epilogue ``‖q_res‖² − 2·dot + row`` (L2) or ``row − (q·c + dot)``
+    (inner product, negated so that least is best), where the row term is
+    the slot's ``‖dec‖²`` (L2), 0 (inner product) or +inf (a slot that
+    holds no row, repeats an earlier group's, or a filter clears). Each
+    group's [T, 128] distances land in the block's groups-major output and
+    its minimum in its lane of the minima. Blocks past the plan's last
+    (``n_ref``) hold no rows and are skipped."""
+    @pl.when(pl.program_id(0) < n_ref[0])
+    def _():
+        q = q_ref[0]  # [T, rot]
+        c = c_ref[0]  # [1, rot]
+        if l2:
+            q = q - c
+            qt = jnp.sum(q * q, axis=1, keepdims=True)
+        else:
+            qt = jnp.sum(q * c, axis=1, keepdims=True)
+        lane = jax.lax.broadcasted_iota(jnp.int32, min_ref.shape[1:], 1)
+        mins = jnp.full(min_ref.shape[1:], jnp.inf, jnp.float32)
+        for g, s in enumerate(starts):
+            x = dec_ref[0, s:s + SCAN_GROUP, :].astype(jnp.float32)
+            dots = jax.lax.dot_general(
+                q, x, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision,
+            )  # [T, 128]
+            row = row_ref[0, g:g + 1, :]
+            d = qt - 2.0 * dots + row if l2 else row - (qt + dots)
+            dist_ref[0, g] = d
+            mins = jnp.where(lane == g, jnp.min(d, axis=1, keepdims=True),
+                             mins)
+        min_ref[0] = mins
+
+
+@functools.partial(jax.jit, static_argnames=("l2", "precision",
+                                              "interpret"))
+def list_scan(block_list, n_used, rows, centers_rot, list_decoded,
+              row_terms, *, l2: bool, precision,
+              interpret: bool = False):
+    """Distances of blocks of query rows against their lists' slabs.
+
+    ``block_list`` [NB] int32 names each block's list, blocks of one list
+    consecutive; ``n_used`` [1] the blocks that hold rows (the rest are
+    skipped); ``rows`` [NB, T, rot] float32 each block's rotated queries;
+    ``centers_rot`` [n_lists, rot]; ``list_decoded`` [n_lists, list_pad,
+    rot] (float32 or bfloat16, upcast in VMEM); ``row_terms`` [n_lists,
+    n_g, 128] each group's slot terms (``_list_scan_kernel``), laid out by
+    ``list_scan_group_starts``. Returns ``(dist [NB, n_g, T, 128], minima
+    [NB, T, 128])``: each block's values by group (least is best) and each
+    group's minimum in lane ``g`` (+inf past ``n_g``). Skipped blocks
+    (past ``n_used``) are neither read nor written: their outputs are
+    undefined."""
+    nb, t, rot = rows.shape
+    n_lists, list_pad, _ = list_decoded.shape
+    if list_pad < SCAN_GROUP:
+        raise ValueError(f"list_scan needs lists of at least {SCAN_GROUP} "
+                         f"slots, got {list_pad}")
+    n_g = list_scan_groups(list_pad)
+    starts = tuple(int(s) for s in list_scan_group_starts(list_pad))
+
+    def last(b, n):
+        # a skipped block keeps the last used block's rows and outputs
+        # resident: nothing is fetched or written back for it
+        return jnp.minimum(b, jnp.maximum(n[0] - 1, 0))
+
+    return pl.pallas_call(
+        functools.partial(_list_scan_kernel, starts=starts, l2=l2,
+                          precision=precision),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nb,),
+            in_specs=[
+                pl.BlockSpec((1, t, rot), lambda b, s, n: (last(b, n), 0, 0)),
+                pl.BlockSpec((1, 1, rot), lambda b, s, n: (s[b], 0, 0)),
+                pl.BlockSpec((1, list_pad, rot),
+                             lambda b, s, n: (s[b], 0, 0)),
+                pl.BlockSpec((1, n_g, SCAN_GROUP),
+                             lambda b, s, n: (s[b], 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, n_g, t, SCAN_GROUP),
+                             lambda b, s, n: (last(b, n), 0, 0, 0)),
+                pl.BlockSpec((1, t, SCAN_GROUP),
+                             lambda b, s, n: (last(b, n), 0, 0)),
+            ]),
+        out_shape=(
+            jax.ShapeDtypeStruct((nb, n_g, t, SCAN_GROUP), jnp.float32),
+            jax.ShapeDtypeStruct((nb, t, SCAN_GROUP), jnp.float32)),
+        interpret=interpret,
+    )(block_list.astype(jnp.int32), n_used.astype(jnp.int32),
+      rows.astype(jnp.float32),
+      centers_rot.astype(jnp.float32).reshape(n_lists, 1, rot),
+      list_decoded, row_terms.astype(jnp.float32))
 
 
 # ------------------------------------------------------- fused ivf top-k

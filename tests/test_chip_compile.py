@@ -98,6 +98,31 @@ def test_fused_ivf_topk_compiles_at_sift1m(sds, dtype, clamp):
         sds((N_LISTS, LIST_PAD), jnp.int32))
 
 
+@pytest.mark.parametrize("dtype,list_pad", [(jnp.float32, 1456),
+                                            (jnp.bfloat16, 1448)],
+                         ids=["float32", "bfloat16_odd_group"])
+def test_list_scan_compiles_at_the_ivfpq_cell(sds, dtype, list_pad):
+    """The list-major cache kernel at the ivfpq-sift1m-batch cell's plan
+    (10k queries × nprobe 32 over 1024 lists: 3,516 blocks of 128 rows),
+    its slabs read in place; the bfloat16 case's last group starts 8
+    rows off a 16-row packed tile."""
+    from raft_tpu.neighbors import ivf_pq
+
+    plan, why = ivf_pq.plan_list_scan(
+        "tpu", NQ, N_PROBES, N_LISTS, list_pad, DIM,
+        jnp.dtype(dtype).itemsize, K, 0, V5E_BYTES_LIMIT // 4)
+    assert why == "list_kernel" and plan.n_super == 1
+    n_g = pk.list_scan_groups(list_pad)
+    prec = ivf_pq.contraction_precision(dtype, jnp.float32)
+    _compile(lambda bl, n, r, c, ld, rt: pk.list_scan(
+        bl, n, r, c, ld, rt, l2=True, precision=prec),
+        sds((plan.n_blocks,), jnp.int32), sds((1,), jnp.int32),
+        sds((plan.n_blocks, plan.block_rows, DIM), jnp.float32),
+        sds((N_LISTS, DIM), jnp.float32),
+        sds((N_LISTS, list_pad, DIM), dtype),
+        sds((N_LISTS, n_g, pk.SCAN_GROUP), jnp.float32))
+
+
 def test_fused_pq_topk_compiles_at_sift1m(sds):
     _compile(lambda pr, q, c, cb, cbn, codes, li: pk.fused_pq_topk(
         pr, q, c, cb, cbn, codes, li, K),

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from raft_tpu.core.bitset import Bitset
 from raft_tpu.neighbors import brute_force, ivf_pq
+from raft_tpu.ops.distance import DistanceType
 from raft_tpu.stats import neighborhood_recall
 
 
@@ -597,7 +598,60 @@ def test_contraction_precision_follows_stated_dtypes(dtypes, want):
     assert ivf_pq.contraction_precision(*dtypes).name == want
 
 
-@pytest.mark.parametrize("engine", ["cache", "lut"])
+def _kernel_precisions(jaxpr, inside: bool = False) -> list:
+    """The precision of each ``dot_general`` inside a ``pallas_call`` of
+    ``jaxpr``: the precision a kernel is given, which lowers to no HLO
+    dot."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if inside and eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"][0].name)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _kernel_precisions(
+                        sub, inside or eqn.primitive.name == "pallas_call")
+    return out
+
+
+def _lists_precisions(overflow: bool, table, dist) -> list:
+    """The list-major cache core's contraction precisions at a tiny size:
+    the HLO dots of its program as a TPU lowers it (the coarse steps and
+    the overflow block), then the precision its kernel is given (each of
+    the kernel's per-group dots at one precision)."""
+    import jax
+
+    from raft_tpu.ops.distance import DistanceType
+
+    def s(shape, dt=_F32):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    n_lists, pad, rot, dim = 8, 256, 16, 16
+    n_over = 8 if overflow else 0
+    traced = ivf_pq._search_cache_lists_jit.trace(
+        s((24, dim)), s((n_lists, dim)), s((rot, dim)),
+        s((n_lists, pad, rot), table), s((n_lists, pad)),
+        s((n_lists, pad), jnp.int32), s((n_lists,), jnp.int32),
+        s((0,), jnp.uint32), metric=DistanceType.L2Expanded, k=5,
+        n_probes=3, block_rows=8, super_tile=24, has_filter=False,
+        overflow_decoded=s((n_over, rot), table),
+        overflow_norms=s((n_over,)),
+        overflow_indices=s((n_over,), jnp.int32), has_overflow=overflow,
+        dist_dtype=jnp.dtype(dist).name)
+    kernel = _kernel_precisions(traced.jaxpr.jaxpr)
+    assert len(kernel) == 2 and len(set(kernel)) == 1  # two groups
+    return (_dot_precisions(traced.lower(lowering_platforms=("tpu",)))
+            + kernel[:1])
+
+
+def _scan_precisions(engine: str, overflow: bool, table, dist) -> list:
+    if engine == "cache_lists":
+        return _lists_precisions(overflow, table, dist)
+    return _dot_precisions(_lower_scan(engine, overflow, table, dist))
+
+
+@pytest.mark.parametrize("engine", ["cache", "lut", "cache_lists"])
 @pytest.mark.parametrize("overflow", [False, True],
                          ids=["lists", "overflow"])
 def test_float32_scan_lowers_every_contraction_at_highest(engine, overflow):
@@ -605,18 +659,18 @@ def test_float32_scan_lowers_every_contraction_at_highest(engine, overflow):
     the coarse step, the cache scan or the LUT build, and the overflow
     block — is lowered at HIGHEST: on a TPU a DEFAULT float32 dot is one
     bfloat16 pass, which the CPU suite cannot see in the answers."""
-    got = _dot_precisions(_lower_scan(engine, overflow, _F32, _F32))
+    got = _scan_precisions(engine, overflow, _F32, _F32)
     assert got == ["HIGHEST"] * (4 + overflow)
 
 
-@pytest.mark.parametrize("engine", ["cache", "lut"])
+@pytest.mark.parametrize("engine", ["cache", "lut", "cache_lists"])
 @pytest.mark.parametrize("overflow", [False, True],
                          ids=["lists", "overflow"])
 def test_half_scan_lowers_its_contractions_at_default(engine, overflow):
     """The bfloat16 path (cache or LUT and internal distances bfloat16)
     keeps its one-pass contractions: only the three coarse steps, which
     are always float32, carry HIGHEST."""
-    got = _dot_precisions(_lower_scan(engine, overflow, _BF16, _BF16))
+    got = _scan_precisions(engine, overflow, _BF16, _BF16)
     assert got[:3] == ["HIGHEST"] * 3
     assert got[3:] == ["DEFAULT"] * (1 + overflow)
 
@@ -651,16 +705,21 @@ def pq_index(data):
     ("cache", _BF16, "default"),
     ("lut", _F32, "highest"),
     ("lut", _BF16, "default"),
+    ("cache_lists", _F32, "highest"),
+    ("cache_lists", _BF16, "default"),
 ])
 def test_search_records_and_counts_its_precision(pq_index, data, engine,
-                                                 dtype, want):
+                                                 dtype, want, monkeypatch):
     """Each dispatch names the precision of its float contractions in the
     explain record's plan and counts it in
-    ``raft_tpu_ivf_pq_scan_plans_total{engine,precision}``."""
+    ``raft_tpu_ivf_pq_scan_plans_total{engine,precision}``; the
+    list-major cache core (``cache_lists``) under the interpreter."""
     from raft_tpu.obs.metrics import REGISTRY
 
     _, q = data
-    params = ivf_pq.SearchParams(n_probes=8, scan_mode=engine,
+    if engine == "cache_lists":
+        monkeypatch.setattr(ivf_pq, "_LIST_MAJOR_PLATFORMS", ("tpu", "cpu"))
+    params = ivf_pq.SearchParams(n_probes=8, scan_mode=engine.split("_")[0],
                                  lut_dtype=dtype, scan_cache_dtype=dtype,
                                  internal_distance_dtype=dtype)
     plans = REGISTRY.get("raft_tpu_ivf_pq_scan_plans_total")
@@ -671,3 +730,184 @@ def test_search_records_and_counts_its_precision(pq_index, data, engine,
     assert after[(engine, want)] - before.get((engine, want), 0) == 1
     assert sum(after.values()) - sum(before.values()) == 1
     assert (np.asarray(ids) >= 0).all()
+
+
+# ------------------------------------------ the list-major cache core
+
+
+def _skewed_rows(n: int, rng):
+    """Clustered rows, half of them in one cluster: ragged lists."""
+    centers = rng.standard_normal((20, 32)) * 4.0
+    labels = np.where(rng.random(n) < 0.5, 0, rng.integers(1, 20, n))
+    return (centers[labels] + rng.standard_normal((n, 32))).astype(
+        np.float32)
+
+
+#: case → (build params, search params, queries, k, filtered, resources)
+_LIST_CASES = {
+    "l2": ({}, {}, "data", 10, False, None),
+    "inner_product": ({"metric": "inner_product"}, {}, "data", 10, False,
+                      None),
+    "l2sqrt": ({"metric": DistanceType.L2SqrtExpanded}, {}, "data", 10,
+               False, None),
+    "filter": ({}, {}, "data", 10, True, None),
+    "overflow": ({"list_pad_expansion": 1.01}, {}, "data", 10, False, None),
+    "ragged": ({}, {}, "skewed", 10, False, None),
+    "one_list": ({}, {}, "same", 10, False, None),
+    "k_above_candidates": ({}, {"n_probes": 1}, "data", 600, False, None),
+    "bfloat16_cache": ({}, {"scan_cache_dtype": jnp.bfloat16,
+                            "internal_distance_dtype": jnp.bfloat16},
+                       "data", 10, False, None),
+    "super_tiles": ({}, {}, "data", 10, False, 4 << 20),
+}
+
+
+def _assert_same_answers(d0, i0, d1, i1, k):
+    """The list-major answers ``(d1, i1)`` [nq, k] against the query-major
+    core's ``(d0, i0)`` [nq, k + 1]: distances to 1e-5 of the k-th (of
+    the row's widest finite one where fewer than k pass), ids equal except
+    where two candidates tie, -1 where no candidate passes."""
+    for r in range(d0.shape[0]):
+        ref, row_i = d0[r], i0[r]
+        fin = np.isfinite(ref[:k])
+        scale = max(abs(ref[k - 1]) if fin.all() else
+                    np.abs(ref[:k][fin]).max(initial=1.0), 1.0)
+        tol = 1e-5 * scale
+        assert (np.isfinite(d1[r]) == fin).all(), r
+        assert (i1[r][~fin] == -1).all(), r
+        np.testing.assert_allclose(d1[r][fin], ref[:k][fin], rtol=0,
+                                   atol=tol)
+        for j in np.flatnonzero(fin & (i1[r] != row_i[:k])):
+            others = np.delete(ref, j)
+            assert np.abs(others - ref[j]).min() <= tol, (r, j)
+
+
+@pytest.mark.parametrize("case", list(_LIST_CASES))
+def test_list_major_core_matches_query_major(data, case, monkeypatch):
+    """The list-major core (its kernel interpreted) gives the query-major
+    core's answers: every metric, a filter, an overflow block, ragged
+    lists, one list probed by every query, a batch that is not a whole
+    number of blocks, k above the probed candidates, a bfloat16 cache and
+    several super-tiles."""
+    from raft_tpu.core.resources import Resources
+
+    db, q = data
+    build, search, queries, k, filtered, workspace = _LIST_CASES[case]
+    rng = np.random.default_rng(7)
+    if queries == "skewed":
+        db = _skewed_rows(4000, rng)
+        q = db[rng.integers(0, 4000, 100)] + 0.1
+    elif queries == "same":
+        q = np.repeat(q[:1], 40, axis=0)
+    index = ivf_pq.build(db, ivf_pq.IndexParams(**{
+        "n_lists": 16, "pq_dim": 16, "kmeans_n_iters": 4,
+        "list_pad_expansion": 8.0, **build}))
+    n_over = int((np.asarray(index.overflow_indices) >= 0).sum())
+    assert (n_over > 0) == (case == "overflow")
+    sizes = np.asarray(index.list_sizes)
+    if case == "ragged":
+        # some list leaves a whole 128-slot group of its pad empty
+        assert sizes.min() + 128 < index.list_codes.shape[1], sizes
+    params = ivf_pq.SearchParams(**{"n_probes": 4, "scan_mode": "cache",
+                                    "scan_cache_dtype": jnp.float32,
+                                    **search})
+    filt, banned = None, np.zeros((0,), np.int64)
+    if filtered:
+        _, top = brute_force.knn(q, db, k=2, metric="sqeuclidean")
+        banned = np.unique(np.asarray(top))
+        filt = Bitset.create(len(db)).set(banned, value=False)
+    res = Resources(workspace_limit_bytes=workspace) if workspace else None
+    d0, i0, r0 = ivf_pq.search(index, q, k + 1, params, filter=filt,
+                               res=res, explain=True)
+    monkeypatch.setattr(ivf_pq, "_LIST_MAJOR_PLATFORMS", ("tpu", "cpu"))
+    d1, i1, r1 = ivf_pq.search(index, q, k, params, filter=filt, res=res,
+                               explain=True)
+    assert (r0.engine, r0.reason) == ("cache", "tpu_absent")
+    assert (r1.engine, r1.reason) == ("cache_lists", "list_kernel")
+    assert r1.plan["interpret"]
+    if case == "super_tiles":
+        assert r1.plan["super_tiles"] > 1
+    if case != "one_list":
+        assert len(q) % r1.plan["block_rows"]  # a ragged last block
+    d0, i0, d1, i1 = map(np.asarray, (d0, i0, d1, i1))
+    if case == "k_above_candidates":
+        assert (i1 == -1).any() and (i1 >= 0).any()
+    assert not np.isin(i1, banned).any()
+    _assert_same_answers(d0, i0, d1, i1, k)
+
+
+@pytest.mark.parametrize("skew", ["uniform", "one_list", "two_lists"])
+def test_list_major_plan_keeps_every_pair_once(skew):
+    """The plan puts each (query, list) pair in exactly one slot of a block
+    of its list, the blocks sorted by list, within the NB bound, and
+    the blocks past the last used hold no rows and repeat its list."""
+    import jax
+
+    rng = np.random.default_rng(3)
+    nq, n_probes, n_lists, t = 37, 5, 12, 8
+    probes = np.stack([rng.permutation(n_lists)[:n_probes]
+                       for _ in range(nq)])
+    if skew == "one_list":
+        probes[:, 0] = 7  # every query probes list 7
+    elif skew == "two_lists":
+        probes = np.tile(np.arange(n_probes), (nq, 1))
+    nb = ivf_pq.list_scan_blocks(nq * n_probes, n_lists, t)
+    assert nb == (nq * n_probes + min(n_lists, nq * n_probes) * (t - 1)) // t
+    block_list, n_used, block_queries, pair_at = map(
+        np.asarray, jax.jit(ivf_pq._list_major_plan, static_argnums=(1, 2, 3))(
+            jnp.asarray(probes, jnp.int32), n_lists, t, nb))
+    used = int(n_used[0])
+    counts = np.bincount(probes.ravel(), minlength=n_lists)
+    assert used == int((-(-counts // t)).sum()) <= nb
+    assert (np.diff(block_list) >= 0).all()
+    assert (block_list[used:] == block_list[used - 1]).all()
+    assert (block_queries[used:] == -1).all()
+    flat = block_queries.reshape(-1)
+    assert (flat >= 0).sum() == nq * n_probes
+    # each pair's slot holds its query, in a block of its list
+    q_of = np.repeat(np.arange(nq), n_probes).reshape(nq, n_probes)
+    np.testing.assert_array_equal(flat[pair_at], q_of)
+    np.testing.assert_array_equal(block_list[pair_at // t], probes)
+    assert len(np.unique(pair_at)) == nq * n_probes
+
+
+@pytest.mark.parametrize("platform,nq,list_pad,rot,want", [
+    ("cpu", 10_000, 1456, 128, "tpu_absent"),
+    ("tpu", 8, 1456, 128, "list_kernel"),
+    ("tpu", 10_000, 120, 128, "short_lists"),
+    ("tpu", 10_000, 8192, 512, "list_vmem"),
+    ("tpu", 32, 1456, 128, "list_kernel"),
+    ("tpu", 10_000, 1456, 128, "list_kernel"),
+])
+def test_plan_list_scan_routes_on_the_shape(platform, nq, list_pad, rot,
+                                            want):
+    """The list-major core engages on a TPU at any batch size, where lists
+    hold at least one group and a slab fits the kernel's VMEM; at the
+    benchmark's IVF-PQ shape it takes 128-row blocks in one super-tile,
+    and 8-row blocks for 8 queries (256 pairs over 1024 lists)."""
+    plan, why = ivf_pq.plan_list_scan(platform, nq, 32, 1024, list_pad, rot,
+                                      4, 10, 2360, 16_909_336_064 // 4)
+    assert why == want
+    assert (plan is None) == (want != "list_kernel")
+    if nq == 10_000 and plan is not None:
+        assert plan == ivf_pq.ListScan(128, 3516, 10_000, 1, False)
+    elif plan is not None:
+        assert plan.block_rows == 8 and plan.n_super == 1
+
+
+def test_search_routes_small_buckets_to_the_list_major_core(pq_index, data,
+                                                            monkeypatch):
+    """Even a bucket of fewer pairs than lists takes the list-major core
+    where it runs; elsewhere ``search`` keeps the query-major core and
+    says why."""
+    _, q = data
+    params = ivf_pq.SearchParams(n_probes=2, scan_mode="cache")
+    assert 8 * 2 < pq_index.n_lists
+    monkeypatch.setattr(ivf_pq, "_LIST_MAJOR_PLATFORMS", ())
+    _, _, rec = ivf_pq.search(pq_index, q[:8], 10, params, explain=True)
+    assert (rec.engine, rec.reason) == ("cache", "tpu_absent")
+    monkeypatch.setattr(ivf_pq, "_LIST_MAJOR_PLATFORMS", ("tpu", "cpu"))
+    _, _, rec = ivf_pq.search(pq_index, q[:8], 10, params, explain=True)
+    assert (rec.engine, rec.reason) == ("cache_lists", "list_kernel")
+    assert {"block_rows", "n_blocks", "super_tiles",
+            "padded_row_share"} <= set(rec.plan)

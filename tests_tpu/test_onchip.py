@@ -232,20 +232,51 @@ def sift_width_pq():
     exact k-th distances (float64)."""
     from raft_tpu.neighbors import ivf_pq
 
+    base, queries = _sift_width_rows(512)
+    index = ivf_pq.build(base, ivf_pq.IndexParams(n_lists=256, pq_dim=64,
+                                                  pq_bits=8))
+    return index, queries, _kth_distances(base, queries)
+
+
+def _sift_width_rows(n_queries: int):
+    """``sift_width_pq``'s 100k × 128 rows (the same for any
+    ``n_queries``) and ``n_queries`` queries from its clusters."""
     rng = np.random.default_rng(25)
     centers = rng.standard_normal((256, 128)).astype(np.float32) * 4.0
     base = centers[rng.integers(0, 256, 100_000)] + rng.standard_normal(
         (100_000, 128)).astype(np.float32)
-    queries = centers[rng.integers(0, 256, 512)] + rng.standard_normal(
-        (512, 128)).astype(np.float32)
-    index = ivf_pq.build(base, ivf_pq.IndexParams(n_lists=256, pq_dim=64,
-                                                  pq_bits=8))
+    queries = centers[rng.integers(0, 256, n_queries)] + rng.standard_normal(
+        (n_queries, 128)).astype(np.float32)
+    return base, queries
+
+
+def _kth_distances(base, queries):
+    """Each query's exact 10th squared distance, in float64."""
     b64 = base.astype(np.float64)
     bn = (b64 * b64).sum(1)
-    kth = np.concatenate([np.partition(
+    return np.concatenate([np.partition(
         (q * q).sum(1)[:, None] + bn[None] - 2.0 * q @ b64.T, 9, 1)[:, 9]
-        for q in np.array_split(queries.astype(np.float64), 8)])
-    return index, queries, kth
+        for q in np.array_split(queries.astype(np.float64),
+                                max(len(queries) // 64, 1))])
+
+
+def _adc_error(index, queries, kth, d, i):
+    """The widest gap between a reported distance and the float64 ADC
+    distance of its id (benchmark/references/ivf_pq_adc.py), over the
+    query's exact k-th distance."""
+    from benchmark import harness
+
+    names = ("centers", "rotation", "codebooks", "list_codes",
+             "list_indices", "list_sizes", "overflow_codes",
+             "overflow_labels", "overflow_indices")
+    view = dict(zip(names, jax.device_get(
+        [getattr(index, n) for n in names])))
+    view.update(n_rows=index.n_rows, pq_dim=index.pq_dim,
+                pq_bits=index.pq_bits, per_cluster=False)
+    adc = harness.load_module("references", "ivf_pq_adc").distances(
+        view, queries, np.asarray(i))
+    return float((np.abs(np.asarray(d, np.float64) - adc)
+                  / kth[:, None]).max())
 
 
 @pytest.mark.parametrize("engine,dtype,float_path", [
@@ -262,7 +293,6 @@ def test_ivf_pq_adc_error_on_chip(sift_width_pq, engine, dtype, float_path):
     the limit separates the two."""
     import json
 
-    from benchmark import harness
     from raft_tpu.neighbors import ivf_pq
 
     index, queries, kth = sift_width_pq
@@ -270,23 +300,61 @@ def test_ivf_pq_adc_error_on_chip(sift_width_pq, engine, dtype, float_path):
                                  lut_dtype=dtype, scan_cache_dtype=dtype,
                                  internal_distance_dtype=dtype)
     d, i = ivf_pq.search(index, queries, 10, params)
-    names = ("centers", "rotation", "codebooks", "list_codes",
-             "list_indices", "list_sizes", "overflow_codes",
-             "overflow_labels", "overflow_indices")
-    view = dict(zip(names, jax.device_get(
-        [getattr(index, n) for n in names])))
-    view.update(n_rows=index.n_rows, pq_dim=index.pq_dim,
-                pq_bits=index.pq_bits, per_cluster=False)
-    adc = harness.load_module("references", "ivf_pq_adc").distances(
-        view, queries, np.asarray(i))
-    err = float((np.abs(np.asarray(d, np.float64) - adc)
-                 / kth[:, None]).max())
+    err = _adc_error(index, queries, kth, d, i)
     print(json.dumps({"test": "ivf_pq_adc_error", "engine": engine,
                       "dtype": jnp.dtype(dtype).name, "adc_error": err}))
     if float_path:
         assert err <= 1e-4, err
     else:
         assert err > 1e-3, err
+
+
+def test_ivf_pq_list_major_core_on_chip(sift_width_pq, monkeypatch):
+    """The list-major cache core, compiled, at 1,000 queries × nprobe 32
+    over 256 lists: ``adc_error`` within the cell's 1e-4, the query-major
+    core's ids except where two distances tie, and a program that holds
+    the list kernel and no [t, P, pad, rot] slab gather."""
+    import json
+    import re
+
+    from raft_tpu.neighbors import ivf_pq
+
+    index, _, _ = sift_width_pq
+    base, queries = _sift_width_rows(1000)
+    kth = _kth_distances(base, queries)
+    params = ivf_pq.SearchParams(n_probes=32, scan_mode="cache",
+                                 scan_cache_dtype=jnp.float32)
+    calls = []
+    core = ivf_pq._search_cache_lists_jit
+
+    def spy(*args, **kwargs):
+        calls.append((args, kwargs))
+        return core(*args, **kwargs)
+
+    monkeypatch.setattr(ivf_pq, "_search_cache_lists_jit", spy)
+    d1, i1, rec = ivf_pq.search(index, queries, 10, params, explain=True)
+    assert (rec.engine, rec.reason) == ("cache_lists", "list_kernel")
+    assert not rec.plan["interpret"]
+    monkeypatch.setattr(ivf_pq, "_LIST_MAJOR_PLATFORMS", ())
+    d0, i0, rec0 = ivf_pq.search(index, queries, 11, params, explain=True)
+    assert rec0.engine == "cache"
+    err = _adc_error(index, queries, kth, d1, i1)
+    d0, i0, d1, i1 = map(np.asarray, (d0, i0, d1, i1))
+    tol = 1e-5 * np.abs(d0[:, 9:10])
+    gap = np.abs(d1 - d0[:, :10])
+    print(json.dumps({"test": "ivf_pq_list_major", "adc_error": err,
+                      "ids_differ": int((i1 != i0[:, :10]).sum()),
+                      "widest_gap": float((gap / tol).max()) * 1e-5,
+                      "plan": rec.plan}))
+    assert err <= 1e-4, err
+    assert (gap <= tol).all()
+    for r, j in zip(*np.nonzero(i1 != i0[:, :10])):
+        assert np.abs(np.delete(d0[r], j) - d0[r, j]).min() <= tol[r, 0]
+    args, kwargs = calls[0]
+    hlo = core.lower(*args, **kwargs).compile().as_text()
+    n_probes, (pad, rot) = 32, index.list_decoded.shape[1:]
+    assert "tpu_custom_call" in hlo and "list_scan" in hlo
+    assert not re.search(rf"\[\d+,{n_probes},{pad},{rot}\]", hlo)
 
 
 def test_ivf_pq_approx_select_recall(pq_index, clustered, gt):
