@@ -99,7 +99,7 @@ def main():
             n_q, n_db, dim, k,
             ensure_resources(None).workspace_limit_bytes)
         sel_algo = _resolve_auto(db_tile, k).value
-        # whether a measured TOPK_PAD rule rewrote the requested k
+        # whether a k-pad rule rewrote the requested k
         k_pad = _pad_k(db_tile, k) if sel_algo == "direct" else 0
 
     row = {

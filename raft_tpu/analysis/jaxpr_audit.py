@@ -279,8 +279,7 @@ def make_ivf_pq_cache_core(budget_bytes: int = DEFAULT_BUDGET_BYTES,
             queries, centers, rotation, list_decoded, decoded_norms,
             list_indices, list_sizes, filter_words,
             metric=DistanceType.L2Expanded, k=s.k, n_probes=s.n_probes,
-            q_tile=q_tile, has_filter=False, use_pallas=False,
-            pallas_interpret=False,
+            q_tile=q_tile, has_filter=False,
             overflow_decoded=jnp.zeros((0, s.rot_dim), jnp.float32),
             overflow_norms=jnp.zeros((0,), jnp.float32),
             overflow_indices=jnp.zeros((0,), jnp.int32),
@@ -359,8 +358,7 @@ def make_ivf_flat_core(budget_bytes: int = DEFAULT_BUDGET_BYTES,
             queries, centers, list_data, list_indices, list_sizes,
             filter_words, metric=DistanceType.L2Expanded, k=s.k,
             n_probes=s.n_probes, q_tile=q_tile, has_filter=False,
-            row_norms=None, use_pallas=False, pallas_interpret=False,
-            fast_scan=False,
+            row_norms=None, fast_scan=False,
             overflow_data=jnp.zeros((0, s.dim), jnp.float32),
             overflow_indices=jnp.zeros((0,), jnp.int32),
             has_overflow=False)

@@ -501,17 +501,26 @@ def _grid_spec_kw(mod: ModuleInfo, site: ast.Call) -> Optional[dict]:
         return {"grid": kw.get("grid"), "in_specs": kw.get("in_specs"),
                 "out_specs": kw.get("out_specs"), "nsp": 0}
     if isinstance(gs, ast.Name):
-        # find `name = pltpu.PrefetchScalarGridSpec(...)` in the module
-        for node in ast.walk(mod.tree):
+        # the last `name = pltpu.PrefetchScalarGridSpec(...)` before the
+        # site in the function that holds it: several kernels' wrappers
+        # reuse one variable name, and each site reads its own
+        qual = _enclosing_qualname(mod, site)
+        scope = (mod.functions[qual].node if qual in mod.functions
+                 else mod.tree)
+        found = None
+        for node in ast.walk(scope):
             if (isinstance(node, ast.Assign)
+                    and node.lineno < site.lineno
                     and len(node.targets) == 1
                     and isinstance(node.targets[0], ast.Name)
                     and node.targets[0].id == gs.id
                     and isinstance(node.value, ast.Call)
                     and (mod.resolve(node.value.func) or "").endswith(
-                        "PrefetchScalarGridSpec")):
-                gs = node.value
-                break
+                        "PrefetchScalarGridSpec")
+                    and (found is None or node.lineno > found.lineno)):
+                found = node
+        if found is not None:
+            gs = found.value
     if not isinstance(gs, ast.Call):
         return None
     gkw = {k.arg: k.value for k in gs.keywords if k.arg}
@@ -538,9 +547,23 @@ def _kernel_function(mod: ModuleInfo, site: ast.Call):
     return mod.functions[quals[0]] if quals else None
 
 
+def _when_conds(mod: ModuleInfo, fn_node) -> list:
+    """The conditions of the ``@pl.when(...)`` blocks inside a function."""
+    conds = []
+    for node in ast.walk(fn_node):
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            for dec in node.decorator_list:
+                if isinstance(dec, ast.Call) and _api(mod, dec) == "when" \
+                        and dec.args:
+                    conds.append(dec.args[0])
+    return conds
+
+
 def _first_visit_guards(mod: ModuleInfo, kernel_info) -> Tuple[dict, list]:
     """→ (axis → program_id variable, [pl.when condition exprs]) inside
-    the kernel function."""
+    the kernel function. A module helper the kernel calls counts too:
+    where the helper guards on one of its parameters (``@pl.when(first)``),
+    the argument the kernel passes there is a condition of the kernel."""
     axis_vars: dict = {}
     for node in _own_body_walk(kernel_info):
         if (isinstance(node, ast.Assign) and len(node.targets) == 1
@@ -550,13 +573,20 @@ def _first_visit_guards(mod: ModuleInfo, kernel_info) -> Tuple[dict, list]:
                 and node.value.args
                 and isinstance(node.value.args[0], ast.Constant)):
             axis_vars[node.value.args[0].value] = node.targets[0].id
-    conds = []
-    for node in ast.walk(kernel_info.node):
-        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            for dec in node.decorator_list:
-                if isinstance(dec, ast.Call) and _api(mod, dec) == "when" \
-                        and dec.args:
-                    conds.append(dec.args[0])
+    conds = _when_conds(mod, kernel_info.node)
+    for node in _own_body_walk(kernel_info):
+        if not (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Name)):
+            continue
+        quals = mod.name_index.get(node.func.id, ())
+        helper = mod.functions[quals[0]] if quals else None
+        if helper is None or helper is kernel_info:
+            continue
+        params = [a.arg for a in helper.node.args.args]
+        for cond in _when_conds(mod, helper.node):
+            if (isinstance(cond, ast.Name) and cond.id in params
+                    and params.index(cond.id) < len(node.args)):
+                conds.append(node.args[params.index(cond.id)])
     return axis_vars, conds
 
 

@@ -511,7 +511,7 @@ def _knn_fused_jit(queries, dataset, db_norms, k: int, tm: int, tn: int,
     """Fused-Pallas brute-force core: the [nq, ndb] distance slab never
     touches HBM — each [tm, tn] tile feeds the VMEM-resident top-k carry
     (``ops.pallas_kernels.fused_l2_topk``). Selection happens in-kernel,
-    so no ``select_k`` call and no TOPK_PAD padding applies here."""
+    so no ``select_k`` call and no k-pad rule applies here."""
     qn = row_norms_sq(queries)
     dbn = row_norms_sq(dataset) if db_norms is None else db_norms
     v, i = pk.fused_l2_topk(queries, dataset, k, x_norms=qn, y_norms=dbn,

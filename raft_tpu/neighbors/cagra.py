@@ -98,9 +98,8 @@ class SearchParams:
     #: reference's half-precision compute_distance teams
     #: (detail/cagra/compute_distance.hpp).
     scan_dtype: Optional[object] = None
-    #: "auto" routes the fused Pallas beam-search engine only where the
-    #: committed PALLAS_PROBE artifact records a ``fused.cagra.fused_wins``
-    #: verdict for this platform (conservative XLA default otherwise);
+    #: "auto" routes XLA (the fused Pallas beam-search engine has no chip
+    #: measurement beating it, ``pallas_kernels.fused_dispatch_explained``);
     #: "pallas"/"xla" force an engine. Same contract as the other fused
     #: families (docs/tuning.md fallback matrix).
     scan_mode: str = "auto"

@@ -364,17 +364,11 @@ def _overflow_scan(qt, qf, o_scan, o_norms, o_ok_base, overflow_indices,
 def _search_core(queries, centers, list_data, list_indices, list_sizes,
                  filter_words, metric: DistanceType, k: int, n_probes: int,
                  q_tile: int, has_filter: bool, row_norms=None,
-                 use_pallas: bool = False, pallas_interpret: bool = False,
                  fast_scan: bool = False, overflow_data=None,
                  overflow_indices=None, has_overflow: bool = False,
                  select_recall: float = 1.0, refine_mult: int = 4):
     """Traceable search body — jitted below; also shard_mapped by
     raft_tpu.parallel.sharded for multi-device list-sharded search.
-
-    ``use_pallas`` routes the probe scan through the fused scalar-prefetch
-    kernel (ops.pallas_kernels.ivf_scan): probed list slabs are DMA'd
-    straight to VMEM instead of materializing the [t, P, pad, dim] gather
-    in HBM; requires ``row_norms`` [L, pad].
 
     ``has_overflow``: rows spilled past the capped list_pad are scanned
     brute-force for every query and merged into the final select_k — a
@@ -405,63 +399,42 @@ def _search_core(queries, centers, list_data, list_indices, list_sizes,
         g_idx = list_indices[probes]  # [t, P, pad]
         g_valid = valid_slot[probes]  # [t, P, pad]
         qf = qt.astype(jnp.float32)
-        if use_pallas:
-            from raft_tpu.ops import pallas_kernels as pk
-
-            qv = jnp.broadcast_to(qf[:, None, :],
-                                  (qt.shape[0], n_probes, dim))
-            part = pk.ivf_scan(probes, qv, list_data, row_norms,
-                               interpret=pallas_interpret)  # ||v||²−2q·v
-            vn2 = row_norms[probes]
-            dots = 0.5 * (vn2 - part)
-            if metric == DistanceType.InnerProduct:
-                d = dots
-            elif metric == DistanceType.CosineExpanded:
+        # ---- gather probed lists and scan
+        g_data = list_data[probes]  # [t, P, pad, dim]
+        if fast_scan:
+            # bf16 MXU pass; norms stay exact fp32 (cached per-row)
+            q_s, g_s = qt.astype(jnp.bfloat16), g_data.astype(jnp.bfloat16)
+        else:
+            q_s, g_s = qf, g_data.astype(jnp.float32)
+        dots = jnp.einsum(
+            "td,tpld->tpl", q_s, g_s,
+            # HIGHEST only for true fp32 data on the accurate path;
+            # int8/uint8/bf16 values are bf16-exact → single MXU pass
+            precision=(jax.lax.Precision.HIGHEST
+                       if (not fast_scan
+                           and g_data.dtype == jnp.float32) else None),
+            preferred_element_type=jnp.float32,
+        )
+        if metric == DistanceType.InnerProduct:
+            d = dots
+        else:
+            # exact per-row norms: cached [L, pad] gather when available,
+            # else recomputed from the gathered tile
+            if row_norms is not None:
+                vn2 = row_norms[probes]
+            else:
+                gf32 = g_data.astype(jnp.float32)
+                vn2 = jnp.sum(gf32 * gf32, -1)
+            if metric == DistanceType.CosineExpanded:
                 vn = jnp.sqrt(jnp.maximum(vn2, 1e-20))
                 qn = jnp.sqrt(jnp.maximum(row_norms_sq(qf), 1e-20))
                 d = 1.0 - dots / (vn * qn[:, None, None])
             else:
                 qn2 = row_norms_sq(qf)
-                d = jnp.maximum(qn2[:, None, None] + part, 0.0)
+                d = qn2[:, None, None] + vn2 - 2.0 * dots
+                d = jnp.maximum(d, 0.0)
                 if metric == DistanceType.L2SqrtExpanded:
                     d = jnp.sqrt(d)
-        else:
-            # ---- gather probed lists and scan
-            g_data = list_data[probes]  # [t, P, pad, dim]
-            if fast_scan:
-                # bf16 MXU pass; norms stay exact fp32 (cached per-row)
-                q_s, g_s = qt.astype(jnp.bfloat16), g_data.astype(jnp.bfloat16)
-            else:
-                q_s, g_s = qf, g_data.astype(jnp.float32)
-            dots = jnp.einsum(
-                "td,tpld->tpl", q_s, g_s,
-                # HIGHEST only for true fp32 data on the accurate path;
-                # int8/uint8/bf16 values are bf16-exact → single MXU pass
-                precision=(jax.lax.Precision.HIGHEST
-                           if (not fast_scan
-                               and g_data.dtype == jnp.float32) else None),
-                preferred_element_type=jnp.float32,
-            )
-            if metric == DistanceType.InnerProduct:
-                d = dots
-            else:
-                # exact per-row norms: cached [L, pad] gather when available,
-                # else recomputed from the gathered tile
-                if row_norms is not None:
-                    vn2 = row_norms[probes]
-                else:
-                    gf32 = g_data.astype(jnp.float32)
-                    vn2 = jnp.sum(gf32 * gf32, -1)
-                if metric == DistanceType.CosineExpanded:
-                    vn = jnp.sqrt(jnp.maximum(vn2, 1e-20))
-                    qn = jnp.sqrt(jnp.maximum(row_norms_sq(qf), 1e-20))
-                    d = 1.0 - dots / (vn * qn[:, None, None])
-                else:
-                    qn2 = row_norms_sq(qf)
-                    d = qn2[:, None, None] + vn2 - 2.0 * dots
-                    d = jnp.maximum(d, 0.0)
-                    if metric == DistanceType.L2SqrtExpanded:
-                        d = jnp.sqrt(d)
         bad_fill = jnp.inf if minimize else -jnp.inf
         ok = g_valid
         if has_filter:
@@ -536,7 +509,7 @@ def _search_core(queries, centers, list_data, list_indices, list_sizes,
 _search_jit = jax.jit(
     _search_core,
     static_argnames=("metric", "k", "n_probes", "q_tile", "has_filter",
-                     "use_pallas", "pallas_interpret", "fast_scan",
+                     "fast_scan",
                      "has_overflow", "select_recall", "refine_mult"),
 )
 
@@ -555,7 +528,7 @@ def _search_fused_core(queries, centers, list_data, list_indices, list_sizes,
     coarse selection stays XLA, then the probed slabs are DMA'd straight
     to VMEM and merged into an in-kernel top-k carry
     (``ops.pallas_kernels.fused_ivf_topk``) — the [nq, P, pad] candidate
-    slab never materializes in HBM and no ``select_k``/TOPK_PAD padding
+    slab never materializes in HBM and no ``select_k`` k-pad rule
     applies to the fine scan. Overflow rows (spilled past the capped
     list_pad) are scanned by the XLA brute pass in squared space and
     merged with the kernel's survivors through one unpadded ``select_k``."""
@@ -593,7 +566,7 @@ def _search_fused_core(queries, centers, list_data, list_indices, list_sizes,
         cand_v = jnp.concatenate([v, od], axis=1)
         cand_i = jnp.concatenate([i, oi], axis=1)
         # selection already happened in-kernel — the merge select runs with
-        # pad_rules=False so TOPK_PAD cannot double-pad it (ISSUE 10)
+        # pad_rules=False so the k-pad rules cannot double-pad it
         v, i = select_k(cand_v, k, select_min=True, indices=cand_i,
                         pad_rules=False)
     if metric == DistanceType.L2SqrtExpanded:
@@ -710,33 +683,26 @@ def search(
                 fused_interp,
             )
         else:
-            # The unfused ivf_scan kernel only routes where a committed probe
-            # artifact shows it beating XLA — none is committed (a pre-fused
-            # probe measured 22.3 ms vs 10.9 ms), so this stays off
-            # without a measured verdict; the RAFT_TPU_PALLAS=1 env override
-            # is retired. An explicit bf16 request still wins over any fp32
-            # Pallas scan — never silently benchmark fp32 under a bf16 label.
-            use_pallas = pk.fused_crossover("ivf_scan") and not fast_scan
             reason = ineligible if (use_fused and ineligible) else dreason
             obs_explain.record_dispatch(
                 "ivf_flat", scan_mode, "xla", reason, params=ex_params,
-                plan={"q_tile": q_tile, "unfused_ivf_scan": use_pallas,
+                plan={"q_tile": q_tile,
                       "predicted_workspace_bytes": q_tile *
                       scan_bytes_per_query(n_probes, list_pad, index.dim)})
-            # Cached exact norms are required by the Pallas path and the bf16
-            # fast scan; the plain XLA path keeps computing norms per probed
-            # tile instead (materializing [L, pad] fp32 norms for a large
-            # narrow-dtype index is a needless device-memory spike there).
-            need_norms = use_pallas or (
-                fast_scan and index.metric != DistanceType.InnerProduct)
+            # Cached exact norms are required by the bf16 fast scan; the
+            # fp32 path keeps computing norms per probed tile instead
+            # (materializing [L, pad] fp32 norms for a large narrow-dtype
+            # index is a needless device-memory spike there).
+            need_norms = (fast_scan
+                          and index.metric != DistanceType.InnerProduct)
             v, i = _search_jit(
                 queries, index.centers, index.list_data, index.list_indices,
                 index.list_sizes,
                 filter.words if filter is not None
                 else jnp.zeros((0,), jnp.uint32),
                 index.metric, int(k), n_probes, q_tile, filter is not None,
-                index.ensure_row_norms() if need_norms else None, use_pallas,
-                False, fast_scan, index.overflow_data, index.overflow_indices,
+                index.ensure_row_norms() if need_norms else None, fast_scan,
+                index.overflow_data, index.overflow_indices,
                 has_overflow, float(params.select_recall),
                 refine_multiplier(params.refine_ratio, fast_scan),
             )

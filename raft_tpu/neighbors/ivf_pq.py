@@ -906,17 +906,24 @@ def _search_cache_core(queries, centers, rotation, list_decoded,
                        decoded_norms, list_indices, list_sizes, filter_words,
                        metric: DistanceType, k: int, n_probes: int,
                        q_tile: int, has_filter: bool,
-                       use_pallas: bool = False,
-                       pallas_interpret: bool = False,
                        overflow_decoded=None, overflow_norms=None,
                        overflow_indices=None, has_overflow: bool = False,
                        select_recall: float = 1.0,
-                       dist_dtype: str = "float32"):
+                       dist_dtype: str = "float32",
+                       use_pallas: bool = False,
+                       pallas_interpret: bool = False):
     """ADC scan over the decoded-residual cache: identical distances to the
     LUT formulation (||q_res − dec||² expands to ||q_res||² − 2 q_res·dec +
     ||dec||²), evaluated as one batched matvec per probe on the MXU, at the
     precision the cache's dtype and ``dist_dtype`` (the internal distance
-    dtype) state (:func:`contraction_precision`)."""
+    dtype) state (:func:`contraction_precision`).
+
+    ``use_pallas``/``pallas_interpret`` are accepted only as False, the
+    values ``benchmark/rehearse_compile.py`` passes: the unfused Pallas
+    scan they once selected is gone."""
+    if use_pallas or pallas_interpret:
+        raise ValueError("the unfused Pallas scan was removed; "
+                         "use_pallas must be False")
     nq, dim = queries.shape
     n_lists, list_pad, rot_dim = list_decoded.shape
     minimize = metric != DistanceType.InnerProduct
@@ -941,27 +948,7 @@ def _search_cache_core(queries, centers, rotation, list_decoded,
                                               metric, n_probes, _sel)
         g_idx = list_indices[probes]
         g_valid = valid_slot[probes]
-        if use_pallas:
-            # fused probe-gather + scan kernel: each probed list slab is
-            # DMA'd straight into VMEM (scalar-prefetch block index); the
-            # [t, P, pad, rot] gather intermediate never exists in HBM
-            if metric == DistanceType.InnerProduct:
-                qv = jnp.broadcast_to(
-                    q_rot[:, None, :],
-                    (qt.shape[0], n_probes, q_rot.shape[1]))
-                part = pk.ivf_scan(probes, qv, list_decoded, decoded_norms,
-                                   interpret=pallas_interpret)
-                g_n = decoded_norms[probes]
-                base = jnp.take_along_axis(dots_c, probes, axis=1)
-                d = base[:, :, None] + 0.5 * (g_n - part)
-            else:
-                qr_res = q_rot[:, None, :] - centers_rot[probes]
-                part = pk.ivf_scan(probes, qr_res, list_decoded,
-                                   decoded_norms,
-                                   interpret=pallas_interpret)
-                qn = jnp.sum(qr_res * qr_res, -1)
-                d = qn[:, :, None] + part
-        elif metric == DistanceType.InnerProduct:
+        if metric == DistanceType.InnerProduct:
             g_dec = list_decoded[probes]  # [t, P, pad, rot] bf16
             # score = q·center + q_rot·dec
             dots = jnp.einsum("td,tpld->tpl", q_rot,
@@ -1503,8 +1490,8 @@ def _fused_merge_overflow(v, i, q_rot, overflow_decoded, overflow_norms,
     """Merge the kernel's VMEM-carry survivors with the XLA overflow scan
     (squared space on both sides; at ``HIGHEST``, as the kernels
     contract). Selection already happened in-kernel,
-    so the merge select runs with ``pad_rules=False`` — TOPK_PAD models an
-    HBM slab select and must not re-pad the short candidate list
+    so the merge select runs with ``pad_rules=False`` — the k-pad rules
+    model an HBM slab select and must not re-pad the short candidate list
     (ISSUE 10)."""
     od, oi = _pq_overflow_scan(q_rot, overflow_decoded, overflow_norms,
                                overflow_indices,
@@ -1527,7 +1514,7 @@ def _search_fused_cache_core(queries, centers, rotation, list_decoded,
     ``ops.pallas_kernels.fused_ivf_topk`` DMAs each probed cache slab to
     VMEM and merges ``||q_res||² − 2·q_res·dec + ||dec||²`` partials into
     an in-kernel top-k carry — the [nq, P, pad] candidate slab never
-    exists in HBM and no TOPK_PAD padding applies to the fine scan.
+    exists in HBM and no k-pad rule applies to the fine scan.
     Unclamped, exactly like the XLA cache engine (ADC space)."""
     list_pad = list_decoded.shape[1]
     q_rot, centers_rot, probes = _coarse_probes_rot(
@@ -1910,10 +1897,6 @@ def search(
                         index.list_indices, index.list_sizes, words,
                         index.metric, int(k), n_probes, q_tile,
                         filter is not None,
-                        # unfused ivf_scan routes only on a measured probe
-                        # verdict (PALLAS_PROBE "fused" table); the env
-                        # flag is retired
-                        pk.fused_crossover("ivf_scan"), False,
                         index.overflow_decoded, index.overflow_norms,
                         index.overflow_indices, has_overflow,
                         select_recall=float(params.select_recall),

@@ -59,7 +59,6 @@ __all__ = [
 REASONS = frozenset({
     # engine chosen positively
     "forced",                  # scan_mode explicitly named this engine
-    "auto_fused_wins",         # measured PALLAS_PROBE verdict routed fused
     "interpret",               # RAFT_TPU_PALLAS_INTERPRET=1 parity hook
     "only_engine",             # family has a single engine (kept in the
                                # vocabulary for artifact replay; cagra —
@@ -68,8 +67,8 @@ REASONS = frozenset({
                                # the other fused families)
     # fused considered but routed to XLA
     "tpu_absent",              # pallas/auto on a host with no TPU backend
-    "no_fused_wins_verdict",   # auto on TPU, probe artifact has no verdict
-    "fused_loses",             # auto on TPU, probe measured XLA winning
+    "fused_unmeasured",        # auto on TPU: no chip measurement of the
+                               # fused kernel beating XLA
     "non_l2",                  # metric outside the fused L2 matrix
     "filtered",                # bitset filter (no in-carry filter epilogue)
     "fast_scan",               # bf16 fast scan requested (fp32-only carry)
@@ -88,11 +87,9 @@ REASONS = frozenset({
     "short_lists",             # query-major: list_pad under 128 slots
     "list_vmem",               # query-major: a slab overflows the VMEM
     # sharded cross-chip merge dispatch (parallel/sharded.py merge_mode;
-    # "forced"/"fused_loses" above are shared with the merge ladder)
-    "merge_tree",              # auto: log₂S ppermute tree merge (default)
-    "merge_ring",              # auto on TPU: measured merge_ring win
+    # "forced" above is shared with the merge ladder)
+    "merge_tree",              # auto: log₂S ppermute tree merge
     "merge_allgather",         # auto: non-power-of-two mesh fallback
-    "no_ring_verdict",         # auto on TPU, probe has no merge_ring row
     # deadline-aware adaptive planning (planner/adaptive.py choice
     # reasons — emitted with requested="adaptive", engine="planner";
     # also counted in raft_tpu_adaptive_choice_total{family,reason})

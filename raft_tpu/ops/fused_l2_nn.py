@@ -91,18 +91,6 @@ def fused_l2_nn_argmin(
     res = ensure_resources(res)
     x = jnp.asarray(x)
     y = jnp.asarray(y)
-    from raft_tpu.ops import pallas_kernels
-
-    # measured crossover, not an env flag: the probe artifact must show the
-    # standalone Pallas kernel actually beating XLA on this platform
-    # (no committed probe says it does; a pre-fused one measured 22.3 ms
-    # vs 10.9)
-    if pallas_kernels.fused_crossover("l2_argmin"):
-        val, idx = pallas_kernels.fused_l2_argmin(
-            x, y, x_norms=x_norms, y_norms=y_norms)
-        if sqrt:
-            val = jnp.sqrt(jnp.maximum(val, 0.0))
-        return val, idx
     xn = row_norms_sq(x) if x_norms is None else x_norms
     yn = row_norms_sq(y) if y_norms is None else y_norms
     tile = choose_tile_rows(x.shape[0], y.shape[0], res.workspace_limit_bytes)

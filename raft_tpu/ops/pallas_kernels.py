@@ -1,38 +1,36 @@
 """Pallas TPU kernels for the hot fused ops.
 
 Reference analogs: ``fusedL2NN`` (distance/fused_l2_nn-inl.cuh:76 — L2 +
-argmin without materializing the distance matrix) and the tiled pairwise
-engine (detail/pairwise_distance_base.cuh).
+argmin without materializing the distance matrix), the tiled pairwise
+engine (detail/pairwise_distance_base.cuh) and the IVF interleaved scan.
 
-TPU-native design: a [TM, TN] distance tile is produced on the MXU from
-VMEM-resident x/y tiles and consumed immediately by a VPU min/argmin that
-merges into the running per-row best — the distance matrix never exists in
-HBM, the exact property the CUDA kernel gets from its fused epilogue. The
-grid walks (x_tiles × y_tiles) with the y axis innermost so each x tile's
-output block stays resident while y streams through.
-
-Selection: the fused scan+select kernels (``fused_l2_topk``,
-``fused_ivf_topk``, ``fused_pq_topk``) carry a query tile's running top-k
-(values + global row ids) in VMEM across database/probe tiles — the
-candidate-distance slab never round-trips through HBM before ``select_k``
-reads it back, the exact traffic CUDA RAFT eliminates by fusing distance +
+TPU-native design: a distance tile is produced on the MXU from
+VMEM-resident tiles and consumed in VMEM by the kernel's epilogue — the
+candidate-distance slab never round-trips through HBM before selection
+reads it back, the traffic CUDA RAFT eliminates by fusing distance +
 selection in registers/SMEM. Tile sizes come from a VMEM-budget planner
 (``core.resources.solve_vmem_tiles``, the ~16 MiB on-chip analog of
-``solve_joint_tiles``); dispatch is MEASURED, not env-gated: ``search``
-entry points route here only when the committed ``PALLAS_PROBE`` artifact
-records the fused kernel winning for that family on this platform
-(``fused_crossover``) or when the caller forces ``scan_mode="pallas"``.
-The standalone (unfused) ``fused_l2_argmin``/``ivf_scan`` kernels lost to
-XLA in a pre-fused hardware probe (22.3 ms vs 10.9 ms at 8192 clusters;
-that record predates the chip setup this repo now uses and is gone) —
-they stay for the same crossover-gated dispatch and as the building
-blocks the fused kernels grew from, but nothing routes to them
-unconditionally anymore."""
+``solve_joint_tiles``).
+
+Two groups of kernels, dispatched differently:
+
+- The scan kernels the benchmark cells run, chosen in code by their
+  callers from the shape and the device: ``group_scan_tile`` (the exact
+  scan's tile and its group minima, ``brute_force.plan_group_scan``) and
+  ``list_scan`` (the IVF-PQ decoded-cache scan, list-major,
+  ``ivf_pq.plan_list_scan``).
+- The fused scan+select kernels (``fused_l2_topk``, ``fused_ivf_topk``,
+  ``fused_pq_topk``, ``fused_cagra_topk``) and the ring shift of the
+  sharded merge. No chip measurement shows them beating XLA, so
+  ``scan_mode="auto"`` routes XLA (reason ``fused_unmeasured``) and the
+  sharded merge takes the tree; they run only when a caller asks for
+  them (``scan_mode="pallas"``, ``merge_mode="ring"``). A PR that
+  measures a win writes the rule here, in code.
+"""
 
 from __future__ import annotations
 
 import functools
-import logging
 import os
 from typing import Optional
 
@@ -45,140 +43,6 @@ from jax.experimental.pallas import tpu as pltpu
 from raft_tpu.utils.shape import round_up_to
 
 
-def _fused_l2_argmin_kernel(x_ref, y_ref, xn_ref, yn_ref, val_ref, idx_ref):
-    j = pl.program_id(1)
-    tn = y_ref.shape[0]
-
-    dots = jax.lax.dot_general(
-        x_ref[:], y_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )  # [TM, TN] — fp32 MXU passes; default would truncate to bf16
-    d = xn_ref[:] + yn_ref[:] - 2.0 * dots  # [TM, TN] (norm bcast)
-    local_val = jnp.min(d, axis=1, keepdims=True)  # [TM, 1]
-    local_arg = (jnp.argmin(d, axis=1).reshape(-1, 1)
-                 + j * tn).astype(jnp.int32)
-
-    @pl.when(j == 0)
-    def _():
-        val_ref[:] = local_val
-        idx_ref[:] = local_arg
-
-    @pl.when(j > 0)
-    def _():
-        better = local_val < val_ref[:]
-        val_ref[:] = jnp.where(better, local_val, val_ref[:])
-        idx_ref[:] = jnp.where(better, local_arg, idx_ref[:])
-
-
-@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
-def _fused_l2_argmin_pallas(x, y, x_norms, y_norms, tm: int, tn: int,
-                            interpret: bool):
-    m, d = x.shape
-    n, _ = y.shape
-    mp = round_up_to(m, tm)
-    np_ = round_up_to(n, tn)
-    xp = jnp.pad(x.astype(jnp.float32), ((0, mp - m), (0, 0)))
-    yp = jnp.pad(y.astype(jnp.float32), ((0, np_ - n), (0, 0)))
-    xn = jnp.pad(x_norms.astype(jnp.float32), (0, mp - m)).reshape(mp, 1)
-    # padded y rows must never win the argmin
-    yn = jnp.pad(y_norms.astype(jnp.float32), (0, np_ - n),
-                 constant_values=jnp.inf)
-    yn = jnp.where(jnp.arange(np_) < n, yn, jnp.inf).reshape(1, np_)
-
-    grid = (mp // tm, np_ // tn)
-    val, idx = pl.pallas_call(
-        _fused_l2_argmin_kernel,
-        out_shape=(jax.ShapeDtypeStruct((mp, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((mp, 1), jnp.int32)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, d), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tn, d), lambda i, j: (j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tm, 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tn), lambda i, j: (0, j),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((tm, 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tm, 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ),
-        interpret=interpret,
-    )(xp, yp, xn, yn)
-    return val[:m, 0], idx[:m, 0]
-
-
-# ------------------------------------------------- measured crossover gate
-#
-# The unconditional RAFT_TPU_PALLAS=1 env flag is retired: routing to a
-# Pallas kernel is now a MEASURED decision recorded by tools/pallas_probe.py
-# into PALLAS_PROBE_<platform>.json ("fused" section, per-family
-# ``fused_wins`` verdicts). The artifact self-arms exactly like the
-# TOPK_PAD tables (repo root + cwd scan, env override
-# loaded last and loudly) so a hardware window's probe run flips the
-# dispatch for subsequent runs with no env plumbing.
-
-_fused_table_cache = None
-
-
-def _extract_fused_table(art: dict) -> dict:
-    fused = art.get("fused", {})
-    return {fam: bool(row.get("fused_wins"))
-            for fam, row in fused.items() if isinstance(row, dict)}
-
-
-def _load_fused_table() -> dict:
-    global _fused_table_cache
-    if _fused_table_cache is None:
-        from raft_tpu.ops.select_k import _scan_artifacts
-
-        _fused_table_cache = _scan_artifacts(
-            {}, "PALLAS_PROBE", "RAFT_TPU_PALLAS_PROBE",
-            _extract_fused_table)
-    return _fused_table_cache
-
-
-def fused_platform_key() -> str:
-    """The platform key fused-crossover verdicts are recorded under —
-    select_k's artifact key (device kind on TPU, backend name elsewhere),
-    public so probes/tests can target ``set_fused_crossover`` at the
-    running host without reaching into select_k internals."""
-    from raft_tpu.ops.select_k import _platform_key
-
-    return _platform_key()
-
-
-def set_fused_crossover(platform: str, families) -> None:
-    """Install (or with None, drop) measured fused-kernel verdicts for a
-    platform: ``{"brute_force": True, "ivf_flat": False, ...}`` (the test
-    hook mirroring select_k.set_auto_table)."""
-    global _fused_table_cache
-    tables = _load_fused_table()
-    if families is None:
-        tables.pop(platform, None)
-    else:
-        tables[platform] = {k: bool(v) for k, v in families.items()}
-    _fused_table_cache = tables
-
-
-def fused_crossover(family: str) -> bool:
-    """True when the measured PALLAS_PROBE artifact for this platform
-    records the fused kernel beating XLA for ``family`` ("brute_force",
-    "ivf_flat", "ivf_pq", "l2_argmin"). Conservative default: with no
-    measurement (or a pre-fused-schema artifact) every family reads
-    False, so ``scan_mode="auto"`` stays on XLA until hardware evidence
-    lands."""
-    from raft_tpu.ops.select_k import _platform_key
-
-    return bool(_load_fused_table().get(_platform_key(), {}).get(
-        family, False))
-
-
 def fused_dispatch(family: str, scan_mode: str):
     """Resolve ``(use_fused, interpret)`` for a family's search dispatch.
 
@@ -189,46 +53,12 @@ def fused_dispatch(family: str, scan_mode: str):
     the XLA engines — a CPU canary sharing a TPU fleet's serving config
     must not error.
 
-    ``scan_mode="auto"``: fused only on TPU at shapes/families where the
-    committed PALLAS_PROBE crossover records a win (``fused_crossover``).
+    ``scan_mode="auto"``: the XLA engines — no fused kernel has a chip
+    measurement that beats them.
 
     Anything else: never fused."""
     use_fused, interpret, _ = fused_dispatch_explained(family, scan_mode)
     return use_fused, interpret
-
-
-def _fused_verdict(family: str):
-    """The raw PALLAS_PROBE verdict for this platform+family: True/False
-    when measured, None when the artifact has no row — the distinction
-    the warn-once satellite hinges on (a measured loss is policy; a
-    missing verdict is the ROADMAP re-probe caveat)."""
-    from raft_tpu.ops.select_k import _platform_key
-
-    v = _load_fused_table().get(_platform_key(), {}).get(family)
-    return None if v is None else bool(v)
-
-
-_warned_no_verdict = False
-
-
-def _reset_fused_warn() -> None:
-    """Test hook: re-arm the once-per-process no-verdict warning."""
-    global _warned_no_verdict
-    _warned_no_verdict = False
-
-
-def _warn_no_verdict_once(family: str) -> None:
-    global _warned_no_verdict
-    if _warned_no_verdict:
-        return
-    _warned_no_verdict = True
-    logging.getLogger(__name__).warning(
-        "scan_mode='auto' is routing %s (and every family) to the XLA "
-        "engines on a TPU host because the loaded PALLAS_PROBE artifact "
-        "has no fused_wins verdicts — the fused Pallas hot path is OFF. "
-        "Run tools/pallas_probe.py on this hardware to record verdicts, "
-        "or force scan_mode='pallas'.",
-        family)
 
 
 def require_compiled_kernel(family: str, scan_mode: str, ineligible) -> None:
@@ -249,9 +79,7 @@ def fused_dispatch_explained(family: str, scan_mode: str):
     """``fused_dispatch`` plus the reason code: ``(use_fused, interpret,
     reason)`` with reason from ``obs.explain.REASONS`` — the attributed
     form the family ``search()`` entry points feed into their explain
-    records. Also the emission point for the once-per-process warning
-    when ``auto`` routes XLA on a TPU host only because the committed
-    probe artifact carries no verdict (ROADMAP caveat, now audible)."""
+    records."""
     interp = os.environ.get("RAFT_TPU_PALLAS_INTERPRET") == "1"
     on_tpu = jax.default_backend() == "tpu"
     if scan_mode == "pallas":
@@ -261,108 +89,12 @@ def fused_dispatch_explained(family: str, scan_mode: str):
             return True, True, "interpret"
         return False, False, "tpu_absent"
     if scan_mode == "auto":
-        if not on_tpu:
-            return False, False, "tpu_absent"
-        verdict = _fused_verdict(family)
-        if verdict:
-            return True, False, "auto_fused_wins"
-        if verdict is None:
-            _warn_no_verdict_once(family)
-            return False, False, "no_fused_wins_verdict"
-        return False, False, "fused_loses"
+        return False, False, "fused_unmeasured" if on_tpu else "tpu_absent"
     # an explicit engine name ("xla", "cache", "lut"): honored as asked
     return False, False, "forced"
 
 
-def fused_l2_argmin(x, y, x_norms=None, y_norms=None, tm: int = 256,
-                    tn: int = 512, interpret: bool = False):
-    """Fused squared-L2 + argmin via the Pallas kernel.
-
-    Returns (min_sq_dist [m], argmin [m]). Precomputed squared row norms
-    are honored (the k-means EM loop passes them every iteration).
-    ``interpret=True`` runs the Mosaic interpreter (CPU CI); tile sizes are
-    clamped to hardware-aligned shapes.
-    """
-    x = jnp.asarray(x)
-    y = jnp.asarray(y)
-    m, d = x.shape
-    n = y.shape[0]
-    if x_norms is None:
-        x_norms = jnp.sum(x.astype(jnp.float32) ** 2, -1)
-    if y_norms is None:
-        y_norms = jnp.sum(y.astype(jnp.float32) ** 2, -1)
-    tm = int(min(tm, round_up_to(m, 8)))
-    tn = int(min(tn, round_up_to(n, 128)))
-    tm = max(8, tm - tm % 8)
-    tn = max(128, tn - tn % 128)
-    return _fused_l2_argmin_pallas(x, y, x_norms, y_norms, tm, tn,
-                                   bool(interpret))
-
-
-# --------------------------------------------------------------- ivf scan
-
-
-def _ivf_scan_kernel(probes_ref, qvec_ref, dec_ref, norms_ref, out_ref):
-    """One (query, probe) step: out[pad] = norms[pad] − 2·dec[pad,rot]·q[rot].
-
-    ``dec_ref``/``norms_ref`` blocks are DMA'd from the probed list's slab —
-    the block index comes from the prefetched ``probes`` scalars, so the
-    gather never materializes in HBM (the fusion the reference gets from its
-    interleaved_scan kernel)."""
-    dots = jax.lax.dot_general(
-        dec_ref[0].astype(jnp.float32),  # bf16 in HBM; f32 math in VMEM
-        qvec_ref[0, 0].reshape(-1, 1).astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )  # [pad, 1]
-    out_ref[0, 0, :] = norms_ref[0] - 2.0 * dots[:, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _ivf_scan_pallas(probes, qres, list_decoded, decoded_norms,
-                     interpret: bool):
-    nq, n_probes = probes.shape
-    n_lists, list_pad, rot = list_decoded.shape
-    qres_c = qres.astype(jnp.float32)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nq, n_probes),
-        in_specs=[
-            pl.BlockSpec((1, 1, rot), lambda i, j, probes: (i, j, 0)),
-            pl.BlockSpec((1, list_pad, rot),
-                         lambda i, j, probes: (probes[i, j], 0, 0)),
-            pl.BlockSpec((1, list_pad),
-                         lambda i, j, probes: (probes[i, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, list_pad),
-                               lambda i, j, probes: (i, j, 0)),
-    )
-    return pl.pallas_call(
-        _ivf_scan_kernel,
-        out_shape=jax.ShapeDtypeStruct((nq, n_probes, list_pad), jnp.float32),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(probes.astype(jnp.int32), qres_c, list_decoded, decoded_norms)
-
-
-def ivf_scan(probes, qres, list_decoded, decoded_norms,
-             interpret: bool = False):
-    """Fused probe-gather + ADC/flat scan.
-
-    probes [nq, P] int32, qres [nq, P, rot] (per-probe query residual, or
-    the query itself replicated for flat scans), list_decoded
-    [L, pad, rot], decoded_norms [L, pad] → partial distances
-    [nq, P, pad] = ||list row||² − 2·q·row (caller adds ||q_res||² and
-    masks invalid slots). The scan reads each probed list slab exactly once
-    over ICI-free HBM DMA — no [nq, P, pad, rot] gather intermediate.
-    """
-    return _ivf_scan_pallas(probes, qres, list_decoded, decoded_norms,
-                            bool(interpret))
-
-
-# --------------------------------------------------------------- select_k
+# ------------------------------------------------------ in-kernel top-k
 
 
 def _extract_topk(work, ci, k: int, kp: int):
@@ -406,84 +138,6 @@ def _extract_topk(work, ci, k: int, kp: int):
     idxs0 = jnp.full((tb, kp), -1, jnp.int32)
     _, vals, idxs = jax.lax.fori_loop(0, k, body, (work, vals0, idxs0))
     return vals, idxs
-
-
-def _topk_kernel(x_ref, val_ref, idx_ref, *, k: int, kp: int, tn: int):
-    j = pl.program_id(1)
-    tile = x_ref[...].astype(jnp.float32)  # [TB, TN]
-    base = j * tn
-    tv, ti = _extract_topk(tile, None, k, kp)  # ascending, [TB, kp]
-    ti = jnp.where(ti >= 0, ti + base, -1)
-
-    @pl.when(j == 0)
-    def _():
-        val_ref[...] = tv
-        idx_ref[...] = ti
-
-    @pl.when(j > 0)
-    def _():
-        cv = jnp.concatenate([val_ref[...], tv], axis=1)  # [TB, 2·kp]
-        ci = jnp.concatenate([idx_ref[...], ti], axis=1)
-        mv, mi = _extract_topk(cv, ci, k, kp)
-        val_ref[...] = mv
-        idx_ref[...] = mi
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "tb", "tn", "interpret"))
-def _topk_pallas(values, k: int, tb: int, tn: int, interpret: bool):
-    b, n = values.shape
-    bp = round_up_to(b, tb)
-    np_ = round_up_to(n, tn)
-    kp = max(round_up_to(k, 128), 128)
-    x = jnp.pad(values.astype(jnp.float32), ((0, bp - b), (0, np_ - n)),
-                constant_values=jnp.inf)
-    grid = (bp // tb, np_ // tn)
-    val, idx = pl.pallas_call(
-        functools.partial(_topk_kernel, k=k, kp=kp, tn=tn),
-        out_shape=(jax.ShapeDtypeStruct((bp, kp), jnp.float32),
-                   jax.ShapeDtypeStruct((bp, kp), jnp.int32)),
-        grid=grid,
-        in_specs=[pl.BlockSpec((tb, tn), lambda i, j: (i, j),
-                               memory_space=pltpu.VMEM)],
-        out_specs=(pl.BlockSpec((tb, kp), lambda i, j: (i, 0),
-                                memory_space=pltpu.VMEM),
-                   pl.BlockSpec((tb, kp), lambda i, j: (i, 0),
-                                memory_space=pltpu.VMEM)),
-        interpret=interpret,
-    )(x)
-    return val[:b, :k], idx[:b, :k]
-
-
-def pallas_select_k(values, k: int, select_min: bool = True,
-                    tb: int = 128, tn: int = 2048,
-                    interpret: bool = False):
-    """Streaming Pallas top-k: per-tile k-extraction merged into a running
-    VMEM buffer — the row is read from HBM exactly once and no [b, n] sort
-    intermediate exists (the radix/warpsort role of matrix::select_k for
-    small k; best for k ≤ ~32).
-
-    Returns (values [b, k], indices [b, k]) ascending (descending for
-    ``select_min=False``). Ties may resolve to different (equally valid)
-    indices than lax.top_k.
-    """
-    values = jnp.asarray(values)
-    b, n = values.shape
-    if k > 1024:
-        raise ValueError(
-            f"pallas select_k is a small-k algorithm (k={k} > 1024); "
-            "use DIRECT/TWO_PHASE")
-    tb = max(8, min(tb, round_up_to(b, 8)))
-    tb -= tb % 8
-    tn = max(128, min(tn, round_up_to(n, 128)))
-    tn -= tn % 128
-    # each tile must be able to surface k distinct candidates
-    tn = max(tn, round_up_to(k, 128))
-    v = values if select_min else -values
-    out_v, out_i = _topk_pallas(v, int(k), tb, tn, bool(interpret))
-    out_v = out_v if select_min else -out_v
-    # match DIRECT/TWO_PHASE: values come back in the input dtype
-    return out_v.astype(values.dtype), out_i
 
 
 # ---------------------------------------------------- fused scan + select
@@ -1259,8 +913,7 @@ def fused_ivf_topk(probes, qres, qres_norms, list_data, row_norms,
     ``(distances [nq, k], ids [nq, k])`` ascending squared-L2, -1 ids
     where fewer than k valid candidates were probed.
 
-    Unlike ``ivf_scan`` the [nq, P, pad] candidate slab never exists in
-    HBM: each probed slab tile is DMA'd to VMEM (scalar-prefetch block
+    The [nq, P, pad] candidate slab never exists in HBM: each probed slab tile is DMA'd to VMEM (scalar-prefetch block
     index) and merged straight into the query's resident top-k carry.
     ``pad_tile`` must divide the list layout's pad exactly (default: the
     VMEM-budget solve, ``plan_fused_ivf_tile``); ``clamp`` applies
@@ -1920,9 +1573,8 @@ def fused_cagra_topk(queries, dataset, graph, seed_ids, k: int,
 # transfer overlaps the local lex-merge of the block received last step
 # instead of round-tripping through an XLA collective slab. Same contract
 # as ``Comms.shift(x, 1)``: device r's output is device (r-1)'s input.
-# Routing discipline mirrors the fused scan kernels: ``merge_mode="auto"``
-# only takes this path on TPU when the PALLAS_PROBE artifact records a
-# ``merge_ring`` fused_wins verdict (tools/pallas_probe.py).
+# Like the fused scan kernels it has no chip measurement: only
+# ``merge_mode="ring"`` takes it; ``auto`` merges by the tree.
 
 _RING_COLLECTIVE_ID = 1
 
@@ -1968,11 +1620,3 @@ def pallas_ring_shift(x, axis: str, size: int, interpret: bool = False):
             collective_id=_RING_COLLECTIVE_ID),
         interpret=interpret,
     )(x)
-
-
-def ring_merge_verdict():
-    """The PALLAS_PROBE ``merge_ring`` verdict for this platform: True /
-    False when measured, None when the artifact has no row — the same
-    three-state discipline the fused scan kernels use, so ``auto`` never
-    routes the RDMA merge without hardware evidence."""
-    return _fused_verdict("merge_ring")

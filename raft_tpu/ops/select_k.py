@@ -20,8 +20,6 @@ from __future__ import annotations
 
 import enum
 import functools
-import json
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -36,7 +34,6 @@ class SelectAlgo(enum.Enum):
     AUTO = "auto"
     DIRECT = "direct"  # single lax.top_k over the full row
     TWO_PHASE = "two_phase"  # per-tile top-k, then merge (wide rows)
-    PALLAS = "pallas"  # streaming k-extraction kernel (small k, wide rows)
     APPROX = "approx"  # TPU PartialReduce (lax.approx_min_k), recall<1
 
 
@@ -62,35 +59,6 @@ _BUILTIN_TABLES = {
     "default": {"32": 65536, "256": 65536, "inf": 131072},
 }
 _auto_table_cache: Optional[dict] = None
-
-
-def _scan_artifacts(tables: dict, prefix: str, env_var: str, extract):
-    """Fill ``tables`` (platform -> table) from measured artifacts:
-    ``<prefix>_*.json`` at the repo root (anchored via __file__, so the
-    choice can't depend on launch directory) and in cwd. Malformed ambient
-    artifacts are skipped; the ``env_var`` override is loaded LAST and
-    OUTSIDE the try (explicit requests fail loudly and win over ambient
-    artifacts)."""
-    import glob
-
-    repo_root = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    paths = sorted(
-        set(glob.glob(os.path.join(repo_root, prefix + "_*.json")))
-        | set(glob.glob(prefix + "_*.json")))
-    for path in paths:
-        try:
-            with open(path) as f:
-                art = json.load(f)
-            tables[art["platform"]] = extract(art)
-        except (OSError, KeyError, ValueError, TypeError):
-            pass  # malformed artifact: keep what we have
-    path = os.environ.get(env_var)
-    if path:
-        with open(path) as f:
-            art = json.load(f)
-        tables[art["platform"]] = extract(art)
-    return tables
 
 
 def _load_auto_table() -> dict:
@@ -129,51 +97,54 @@ def _band(table: dict, k: int):
 
 # ------------------------------------------------------------- k-pad rules
 #
-# XLA:TPU's top_k lowering has pointwise-pathological (n, k) cells: both
-# the r3 and r4 hardware sweeps measured (n=4096, k=10) at 112-120 ms for
-# batch 2048 while k=32 at the SAME width runs in 1.7-2.3 ms and k=10 on
+# XLA:TPU's top_k lowering has pointwise-pathological (n, k) cells: the r3
+# and r4 hardware sweeps (batch 2048) measured (n=4096, k=10) at
+# 112-120 ms while k=32 at the SAME width runs in 1.7-2.3 ms and k=10 on
 # wider rows in 1-3 ms. top_k(x, k')[..., :k] is exact for any k' >= k
 # (the output is descending-sorted, ties broken by lower index, and the
 # prefix of a larger selection is the smaller selection), so the fix is a
-# trace-time rewrite of the REQUESTED k. Which cells win is measured by
-# tools/topk_k_probe.py (2x bar) into TOPK_PAD_<platform>.json; rules are
-# matched by exact k and nearby width (x1.25 — pointwise pathologies don't
-# extrapolate, cf. the reference picking select algorithms per shape,
-# detail/select_k-inl.cuh:48).
-_pad_rules_cache: Optional[dict] = None
-
-# The one cell measured pathological in BOTH hardware sessions (r3:
-# 112.4 ms, r4: 119.7 ms for batch 2048 — vs 1.7-2.3 ms at k=32, same
-# width, same sessions). Shipped as a builtin so the fix holds even
-# when no TOPK_PAD artifact has been produced. Artifacts MERGE with the
-# builtins per (n, k) cell (see _merge_pad_rules): a builtin survives
-# unless the artifact measured that exact cell — the shipped
-# TOPK_PAD_tpu.json has no n=4096 row, and letting it replace the whole
-# table silently disarmed this fix (ADVICE r5).
+# trace-time rewrite of the REQUESTED k. Rules are matched by exact k and
+# nearby width (x1.25 — pointwise pathologies don't extrapolate, cf. the
+# reference picking select algorithms per shape,
+# detail/select_k-inl.cuh:48). The (4096, 10) "tpu" row is the cell the
+# r3 and r4 sweeps (batch 2048) both measured pathological; the other 19
+# come from one later sweep at batch 2048 (2026-08-02), each padded k at
+# least 2x faster than the requested one, a sweep its round's review
+# found noisy from host-core contention. None was measured on the chip
+# setup the benchmark now runs; a PR that measures a cell changes its
+# row here.
 _BUILTIN_PAD_RULES = {
-    "tpu": [{"n": 4096, "k": 10, "k_pad": 32}],
+    "tpu": [
+        {"n": 1024, "k": 4, "k_pad": 64},
+        {"n": 1024, "k": 8, "k_pad": 64},
+        {"n": 1024, "k": 32, "k_pad": 64},
+        {"n": 2048, "k": 4, "k_pad": 32},
+        {"n": 2048, "k": 10, "k_pad": 32},
+        {"n": 2048, "k": 12, "k_pad": 32},
+        {"n": 2048, "k": 16, "k_pad": 32},
+        {"n": 2048, "k": 24, "k_pad": 32},
+        {"n": 2048, "k": 40, "k_pad": 48},
+        {"n": 6144, "k": 4, "k_pad": 24},
+        {"n": 6144, "k": 8, "k_pad": 24},
+        {"n": 6144, "k": 12, "k_pad": 24},
+        {"n": 8192, "k": 8, "k_pad": 16},
+        {"n": 8192, "k": 10, "k_pad": 16},
+        {"n": 16384, "k": 8, "k_pad": 40},
+        {"n": 16384, "k": 12, "k_pad": 40},
+        {"n": 16384, "k": 32, "k_pad": 40},
+        {"n": 32768, "k": 4, "k_pad": 16},
+        {"n": 32768, "k": 8, "k_pad": 16},
+        {"n": 4096, "k": 10, "k_pad": 32},
+    ],
 }
-
-
-def _merge_pad_rules(builtin: list, measured) -> list:
-    """Measured artifact rules + the builtins for cells the artifact did
-    not measure. A measured (n, k) always wins — including "no pad needed"
-    entries (k_pad == k), which deliberately override a builtin."""
-    measured = [dict(r) for r in measured]
-    seen = {(r["n"], r["k"]) for r in measured}
-    return measured + [dict(r) for r in builtin
-                       if (r["n"], r["k"]) not in seen]
+_pad_rules_cache: Optional[dict] = None
 
 
 def _load_pad_rules() -> dict:
     global _pad_rules_cache
     if _pad_rules_cache is None:
-        _pad_rules_cache = _scan_artifacts(
-            {k: [dict(r) for r in v] for k, v in _BUILTIN_PAD_RULES.items()},
-            "TOPK_PAD", "RAFT_TPU_TOPK_PAD",
-            lambda art: _merge_pad_rules(
-                _BUILTIN_PAD_RULES.get(art["platform"], []),
-                art["pad_rules"]))
+        _pad_rules_cache = {k: [dict(r) for r in v]
+                            for k, v in _BUILTIN_PAD_RULES.items()}
     return _pad_rules_cache
 
 
@@ -193,9 +164,7 @@ def _pad_k(n: int, k: int) -> int:
     width ratio), else k unchanged. The top_k pathologies are pointwise
     in (n, k) and don't extrapolate, so the window is deliberately tight
     — just wide enough to cover tile widths adjacent to a measured power
-    of two (e.g. a 5000-wide balanced tile under the 4096 rule) until
-    tools/topk_k_probe.py has mapped the neighboring widths on hardware
-    (ADVICE r4)."""
+    of two (e.g. a 5000-wide balanced tile under the 4096 rule)."""
     rules = _load_pad_rules().get(_platform_key(), [])
     best = None
     for r in rules:
@@ -269,13 +238,6 @@ def _two_phase(values: jax.Array, k: int, select_min: bool):
     "k", "select_min", "algo", "recall", "k_pad"))
 def _select_k_jit(values, k, select_min, algo, recall=0.95, k_pad=0):
     assert algo != SelectAlgo.AUTO  # resolved in select_k(), pre-cache
-    if algo == SelectAlgo.PALLAS:
-        from raft_tpu.ops.pallas_kernels import pallas_select_k
-
-        # an explicit algo request is the opt-in: hardware path on TPU,
-        # Mosaic interpreter elsewhere (CPU CI)
-        return pallas_select_k(values, k, select_min,
-                               interpret=_platform_key() != "tpu")
     if algo == SelectAlgo.APPROX:
         return _approx(values, k, select_min, recall)
     if algo == SelectAlgo.DIRECT:
@@ -304,33 +266,30 @@ def select_k(
     in through their search params where the recall trade is theirs to
     make.
 
-    ``pad_rules=False`` skips the TOPK_PAD k-padding lookup. The measured
+    ``pad_rules=False`` skips the k-pad rules (``_pad_k``). The measured
     rules model an HBM-resident select over a raw scan slab; callers whose
     selection already happened inside a fused Pallas kernel (the input is
     a short merged candidate list, not a slab) must not be re-padded on
     top of the in-kernel carry width.
     """
     values = jnp.asarray(values)
+    algo = SelectAlgo(algo)  # a name outside the enum raises ValueError
     if values.ndim == 1:
         v, i = select_k(values[None], k, select_min, None, algo,
                         recall_target, pad_rules)
         v, i = v[0], i[0]
         if indices is not None:
-            # preserve -1 null markers (PALLAS exhausted-row convention)
-            i = jnp.where(i < 0, -1,
-                          jnp.asarray(indices)[jnp.maximum(i, 0)])
+            i = jnp.asarray(indices)[i]
         return v, i
     if k > values.shape[-1]:
         raise ValueError(f"k={k} > row length {values.shape[-1]}")
     if algo == SelectAlgo.AUTO:
         # Resolve BEFORE the jit boundary: the concrete algo is the compile
         # key, so later set_auto_table() changes apply to fresh calls
-        # instead of being baked into a cached AUTO trace. (AUTO never
-        # picks PALLAS — its extraction is O(k) serial rounds, wrong for
-        # the IVF k=64-256 band.)
+        # instead of being baked into a cached AUTO trace.
         algo = _resolve_auto(values.shape[-1], int(k))
     # pad rules resolve pre-jit too: the padded k is part of the compile
-    # key, so installing/dropping TOPK_PAD rules retraces fresh calls
+    # key, so set_pad_rules() changes retrace fresh calls
     k_pad = _pad_k(values.shape[-1], int(k)) if (
         pad_rules and algo == SelectAlgo.DIRECT) else 0
     # capture-only explain note: this body runs at TRACE time inside the
@@ -341,11 +300,7 @@ def select_k(
     out_v, out_i = _select_k_jit(values, int(k), bool(select_min), algo,
                                  float(recall_target), k_pad)
     if indices is not None:
-        # preserve -1 null markers (PALLAS exhausted-row convention) —
-        # take_along_axis would wrap -1 to the last column's real id
-        relabeled = jnp.take_along_axis(jnp.asarray(indices),
-                                        jnp.maximum(out_i, 0), axis=1)
-        out_i = jnp.where(out_i < 0, -1, relabeled)
+        out_i = jnp.take_along_axis(jnp.asarray(indices), out_i, axis=1)
     return out_v, out_i
 
 
@@ -395,7 +350,7 @@ def select_k_filtered(
 def select_k_plan(n: int, k: int, pad_rules: bool = True) -> dict:
     """The resolution ``select_k`` would make for a [*, n] row at this k,
     WITHOUT running it: ``{"algo", "k_pad"}`` from the measured
-    AUTO table and TOPK_PAD rules. The dry-run surface ``tools/explain.py``
+    AUTO table and k-pad rules. The dry-run surface ``tools/explain.py``
     prints so an operator can see the selection plan of a query shape
     before paying a compile."""
     algo = _resolve_auto(int(n), int(k))

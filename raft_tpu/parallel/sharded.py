@@ -413,14 +413,11 @@ def plan_cache_clear() -> None:
 def merge_dispatch_explained(merge_mode: str, size: int):
     """Resolve the cross-chip merge engine: ``(engine, reason,
     ring_shift)`` with reason from ``obs.explain.REASONS`` — the merge
-    analog of ``ops.pallas_kernels.fused_dispatch_explained``, sharing its
-    verdict discipline: ``auto`` only routes the RDMA ring kernel on TPU
-    when the PALLAS_PROBE artifact records a ``merge_ring`` win; with no
-    verdict it stays on the pure-XLA tree merge (safe everywhere) and
-    says so. Non-power-of-two meshes fall back to all_gather (the tree
-    pairs ranks by XOR)."""
-    from raft_tpu.ops import pallas_kernels
-
+    analog of ``ops.pallas_kernels.fused_dispatch_explained``. ``auto``
+    takes the pure-XLA tree merge on any power-of-two mesh, on and off the
+    chip: the RDMA ring kernel has no chip measurement, so it runs only
+    when asked for (``ring``). Non-power-of-two meshes fall back to
+    all_gather (the tree pairs ranks by XOR)."""
     on_tpu = jax.default_backend() == "tpu"
     interp = os.environ.get("RAFT_TPU_PALLAS_INTERPRET") == "1"
     pow2 = size >= 2 and (size & (size - 1)) == 0
@@ -447,13 +444,6 @@ def merge_dispatch_explained(merge_mode: str, size: int):
                          f"(one of {MERGE_MODES})")
     if not pow2:
         return "allgather", "merge_allgather", ""
-    if on_tpu:
-        verdict = pallas_kernels.ring_merge_verdict()
-        if verdict:
-            return "ring", "merge_ring", "pallas"
-        if verdict is None:
-            return "tree", "no_ring_verdict", ""
-        return "tree", "fused_loses", ""
     return "tree", "merge_tree", ""
 
 

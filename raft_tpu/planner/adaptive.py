@@ -10,9 +10,8 @@ methodology, PAPERS.md):
 
 - ``tools/autotune.py`` sweeps the knob grid offline against an exact
   oracle and commits the non-dominated QPS-vs-recall frontier as
-  ``PARETO_<platform>.json`` (:data:`PARETO_SCHEMA`, same artifact
-  discipline as PALLAS_PROBE / SELECT_K_TABLE: schema-versioned, flat
-  ``"metrics"`` mirror, diffed by ``tools/bench_gate.py``'s curve-aware
+  ``PARETO_<platform>.json`` (:data:`PARETO_SCHEMA`: schema-versioned,
+  flat ``"metrics"`` mirror, diffed by ``tools/bench_gate.py``'s curve-aware
   ``frontier`` kind);
 - :func:`choose_operating_point` is the policy: given a frontier and the
   batch's remaining latency budget, return the highest-recall point

@@ -70,28 +70,6 @@ def test_interruptible_cancel_from_other_thread():
     assert result.get("cancelled")
 
 
-def test_pallas_fused_l2_argmin_interpret(rng):
-    from raft_tpu.ops import pallas_kernels as pk
-
-    x = rng.standard_normal((100, 32)).astype(np.float32)
-    y = rng.standard_normal((300, 32)).astype(np.float32)
-    v, i = pk.fused_l2_argmin(x, y, interpret=True)
-    d = ((x[:, None, :] - y[None, :, :]) ** 2).sum(-1)
-    np.testing.assert_array_equal(np.asarray(i), d.argmin(1))
-    np.testing.assert_allclose(np.asarray(v), d.min(1), rtol=1e-3, atol=1e-3)
-
-
-def test_pallas_fused_l2_argmin_unaligned(rng):
-    from raft_tpu.ops import pallas_kernels as pk
-
-    # shapes that aren't multiples of the tile sizes
-    x = rng.standard_normal((37, 24)).astype(np.float32)
-    y = rng.standard_normal((131, 24)).astype(np.float32)
-    v, i = pk.fused_l2_argmin(x, y, tm=16, tn=128, interpret=True)
-    d = ((x[:, None, :] - y[None, :, :]) ** 2).sum(-1)
-    np.testing.assert_array_equal(np.asarray(i), d.argmin(1))
-
-
 # ---------------------------------------------------------------------------
 # operators / errors / resources_manager (core/operators.hpp, core/error.hpp,
 # core/device_resources_manager.hpp)
@@ -196,22 +174,6 @@ def test_spatial_namespace(rng):
     pts = np.radians([[51.5, -0.13], [48.86, 2.35]]).astype(np.float32)
     h = np.asarray(spatial.haversine_distance(pts, pts))
     assert h.shape == (2, 2) and h[0, 1] > 0
-
-
-def test_pallas_ivf_scan_interpret(rng):
-    from raft_tpu.ops import pallas_kernels as pk
-
-    L, pad, rot, nq, P = 6, 16, 8, 5, 3
-    dec = rng.standard_normal((L, pad, rot)).astype(np.float32)
-    norms = (dec ** 2).sum(-1).astype(np.float32)
-    probes = rng.integers(0, L, (nq, P)).astype(np.int32)
-    qres = rng.standard_normal((nq, P, rot)).astype(np.float32)
-    out = np.asarray(pk.ivf_scan(probes, qres, dec, norms, interpret=True))
-    ref = np.stack([
-        np.stack([norms[probes[i, j]]
-                  - 2.0 * dec[probes[i, j]] @ qres[i, j]
-                  for j in range(P)]) for i in range(nq)])
-    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
 
 
 def test_device_ndarray_torch_interop():

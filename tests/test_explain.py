@@ -160,49 +160,30 @@ def test_forced_scan_mode_reasons(db, queries):
     assert rec.plan["memory_auto"] is False
 
 
-# ------------------------------------------------ the warn-once satellite
+# ------------------------------------------------ auto on a TPU backend
 
-def test_no_verdict_warns_exactly_once(monkeypatch, caplog):
-    # fake a TPU backend with a verdict-free probe table: auto must
-    # route XLA with reason no_fused_wins_verdict and say so ONCE
+@pytest.mark.parametrize("family", ["brute_force", "ivf_flat", "ivf_pq",
+                                    "cagra"])
+def test_auto_stays_on_xla_on_tpu(family, monkeypatch, caplog):
+    # no fused kernel has a chip measurement beating XLA: auto routes
+    # XLA on a TPU backend with reason fused_unmeasured, and logs nothing
     monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(pk, "_fused_verdict", lambda family: None)
-    pk._reset_fused_warn()
-    with caplog.at_level(logging.WARNING,
+    with caplog.at_level(logging.DEBUG,
                          logger="raft_tpu.ops.pallas_kernels"):
-        for family in ("brute_force", "ivf_flat", "ivf_pq"):
-            use_fused, interp, reason = pk.fused_dispatch_explained(
-                family, "auto")
-            assert (use_fused, interp) == (False, False)
-            assert reason == "no_fused_wins_verdict"
-    warnings = [r for r in caplog.records
-                if "fused_wins" in r.getMessage()]
-    assert len(warnings) == 1, [r.getMessage() for r in warnings]
-    assert "pallas_probe" in warnings[0].getMessage()
-    pk._reset_fused_warn()
+        assert pk.fused_dispatch_explained(family, "auto") == (
+            False, False, "fused_unmeasured")
+    assert not caplog.records, [r.getMessage() for r in caplog.records]
 
 
-def test_measured_loss_does_not_warn(monkeypatch, caplog):
-    # a measured fused_loses verdict is routing policy, not a gap —
-    # silent by design
-    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(pk, "_fused_verdict", lambda family: False)
-    pk._reset_fused_warn()
-    with caplog.at_level(logging.WARNING,
-                         logger="raft_tpu.ops.pallas_kernels"):
-        assert pk.fused_dispatch_explained("brute_force", "auto") == (
-            False, False, "fused_loses")
-        assert pk.fused_dispatch_explained("ivf_flat", "auto")[2] == \
-            "fused_loses"
-    assert not [r for r in caplog.records
-                if "fused_wins" in r.getMessage()]
+def test_merge_auto_is_tree_on_tpu(monkeypatch):
+    from raft_tpu.parallel import sharded
 
-
-def test_auto_fused_wins_on_verdict(monkeypatch):
-    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(pk, "_fused_verdict", lambda family: True)
-    assert pk.fused_dispatch_explained("ivf_pq", "auto") == (
-        True, False, "auto_fused_wins")
+    monkeypatch.setattr(sharded.jax, "default_backend", lambda: "tpu")
+    assert sharded.merge_dispatch_explained("auto", 4) == (
+        "tree", "merge_tree", "")
+    # the ring kernel runs only when asked for
+    assert sharded.merge_dispatch_explained("ring", 4) == (
+        "ring", "forced", "pallas")
 
 
 def test_dispatch_counts_reads_custom_registry():

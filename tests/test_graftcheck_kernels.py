@@ -179,6 +179,53 @@ def test_k003_numeric_alignment_tolerates_subtile_dims():
     assert len(bad) == 1 and "lane dim 200" in bad[0]
 
 
+_K003_GRID_SPEC_SRC = _PALLAS_HEADER + (
+    "import jax\n\n\n"
+    "def _merge(o_ref, x, first):\n"
+    "    @pl.when(first)\n"
+    "    def _():\n"
+    "        o_ref[...] = x\n\n"
+    "    @pl.when(jax.numpy.logical_not(first))\n"
+    "    def _():\n"
+    "        o_ref[...] = o_ref[...] + x\n\n\n"
+    "def _tile_kernel(p_ref, x_ref, o_ref):\n"
+    "    o_ref[...] = x_ref[...]\n\n\n"
+    "def _acc_kernel(p_ref, x_ref, o_ref):\n"
+    "    j = pl.program_id(1)\n"
+    "    {acc}\n\n\n"
+    "def tiles(p, x):\n"
+    "    grid_spec = pltpu.PrefetchScalarGridSpec(\n"
+    "        num_scalar_prefetch=1, grid=(4, 4),\n"
+    "        in_specs=[pl.BlockSpec((8, 128), lambda i, j, p: (i, j))],\n"
+    "        out_specs=pl.BlockSpec((8, 128), lambda i, j, p: (i, j)))\n"
+    "    return pl.pallas_call(_tile_kernel, grid_spec=grid_spec,\n"
+    "                          out_shape=x)(p, x)\n\n\n"
+    "def reduce_cols(p, x):\n"
+    "    grid_spec = pltpu.PrefetchScalarGridSpec(\n"
+    "        num_scalar_prefetch=1, grid=(4, 4),\n"
+    "        in_specs=[pl.BlockSpec((8, 128), lambda i, j, p: (i, j))],\n"
+    "        out_specs=pl.BlockSpec((8, 128), lambda i, j, p: (i, 0)))\n"
+    "    return pl.pallas_call(_acc_kernel, grid_spec=grid_spec,\n"
+    "                          out_shape=x)(p, x)\n"
+)
+
+
+@pytest.mark.parametrize("acc,flagged", [
+    ("o_ref[...] = o_ref[...] + x_ref[...]", True),
+    ("_merge(o_ref, x_ref[...], j == 0)", False),
+    ("_merge(o_ref, x_ref[...], j == 1)", True),
+], ids=["no_init", "init_in_helper", "helper_guard_not_first"])
+def test_k003_revisit_init_reads_each_sites_grid_spec(tmp_path, acc,
+                                                      flagged):
+    # two wrappers bind the same grid_spec name: each pallas_call is
+    # checked against its own, so the revisiting reduce_cols is seen; a
+    # first-visit init may sit in a helper guarded on its parameter
+    mod = tmp_mod(tmp_path, "two_specs.py",
+                  _K003_GRID_SPEC_SRC.format(acc=acc))
+    found = [(f.rule, f.qualname) for f in rule_tile_alignment(mod)]
+    assert found == ([("K003", "_acc_kernel")] if flagged else []), found
+
+
 def test_k004_passthrough_kwarg_is_not_a_divergence(tmp_path):
     src = (
         "from jax.experimental import pallas as pl  # noqa: F401\n\n\n"
@@ -286,34 +333,11 @@ def test_sweep_warns_once_when_pallas_is_unavailable(monkeypatch, caplog):
 # ------------------------------------------------------ the artifact gate
 
 def test_artifacts_gate_is_clean_and_reports_no_stale_probe():
-    # no PALLAS_PROBE artifact is committed (the pre-fused one was taken
-    # off a chip setup that no longer exists): nothing can read stale
+    # every committed artifact loads under its reader, and none is a
+    # kernel-verdict probe: kernel and k choices live in code
     findings, report = run_artifacts(REPO)
     assert findings == [], "\n".join(f.format() for f in findings)
     assert not [ln for ln in report if "STALE pre-v3" in ln]
-
-
-def test_artifacts_gate_reports_a_stale_pre_v3_probe(tmp_path):
-    # a probe artifact with no fused section is reported STALE, naming
-    # every family whose verdict it lacks
-    import shutil
-    (tmp_path / "tools").mkdir()
-    shutil.copy(os.path.join(REPO, "tools", "pallas_probe.py"),
-                tmp_path / "tools" / "pallas_probe.py")
-    (tmp_path / "PALLAS_PROBE_tpu.json").write_text(json.dumps({
-        "platform": "tpu", "fused_l2_argmin": {}}))
-    findings, report = run_artifacts(str(tmp_path))
-    stale = [ln for ln in report if "STALE pre-v3" in ln]
-    assert len(stale) == 1 and "PALLAS_PROBE_tpu.json" in stale[0]
-    assert "cagra" in stale[0] and "ivf_pq" in stale[0]
-
-
-def test_artifacts_gate_flags_a_loader_rejected_table(tmp_path):
-    (tmp_path / "TOPK_PAD_x.json").write_text(
-        json.dumps({"platform": "x", "pad_rules": [{"n": 4096, "k": 10}]}))
-    findings, _ = run_artifacts(str(tmp_path))
-    rules = sorted({(f.rule, f.file) for f in findings})
-    assert ("A001", "TOPK_PAD_x.json") in rules
 
 
 def test_artifacts_gate_flags_unparseable_json(tmp_path):
@@ -321,20 +345,6 @@ def test_artifacts_gate_flags_unparseable_json(tmp_path):
     findings, _ = run_artifacts(str(tmp_path))
     assert any(f.file == "BROKEN.json" and "does not parse" in f.message
                for f in findings)
-
-
-def test_artifacts_gate_flags_v3_probe_with_missing_verdicts(tmp_path):
-    import shutil
-    (tmp_path / "tools").mkdir()
-    shutil.copy(os.path.join(REPO, "tools", "pallas_probe.py"),
-                tmp_path / "tools" / "pallas_probe.py")
-    (tmp_path / "PALLAS_PROBE_tpu.json").write_text(json.dumps({
-        "platform": "tpu",
-        "fused": {"brute_force": {"fused_wins": True}}}))
-    findings, _ = run_artifacts(str(tmp_path))
-    (f,) = [f for f in findings if f.file == "PALLAS_PROBE_tpu.json"]
-    assert "missing measured verdicts" in f.message
-    assert "cagra" in f.message
 
 
 # --------------------------------------------------------------- the gate
@@ -355,7 +365,7 @@ def test_kernel_scan_is_not_vacuous():
     # the scan must have actually seen the fused engines
     s = kernel_stats(REPO)
     assert s["modules"] >= 1, s
-    assert s["pallas_calls"] >= 8, s
+    assert s["pallas_calls"] >= 7, s
     assert s["fused_kernels"] >= 4, s
     assert s["dma_sites"] >= 10, s
 

@@ -6,7 +6,6 @@ import io
 
 import numpy as np
 import pytest
-import jax.numpy as jnp
 
 from raft_tpu import Resources
 from raft_tpu.core.bitset import Bitset
@@ -161,22 +160,6 @@ def test_helpers_pack_unpack(data):
     assert int(np.asarray(idx2.list_sizes)[2]) == 3
     np.testing.assert_allclose(ivf_flat.helpers.unpack_list_data(idx2, 2),
                                vecs[:3], rtol=1e-6)
-
-
-def test_pallas_scan_path_matches_xla(data):
-    db, q = data
-    index = ivf_flat.build(db, ivf_flat.IndexParams(n_lists=16))
-    empty = jnp.zeros((0,), jnp.uint32)
-    args = (jnp.asarray(q[:16]), index.centers, index.list_data,
-            index.list_indices, index.list_sizes, empty, index.metric,
-            10, 8, 16, False)
-    d1, i1 = ivf_flat._search_core(*args)
-    d2, i2 = ivf_flat._search_core(
-        *args, row_norms=index.ensure_row_norms(), use_pallas=True,
-        pallas_interpret=True)
-    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
-    np.testing.assert_allclose(np.asarray(d1), np.asarray(d2),
-                               rtol=1e-3, atol=1e-3)
 
 
 @pytest.mark.parametrize("dt", [np.int8, np.uint8])

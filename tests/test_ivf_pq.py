@@ -289,25 +289,6 @@ def test_helpers_codepacker_roundtrip(data):
     assert rel < 0.5  # coarse: PQ reconstruction error bounded
 
 
-def test_pallas_scan_path_matches_xla(data):
-    """The fused Pallas probe-scan (interpret mode) must agree with the XLA
-    gather+einsum cache path."""
-    db, q = data
-    params = ivf_pq.IndexParams(n_lists=16, pq_dim=16, kmeans_n_iters=4)
-    index = ivf_pq.build(db, params)
-    ivf_pq.ensure_scan_cache(index)
-    empty = jnp.zeros((0,), jnp.uint32)
-    args = (jnp.asarray(q[:20]), index.centers, index.rotation,
-            index.list_decoded, index.decoded_norms, index.list_indices,
-            index.list_sizes, empty, index.metric, 10, 8, 32, False)
-    d1, i1 = ivf_pq._search_cache_core(*args)
-    d2, i2 = ivf_pq._search_cache_core(*args, use_pallas=True,
-                                       pallas_interpret=True)
-    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
-    np.testing.assert_allclose(np.asarray(d1), np.asarray(d2),
-                               rtol=1e-3, atol=1e-3)
-
-
 @pytest.mark.parametrize("pq_bits", [4, 5])
 def test_low_bit_end_to_end(data, gt, pq_bits):
     """Whole-index build→search at pq_bits<8 (the deep-100M reference config
@@ -578,7 +559,6 @@ def _lower_scan(engine: str, overflow: bool, table, dist):
     if engine == "cache":
         return ivf_pq._search_cache_jit.lower(
             *head, s((n_lists, pad, rot), table), s((n_lists, pad)), *tail,
-            use_pallas=False, pallas_interpret=False,
             dist_dtype=jnp.dtype(dist).name, **common, **over)
     return ivf_pq._search_jit.lower(
         *head, s((pq_dim, 256, rot // pq_dim)),

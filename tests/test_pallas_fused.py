@@ -374,19 +374,7 @@ def test_fused_dispatch_cpu_defaults():
     assert pk.fused_dispatch("brute_force", "auto") == (False, False)
 
 
-def test_fused_crossover_reads_probe_verdicts():
-    key = pk.fused_platform_key()
-    try:
-        pk.set_fused_crossover(key, {"brute_force": True, "ivf_pq": False})
-        assert pk.fused_crossover("brute_force") is True
-        assert pk.fused_crossover("ivf_pq") is False
-        assert pk.fused_crossover("ivf_flat") is False  # unmeasured
-    finally:
-        pk.set_fused_crossover(key, None)
-    assert pk.fused_crossover("brute_force") is False  # conservative
-
-
-# --------------------------------------------- TOPK_PAD exemption (no 2x pad)
+# ------------------------------------------- k-pad rule exemption (no 2x pad)
 
 def test_select_k_pad_rules_flag_controls_k_padding():
     import importlib

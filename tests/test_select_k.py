@@ -78,40 +78,14 @@ def test_two_phase_matches_direct_largest(rng):
                                np.sort(np.asarray(v2), 1), rtol=1e-6)
 
 
-@pytest.mark.parametrize("shape,k", [((16, 1000), 5), ((64, 4096), 32),
-                                     ((8, 300), 10)])
-def test_pallas_algo_matches_direct(shape, k, rng):
-    """Streaming Pallas k-extraction agrees with lax.top_k (values exactly;
-    indices up to ties)."""
-    x = rng.standard_normal(shape).astype(np.float32)
-    for select_min in (True, False):
-        v_p, i_p = select_k(x, k, select_min=select_min,
-                            algo=SelectAlgo.PALLAS)
-        v_d, _ = select_k(x, k, select_min=select_min,
-                          algo=SelectAlgo.DIRECT)
-        np.testing.assert_allclose(np.asarray(v_p), np.asarray(v_d),
-                                   rtol=1e-6)
-        picked = np.take_along_axis(x, np.asarray(i_p), axis=1)
-        np.testing.assert_allclose(picked, np.asarray(v_d), rtol=1e-6)
-
-
-def test_pallas_inf_rows_and_wide_k(rng):
-    """Rows with fewer than k finite entries emit -1 null indices (no
-    duplicate picks); k wider than the column tile still selects exactly."""
-    from raft_tpu.ops.pallas_kernels import pallas_select_k
-
-    x = np.full((8, 256), np.inf, np.float32)
-    x[:, 0] = 1.0
-    x[:, 100] = 2.0
-    v, i = pallas_select_k(x, 4, interpret=True)
-    np.testing.assert_array_equal(np.asarray(i)[0], [0, 100, -1, -1])
-
-    y = rng.standard_normal((8, 1024)).astype(np.float32)
-    v, i = pallas_select_k(y, 200, tn=128, interpret=True)
-    np.testing.assert_allclose(np.asarray(v), np.sort(y, 1)[:, :200],
-                               rtol=1e-6)
-    with pytest.raises(ValueError, match="small-k"):
-        pallas_select_k(y, 1025, interpret=True)
+def test_unknown_algo_name_raises(rng):
+    """Only the enum's algorithms select: a name outside it (such as the
+    removed "pallas") raises the enum's ValueError."""
+    x = rng.standard_normal((4, 256)).astype(np.float32)
+    with pytest.raises(ValueError, match="pallas"):
+        select_k(x, 4, algo="pallas")
+    v, _ = select_k(x, 4, algo="direct")
+    np.testing.assert_allclose(np.asarray(v), np.sort(x, 1)[:, :4])
 
 
 def test_auto_uses_measured_table():
@@ -219,8 +193,8 @@ def test_topk_pad_rules():
 
 
 def test_platform_key_is_the_backend_name(monkeypatch):
-    """Measured tables are keyed by the backend name alone: on a TPU
-    backend the tpu tables arm, on CPU the cpu ones."""
+    """The in-code tables are keyed by the backend name alone: on a TPU
+    backend the tpu pad rules arm, on CPU none do."""
     import importlib
 
     import jax
@@ -228,34 +202,87 @@ def test_platform_key_is_the_backend_name(monkeypatch):
     sk = importlib.import_module("raft_tpu.ops.select_k")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert sk._platform_key() == "tpu"
-    # builtin tpu pad rule fires on the tpu backend — and
-    # survives the shipped TOPK_PAD_tpu.json artifact, which measured
-    # other widths but not the (4096, 10) cell (merge semantics:
-    # artifact rules + builtins for unmeasured cells)
     assert sk._pad_k(4096, 10) == 32
-    # a cell the artifact DID measure comes from the artifact
     assert sk._pad_k(8192, 10) == 16
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert sk._platform_key() == "cpu"
     assert sk._pad_k(4096, 10) == 10
 
 
-def test_merge_pad_rules_builtin_survives_unmeasured_cells():
-    """TOPK_PAD artifacts merge with the builtin pad table per (n, k)
-    cell: a measured cell always wins (including k_pad == k "no pad"
-    entries), a builtin survives when the artifact never measured its
-    cell (ADVICE r5: wholesale replacement silently disarmed the n=4096
-    builtin)."""
+# (n, k, k_pad): every "tpu" pad rule, with the k the selection asked
+# for at that width before the rules moved into code. Dropping or
+# changing a row is a change of the program, made on purpose.
+_TPU_PAD_CELLS = [
+    (1024, 4, 64), (1024, 8, 64), (1024, 32, 64),
+    (2048, 4, 32), (2048, 10, 32), (2048, 12, 32), (2048, 16, 32),
+    (2048, 24, 32), (2048, 40, 48),
+    (6144, 4, 24), (6144, 8, 24), (6144, 12, 24),
+    (8192, 8, 16), (8192, 10, 16),
+    (16384, 8, 40), (16384, 12, 40), (16384, 32, 40),
+    (32768, 4, 16), (32768, 8, 16),
+    (4096, 10, 32),
+]
+
+
+@pytest.mark.parametrize("n,k,k_pad", _TPU_PAD_CELLS,
+                         ids=[f"{n}-{k}" for n, k, _ in _TPU_PAD_CELLS])
+def test_tpu_pad_table_keeps_measured_cells(n, k, k_pad, monkeypatch):
     import importlib
 
     sk = importlib.import_module("raft_tpu.ops.select_k")
-    builtin = [{"n": 4096, "k": 10, "k_pad": 32},
-               {"n": 2048, "k": 10, "k_pad": 32}]
-    measured = [{"n": 2048, "k": 10, "k_pad": 10},   # measured: no pad
-                {"n": 8192, "k": 10, "k_pad": 16}]
-    merged = sk._merge_pad_rules(builtin, measured)
-    cells = {(r["n"], r["k"]): r["k_pad"] for r in merged}
-    assert cells[(2048, 10)] == 10   # measured overrides builtin
-    assert cells[(8192, 10)] == 16   # measured-only cell kept
-    assert cells[(4096, 10)] == 32   # unmeasured builtin survives
-    assert len(merged) == 3
+    monkeypatch.setattr(sk, "_platform_key", lambda: "tpu")
+    assert sk._pad_k(n, k) == k_pad
+    assert len(sk._BUILTIN_PAD_RULES["tpu"]) == len(_TPU_PAD_CELLS)
+
+
+def _drop_table_caches(monkeypatch, *modules):
+    """Drop every lazily loaded table of the dispatch modules, so the
+    next lookup builds it anew."""
+    for mod in modules:
+        for name, value in vars(mod).items():
+            if name.endswith("_cache") and isinstance(value, dict):
+                monkeypatch.setattr(mod, name, None)
+
+
+@pytest.mark.parametrize("prefix,via", [
+    ("TOPK_PAD", "cwd"), ("TOPK_PAD", "env"),
+    ("PALLAS_PROBE", "cwd"), ("PALLAS_PROBE", "env")])
+def test_dispatch_reads_no_json_files(prefix, via, tmp_path, monkeypatch):
+    """The choice of kernel and of k lives in code: a dispatch-shaped JSON
+    file in the working directory, or named by an environment variable,
+    switches nothing."""
+    import importlib
+    import json
+
+    import jax
+
+    from raft_tpu.ops import pallas_kernels as pk
+    from raft_tpu.parallel import sharded
+
+    sk = importlib.import_module("raft_tpu.ops.select_k")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def decisions():
+        return ([sk._pad_k(n, k) for n, k, _ in _TPU_PAD_CELLS]
+                + [sk._pad_k(4096, 16)],
+                [pk.fused_dispatch_explained(f, "auto") for f in (
+                    "brute_force", "ivf_flat", "ivf_pq", "ivf_scan",
+                    "l2_argmin", "cagra")],
+                sharded.merge_dispatch_explained("auto", 4))
+
+    before = decisions()
+    art = {"platform": "tpu",
+           "pad_rules": [{"n": n, "k": k, "k_pad": k}
+                         for n, k, _ in _TPU_PAD_CELLS]
+           + [{"n": 4096, "k": 16, "k_pad": 64}],
+           "fused": {f: {"fused_wins": True} for f in (
+               "brute_force", "ivf_flat", "ivf_pq", "ivf_scan",
+               "l2_argmin", "cagra", "merge_ring")}}
+    path = tmp_path / f"{prefix}_tpu.json"
+    path.write_text(json.dumps(art))
+    if via == "cwd":
+        monkeypatch.chdir(tmp_path)
+    else:
+        monkeypatch.setenv(f"RAFT_TPU_{prefix}", str(path))
+    _drop_table_caches(monkeypatch, sk, pk)
+    assert decisions() == before

@@ -392,8 +392,8 @@ def test_cagra_recall_on_chip(clustered, gt):
 
 
 def test_topk_pad_exact_on_chip(rng):
-    """k-pad rules (TOPK_PAD_tpu.json / set_pad_rules) rewrite DIRECT's
-    requested k on the real top_k lowering; the padded prefix must equal
+    """k-pad rules (select_k's in-code "tpu" table, swapped here through
+    set_pad_rules) rewrite DIRECT's requested k on the real top_k lowering; the padded prefix must equal
     the unpadded selection bit-for-bit, at the measured pathological cell
     (n=4096, k=10: 112-120 ms unpadded vs ~2 ms at k=32 on v5e)."""
     import importlib
@@ -406,9 +406,8 @@ def test_topk_pad_exact_on_chip(rng):
     x = rng.standard_normal((512, 4096)).astype(np.float32)
     plat = sk._platform_key()
     prev = sk._load_pad_rules().get(plat)
-    # baseline must be UNPADDED even when the queue already dropped a
-    # TOPK_PAD artifact at the repo root (else this compares padded to
-    # padded and proves nothing)
+    # baseline must be UNPADDED although the in-code table pads this
+    # cell (else this compares padded to padded and proves nothing)
     sk.set_pad_rules(plat, None)
     v0, i0 = select_k(x, 10, algo=SelectAlgo.DIRECT)
     v0, i0 = np.asarray(v0), np.asarray(i0)

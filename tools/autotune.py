@@ -10,8 +10,7 @@ peaks are known), and writes ``PARETO_<platform>.json`` — the artifact
 ``raft_tpu.planner.AdaptivePlanner`` loads and the serving engine
 spends latency budgets against (docs/tuning.md "Adaptive planning").
 
-Artifact discipline matches PALLAS_PROBE / SELECT_K_TABLE: schema tag
-(``raft_tpu.pareto/v1``), flat ``"metrics"`` mirror, refreshed by a
+Artifact discipline: schema tag (``raft_tpu.pareto/v1``), flat ``"metrics"`` mirror, refreshed by a
 chip run, diffed curve-aware by
 ``tools/bench_gate.py`` (frontier kind: hypervolume + per-recall-band
 QPS, never pointwise).

@@ -1,9 +1,9 @@
 """ANN micro-bench on the current backend.
 
 Usage: python tools/bench_ann.py [ivf_flat|ivf_pq|cagra|bf|all] [n_rows]
-Scan-engine routing follows the committed PALLAS_PROBE artifact (fused
-scan+select on TPU where the probe shows it winning; scan_mode="pallas"
-in SearchParams forces it) — the RAFT_TPU_PALLAS env flag is retired.
+Scan-engine routing is ``scan_mode="auto"``'s, decided in code
+(``ops/pallas_kernels.fused_dispatch_explained``); scan_mode="pallas"
+in SearchParams forces the fused kernels.
 Clustered (make_blobs) data so recall reflects the IVF regime.
 Timing via bench/timing.py; queries are uploaded once before any timed
 region.

@@ -11,9 +11,9 @@ drift at a glance.
 
 This is the triage entry point for "why is my query slow / on XLA":
 run it on the same host (TPU or CPU) with the same scan_mode and read
-the reason line. ``no_fused_wins_verdict`` on TPU means no committed
-PALLAS_PROBE artifact holds a fused verdict — run tools/pallas_probe.py
-on the chip.
+the reason line. ``fused_unmeasured`` on TPU means ``auto`` took XLA
+because no fused kernel has a chip measurement beating it
+(``ops/pallas_kernels.py``).
 
 Usage: python tools/explain.py [--family all] [--n 4096] [--dim 64]
        [--k 10] [--scan-mode auto] [--out explain.json]

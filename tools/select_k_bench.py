@@ -4,7 +4,7 @@ VERDICT r2 #6: AUTO's DIRECT/TWO_PHASE decision must come from
 measurement, not the old hardcoded 65536. This sweeps batch-2048 rows
 (the IVF probe-merge shape: [q_tile, n_probes·list_pad]) across widths
 and k ∈ {10, 32, 64, 128, 256} on whatever backend is active, times
-DIRECT vs TWO_PHASE vs (opt-in, small-k) PALLAS, and writes:
+DIRECT vs TWO_PHASE vs APPROX, and writes:
 
   - a full timing grid, and
   - the per-k-band crossover widths in the format of
@@ -37,9 +37,6 @@ def main():
                     default=[4096, 16384, 32768, 65536, 131072, 262144])
     ap.add_argument("--ks", type=int, nargs="*",
                     default=[10, 32, 64, 128, 256])
-    ap.add_argument("--pallas", action="store_true",
-                    help="also time SelectAlgo.PALLAS (TPU only; the "
-                         "interpreter is not a measurement)")
     args = ap.parse_args()
 
     if os.environ.get("RAFT_TPU_BENCH_PLATFORM") != "default":
@@ -54,8 +51,6 @@ def main():
     rng = np.random.default_rng(0)
     grid = []
     algos = [SelectAlgo.DIRECT, SelectAlgo.TWO_PHASE, SelectAlgo.APPROX]
-    if args.pallas:
-        algos.append(SelectAlgo.PALLAS)
 
     def write(partial, **extra):
         """Write the artifact after every row: a timeout kill mid-sweep
@@ -78,8 +73,6 @@ def main():
                 continue
             row = {"n": n, "k": k}
             for algo in algos:
-                if algo == SelectAlgo.PALLAS and k > 1024:
-                    continue
                 dt = time_dispatches(lambda: select_k(x, k, algo=algo),
                                      iters=args.iters)
                 row[algo.value + "_ms"] = round(dt * 1e3, 3)
