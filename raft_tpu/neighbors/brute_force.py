@@ -270,9 +270,9 @@ def _scan_tiles(nq: int, n_db: int, db_tile: int, k: int, tile_dist,
 
 
 def _tile_groups(tile_dist, select_min: bool):
-    """XLA's producer of a tile's groups for ``_group_topk``: the tile's
-    values (distances, negated when selecting the largest: negation is
-    exact) as [nq, n_g, GROUP], the remainder's pad +inf past every row,
+    """XLA's producer of a tile's groups for ``_group_topk``: a gather of
+    the tile's values (distances, negated when selecting the largest:
+    negation is exact) by group, the remainder's pad +inf past every row,
     and each group's minimum [nq, n_g]."""
     def groups(start, width):
         d = tile_dist(start, width)
@@ -282,9 +282,38 @@ def _tile_groups(tile_dist, select_min: bool):
             d = jnp.pad(d, ((0, 0), (0, n_g * GROUP - width)),
                         constant_values=jnp.inf)
         d = d.reshape(d.shape[0], n_g, GROUP)
-        return d, d.min(axis=-1)
+        return (lambda sel: jnp.take_along_axis(d, sel[..., None], axis=1),
+                d.min(axis=-1))
 
     return groups
+
+
+def _groups_major_take(tile, sel):
+    """The values of groups ``sel`` [q, m] of the group kernel's tile [n_g,
+    q, GROUP] as [q, m, GROUP], gathered as rows of its [n_g·q, GROUP]
+    view (a bitcast): a v5e gathers those faster than from the batched
+    [q, n_g, GROUP] view."""
+    n_g, q = tile.shape[:2]
+    at = sel * q + jax.lax.broadcasted_iota(sel.dtype, sel.shape, 0)
+    return jnp.take(tile.reshape(n_g * q, GROUP), at, axis=0)
+
+
+#: most kept groups a merge (query-tile rows × k) for which
+#: ``_group_topk`` takes each tile's best k groups by ``lax.top_k`` before
+#: the sort: XLA keeps such a top_k a partial selection (a TopK call)
+#: rather than a full sort. One v5e, 1M × 128, best of 7 (PERF.md §6,
+#: PR 28): at 64 queries × k 10 (640) the top_k first takes 2.877 ms a
+#: search, sorting every minimum 3.330; at 8 × k 100 (800) 2.449 against
+#: 2.361, and sorting wins at every larger shape measured.
+_TOPK_MERGE_MAX = 640
+
+
+def _total_order(x):
+    """float32 ``x`` as int32 keys that rank as ``lax.top_k`` ranks floats:
+    IEEE total order, -0.0 ahead of +0.0 (``lax.sort`` of the floats would
+    tie them)."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return b ^ ((b >> 31) & 0x7FFFFFFF)
 
 
 def _group_topk(nq: int, n_db: int, db_tile: int, k: int, groups,
@@ -293,45 +322,57 @@ def _group_topk(nq: int, n_db: int, db_tile: int, k: int, groups,
     values and ids as one ``lax.top_k`` over the whole row, ties to the
     lower row.
 
-    ``groups(start, width)`` gives a tile's values as [nq, n_g, GROUP]
-    (least is best) and each group's minimum [nq, n_g] (``_tile_groups``
-    or the group kernel). The k groups of least minimum (ties to the
+    ``groups(start, width)`` gives ``take``, which gathers the tile's
+    values of groups ``sel`` [nq, m] as [nq, m, GROUP] (least is best), and
+    each group's minimum [nq, n_g] (``_tile_groups`` or the group kernel,
+    ``_groups_major_take``). The k groups of least minimum (ties to the
     lower group) hold the k best rows: a row outside them is no better
     than any of those k minima, which are k distinct rows ranked ahead of
-    it. The carry holds the k best groups so far (minimum, the rows'
-    values, first row), in row order; each tile's group minima compete
-    with it in one top-k over width / GROUP + k values, and one top-k over
-    the k·GROUP kept values ends the scan; the values of a scan selecting
-    the largest are negated back."""
+    it. The carry holds the k best groups so far (minimum as its
+    ``_total_order`` key, the rows' values, first row), in row order; each
+    tile's group minima compete with it in one stable sort over width /
+    GROUP + k keys, and one top-k over the k·GROUP kept values ends the
+    scan; the values of a scan selecting the largest are negated back."""
     g = GROUP
-
-    def take(rows, sel):
-        # the rows of groups ``sel`` [q, k] of ``rows`` [q, n_g, g]
-        return jnp.take_along_axis(rows, sel[..., None], axis=1)
-
-    def best(mins):
-        # the k least, ties to the lower position, in position order
-        return jnp.sort(jax.lax.top_k(-mins, k)[1], axis=1)
 
     def merge(carry, start, width):
         # The tiles go from the last down and the first carry's groups are
         # empty ones past every row, so the tile's rows precede the
-        # carried ones: the minima concatenate in row order, and ties go to
-        # the lower row.
-        c_mins, c_rows, c_first = carry
-        rows, mins = groups(start, width)
-        n_t = mins.shape[1]
-        mins = jnp.concatenate([mins, c_mins], axis=1)
-        sel = best(mins)
+        # carried ones: the tile's candidates come first, equal minima
+        # among them in row order, and the stable sort sends ties to the
+        # lower row. Each group's first row rides through both sorts as a
+        # payload, and the sorted keys are the kept minima: element
+        # gathers of them cost a TPU more than the sorts.
+        c_keys, c_rows, c_first = carry
+        take, mins = groups(start, width)
+        nq_t, n_t = mins.shape
+        if nq_t * k > _TOPK_MERGE_MAX:
+            at = jax.lax.broadcasted_iota(jnp.int32, (nq_t, n_t), 1)
+            # one iota, which XLA's stable sort takes as its own tie-break
+            # rather than adding a fourth operand (10 ms a call at the
+            # deep cell's shard)
+            pos = jax.lax.broadcasted_iota(jnp.int32, (nq_t, n_t + k), 1)
+        else:
+            # the tile's best groups first, ties to the lower group
+            v, at = jax.lax.top_k(-mins, min(k, n_t))
+            mins = -v
+            pos = jnp.concatenate([at, n_t + jax.lax.broadcasted_iota(
+                jnp.int32, (nq_t, k), 1)], axis=1)
+        keys, sel, first = jax.lax.sort(
+            (jnp.concatenate([_total_order(mins), c_keys], axis=1), pos,
+             jnp.concatenate([start + g * at, c_first], axis=1)),
+            dimension=1, is_stable=True, num_keys=1)
+        # the k least, back in position order
+        sel, keys, first = jax.lax.sort(
+            (sel[:, :k], keys[:, :k], first[:, :k]), dimension=1, num_keys=1)
         in_tile = sel < n_t
         ts, cs = jnp.minimum(sel, n_t - 1), jnp.maximum(sel - n_t, 0)
-        return (jnp.take_along_axis(mins, sel, 1),
-                jnp.where(in_tile[..., None], take(rows, ts),
+        return (keys,
+                jnp.where(in_tile[..., None], take(ts),
                           jnp.take_along_axis(c_rows, cs[..., None], 1)),
-                jnp.where(in_tile, start + ts * g,
-                          jnp.take_along_axis(c_first, cs, 1)))
+                first)
 
-    carry = (jnp.full((nq, k), jnp.inf, jnp.float32),
+    carry = (jnp.full((nq, k), _total_order(jnp.float32(jnp.inf))),
              jnp.full((nq, k, g), jnp.inf, jnp.float32),
              jnp.full((nq, k), n_db, jnp.int32))
     n_full, rem = divmod(n_db, db_tile)
@@ -431,7 +472,7 @@ def _knn_jit(queries, dataset, db_norms, filter_words, metric, metric_arg, k,
                 sqrt=metric == DistanceType.L2SqrtExpanded,
                 negate=not minimize, filtered=has_filter,
                 interpret=group_scan.interpret)
-            return jnp.swapaxes(tile, 0, 1), mins
+            return functools.partial(_groups_major_take, tile), mins
 
         def tile_dist(start, width):
             db_t = jax.lax.dynamic_slice_in_dim(dataset, start, width, 0)

@@ -201,14 +201,20 @@ def test_choose_tiles_balanced():
     (20_000, 16, 32, 4_096, 16, "duplicate_rows"),
     (20_000, 16, 10, 2_048, 16, "inner_product"),
     (20_000, 20, 10, 2_048, 8, "query_tiles"),
+    (60_000, 16, 100, 12_800, 16, "plain"),
+    (20_000, 16, 10, 2_048, 16, "tied_carry"),
+    (60_000, 16, 100, 12_800, 16, "tied_carry"),
 ], ids=["k10", "k64", "sparse_filter", "starved_filter", "duplicate_rows",
-        "inner_product", "query_tiles"])
+        "inner_product", "query_tiles", "many_tiles", "tied_carry_k10",
+        "tied_carry_k100"])
 def test_group_scan_matches_direct(monkeypatch, n, nq, k, db_tile, q_tile,
                                    case):
     """The group-minima scan (``_group_topk``) answers exactly as the
     per-tile DIRECT top-k it replaces: the same ids, ties to the lower row,
     the same float32 bits. Both run on the same tiles, so XLA:CPU rounds
-    the distances alike."""
+    the distances alike. ``tied_carry`` repeats a block of ten groups, so
+    every merge weighs tile groups against carried ones of equal
+    minimum."""
     import jax
 
     from raft_tpu.core.bitset import Bitset
@@ -217,6 +223,8 @@ def test_group_scan_matches_direct(monkeypatch, n, nq, k, db_tile, q_tile,
     db = rng.standard_normal((n, 8)).astype(np.float32)
     if case == "duplicate_rows":
         db = db[:300][rng.integers(0, 300, n)]
+    if case == "tied_carry":
+        db = np.tile(db[:1_280], (n // 1_280 + 1, 1))[:n]
     q = rng.standard_normal((nq, 8)).astype(np.float32)
     metric = "inner_product" if case == "inner_product" else "sqeuclidean"
     flt = None
@@ -268,11 +276,12 @@ _TILE_CASES = [
     (c + "-rows_major", s, w, g) for c, s, w, g in _TILE_CASES])
 def test_group_scan_tile_matches_xla_tile(case, start, width, gb):
     """``pk.group_scan_tile`` (Mosaic interpreter) against XLA's tile
-    producer (``_tile_groups``) on the same rows, reading the collection's
-    [dim, rows] view or, for ``-rows_major``, its [rows, dim] blocks: +inf
-    at the same places (the last group's pad, rows past ``n_valid``, rows a
-    filter clears), finite values within a few ulp, each minimum its
-    group's least value, and the same groups ranked first."""
+    producer (``_tile_groups``, every group gathered) on the same rows,
+    reading the collection's [dim, rows] view or, for ``-rows_major``, its
+    [rows, dim] blocks: +inf at the same places (the last group's pad, rows
+    past ``n_valid``, rows a filter clears), finite values within a few
+    ulp, each minimum its group's least value, and the same groups ranked
+    first."""
     import jax.numpy as jnp
 
     from raft_tpu.ops import pallas_kernels as pk
@@ -307,9 +316,10 @@ def test_group_scan_tile_matches_xla_tile(case, start, width, gb):
         return jnp.where((ids >= limit) | ~keep[ids], np.inf if l2
                          else -np.inf, d)
 
-    want_rows, want_mins = brute_force._tile_groups(tile_dist, l2)(start,
-                                                                  width)
-    want_rows = np.asarray(want_rows)
+    take, want_mins = brute_force._tile_groups(tile_dist, l2)(start, width)
+    n_g = want_mins.shape[1]
+    want_rows = np.asarray(take(jnp.broadcast_to(jnp.arange(n_g),
+                                                 (16, n_g))))
     assert rows.shape == want_rows.shape
     np.testing.assert_array_equal(np.isinf(rows), np.isinf(want_rows))
     fin = np.isfinite(want_rows)

@@ -226,6 +226,29 @@ def _kernel_vmem_fits(txt: str, q_tile: int, dim: int, plan):
         assert used and int(used.group(1)) <= planned, ln[:200]
 
 
+def _gathers(txt: str):
+    """(output shape, in a loop body) of each gather of the optimized HLO
+    ``txt`` that XLA runs as an op of its own: a ``gather``, or a fusion
+    of one, outside the fusions' bodies."""
+    import re
+
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", txt))
+    out, body = [], None
+    for ln in txt.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", ln)
+        if head:
+            body = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%\S+ = [fs]32\[([\d,]*)\]\S* "
+                     r"(gather|fusion)\(", ln)
+        if m and body not in fused and re.search(
+                r'op_name="[^"]*/gather"', ln) and (
+                m.group(2) == "gather" or "kind=kCustom" in ln):
+            out.append(([int(d) for d in m.group(1).split(",")],
+                        "/while/body/" in ln))
+    return out
+
+
 def test_sharded_knn_group_kernel_at_deep100m_shard(topo):
     """The deep100m-exact cell's program as a v5e compiles it, with each
     distance tile and its group minima made by the group kernel: the
@@ -233,7 +256,11 @@ def test_sharded_knn_group_kernel_at_deep100m_shard(topo):
     [1000, ~208k] distance tile (the tile is written groups-major and the
     gather reads it as a bitcast) or the f32[3125000, 96] shard (the
     kernel reads its [96, rows] view, a bitcast of the v5e's layout of a
-    96-wide array); and the temp is no more than with XLA's tile."""
+    96-wide array); and the temp is no more than with XLA's tile. The
+    group merge gathers no minimum or first row one element at a time
+    (they ride through its sorts): the q·k = 100,000-element gathers left
+    are the final top-k's ids and the select's, outside the tile loop,
+    and the loop gathers only the kept groups' rows."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from raft_tpu import Resources
@@ -265,6 +292,11 @@ def test_sharded_knn_group_kernel_at_deep100m_shard(topo):
     _assert_no_large_copy(txt, (3_125_000, 96), 1000 * 200_000)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= DEEP_XLA_TILE_TEMP, mem.temp_size_in_bytes
+    gathers = _gathers(txt)
+    elements = [loop for shape, loop in gathers if shape == [100_000]]
+    assert not any(elements) and len(elements) <= 2, gathers
+    assert sorted(shape for shape, loop in gathers if loop) == [
+        [100_000, 128]] * 2, gathers
 
 
 @pytest.mark.parametrize("nq,n,dim,k,lanes_rows", [

@@ -65,8 +65,10 @@ def test_fused_l2_argmin_matches_oracle(clustered):
     (64, 300_000, 128, 10, "sqeuclidean"),
     (1000, 420_000, 128, 100, "sqeuclidean"),
     (1000, 300_000, 128, 100, "inner_product"),
+    (1000, 900_000, 96, 100, "sqeuclidean_tied"),
 ], ids=["small", "two_tiles", "inner_product", "sift_width_small",
-        "sift_width_two_tiles", "sift_width_inner_product"])
+        "sift_width_two_tiles", "sift_width_inner_product",
+        "many_tiles_tied"])
 def test_group_scan_exact_on_chip(rng, nq, n, dim, k, metric):
     """The exact brute-force scan's group minima (brute_force._group_topk),
     over the tiles and minima of the group kernel (pk.group_scan_tile),
@@ -76,7 +78,9 @@ def test_group_scan_exact_on_chip(rng, nq, n, dim, k, metric):
     may round apart; on a v5e they were bit-equal, PERF.md). The chip
     keeps a 32- or 96-wide collection with its rows on the lanes, and the
     kernel reads its [dim, rows] view; a 128-wide one rows-major, read in
-    [rows, dim] blocks."""
+    [rows, dim] blocks. ``_tied`` repeats a block of ten groups over many
+    tiles, so every merge weighs tile groups against carried ones of
+    equal minimum."""
     from raft_tpu.neighbors import brute_force
     from raft_tpu.obs import explain as obs_explain
     from raft_tpu.ops.distance import (inner_product, l2_expanded,
@@ -88,6 +92,9 @@ def test_group_scan_exact_on_chip(rng, nq, n, dim, k, metric):
         (n, dim)).astype(np.float32)
     q = centers[rng.integers(0, 64, nq)] + rng.standard_normal(
         (nq, dim)).astype(np.float32)
+    if metric.endswith("_tied"):
+        metric = metric[:-len("_tied")]
+        db = np.tile(db[:1_280], (n // 1_280 + 1, 1))[:n]
     with obs_explain.capture() as cap:
         v, i = brute_force.search(brute_force.build(db, metric=metric), q, k)
     scan = [r for r in cap.records if r.family == "brute_force_group_scan"]
