@@ -44,6 +44,7 @@ from raft_tpu.cluster.kmeans_balanced import KMeansBalancedParams
 from raft_tpu.neighbors import list_packing
 from raft_tpu.neighbors.brute_force import fused_ineligible_reason
 from raft_tpu.obs import explain as obs_explain
+from raft_tpu.obs import metrics as obs_metrics
 from raft_tpu.ops.distance import (DistanceType, gathered_distances,
                                     resolve_metric, row_norms_sq)
 from raft_tpu.ops.select_k import (refine_multiplier, select_k,
@@ -51,6 +52,12 @@ from raft_tpu.ops.select_k import (refine_multiplier, select_k,
 from raft_tpu.ops import rng as rrng
 from raft_tpu.utils.shape import (as_query_array, cdiv, pad_rows,
                                   query_bucket)
+
+_SCAN_PLANS = obs_metrics.REGISTRY.counter(
+    "raft_tpu_ivf_flat_scan_plans_total",
+    "ivf_flat search dispatches by engine and the precision of the probe "
+    "scan's contraction.",
+    ("engine", "precision"))
 
 
 @dataclasses.dataclass
@@ -103,10 +110,11 @@ class SearchParams:
     # (scan_dtype="bfloat16" only); wider = higher recall, more re-rank
     refine_ratio: float = 4.0
     # "pallas" requests the fused Pallas scan+select (probed slabs DMA'd to
-    # VMEM, top-k carried in-kernel — docs/tuning.md); "auto" picks it on
-    # TPU where the committed probe artifact shows it winning; unsupported
-    # combinations (non-L2 metric, filter, bf16 fast scan, k > 1024) fall
-    # back to the XLA engine silently
+    # VMEM, top-k carried in-kernel — docs/tuning.md); "auto" takes the
+    # XLA engine, on a TPU too (reason "fused_unmeasured"), a rule in code
+    # (pallas_kernels.fused_dispatch_explained); unsupported combinations
+    # (non-L2 metric, filter, bf16 fast scan, k > 1024) fall back to the
+    # XLA engine silently
     scan_mode: str = "auto"
     # <1.0 routes internal top-k through the TPU PartialReduce engine
     # (ops.select_k APPROX) at this per-element recall target — measured
@@ -312,6 +320,15 @@ def _merge_overflow(old_rows, old_ids, n_old_valid: int, new_rows_np,
     return jnp.asarray(rows), jnp.asarray(ids)
 
 
+def _scan_precision(fast_scan: bool, dtype):
+    """The probe scan's contraction precision: HIGHEST only for true fp32
+    data on the accurate path; int8/uint8/bf16 values are bf16-exact and
+    the fast scan is a bf16 screen → a single MXU pass (None)."""
+    if not fast_scan and jnp.dtype(dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return None
+
+
 def _coarse_scores(queries, centers, metric: DistanceType):
     dots = jax.lax.dot_general(
         queries.astype(jnp.float32), centers, (((1,), (1,)), ((), ())),
@@ -408,11 +425,7 @@ def _search_core(queries, centers, list_data, list_indices, list_sizes,
             q_s, g_s = qf, g_data.astype(jnp.float32)
         dots = jnp.einsum(
             "td,tpld->tpl", q_s, g_s,
-            # HIGHEST only for true fp32 data on the accurate path;
-            # int8/uint8/bf16 values are bf16-exact → single MXU pass
-            precision=(jax.lax.Precision.HIGHEST
-                       if (not fast_scan
-                           and g_data.dtype == jnp.float32) else None),
+            precision=_scan_precision(fast_scan, g_data.dtype),
             preferred_element_type=jnp.float32,
         )
         if metric == DistanceType.InnerProduct:
@@ -672,9 +685,12 @@ def search(
                 list_pad, index.dim, int(k),
                 jnp.dtype(index.list_data.dtype).itemsize,
                 n_probes=n_probes)
+            # the kernel's dot runs at HIGHEST whatever the list dtype
+            _SCAN_PLANS.labels("fused", "highest").inc()
             obs_explain.record_dispatch(
                 "ivf_flat", scan_mode, "pallas", dreason, params=ex_params,
-                plan={"pad_tile": pad_tile, "interpret": fused_interp})
+                plan={"pad_tile": pad_tile, "interpret": fused_interp,
+                      "precision": "highest"})
             v, i = _search_fused_jit(
                 queries, index.centers, index.list_data, index.list_indices,
                 index.list_sizes, index.ensure_row_norms(),
@@ -684,11 +700,15 @@ def search(
             )
         else:
             reason = ineligible if (use_fused and ineligible) else dreason
+            prec = ("highest" if _scan_precision(
+                fast_scan, index.list_data.dtype) else "default")
+            _SCAN_PLANS.labels("xla", prec).inc()
             obs_explain.record_dispatch(
                 "ivf_flat", scan_mode, "xla", reason, params=ex_params,
                 plan={"q_tile": q_tile,
                       "predicted_workspace_bytes": q_tile *
-                      scan_bytes_per_query(n_probes, list_pad, index.dim)})
+                      scan_bytes_per_query(n_probes, list_pad, index.dim),
+                      "precision": prec})
             # Cached exact norms are required by the bf16 fast scan; the
             # fp32 path keeps computing norms per probed tile instead
             # (materializing [L, pad] fp32 norms for a large narrow-dtype

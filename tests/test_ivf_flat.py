@@ -3,6 +3,7 @@ reference's acceptance pattern (cpp/test/neighbors/ann_ivf_flat.cuh:
 build→(serialize→load)→search, assert recall ≥ floor)."""
 
 import io
+import itertools
 
 import numpy as np
 import pytest
@@ -190,3 +191,160 @@ def test_bf16_fast_scan(data, gt):
     with pytest.raises(ValueError, match="bfloat16"):
         ivf_flat.search(index, q, 10,
                         ivf_flat.SearchParams(n_probes=4, scan_dtype="float16"))
+
+
+# ------------------------------- the plain probe-restricted reference
+
+
+def _numpy_ivf_flat(index, q, k: int, n_probes: int, inner: bool, ctol):
+    """IVF-Flat search in float64 numpy, independent of the jitted core:
+    the index's own centres, each query's ``n_probes`` nearest lists,
+    exact distances over those lists' valid rows and the overflow block,
+    the k best. Lists whose coarse distance lies within ``ctol`` [nq] of
+    the ``n_probes``-th are a tie at float32, which the program may break
+    either way: each query gets one answer per way of filling its probe
+    set from the tied lists, as (distances [k], ids [k], the distance of
+    every row id the probes saw)."""
+    centers = np.asarray(index.centers, np.float64)
+    data = np.asarray(index.list_data, np.float64)
+    ids = np.asarray(index.list_indices)
+    sizes = np.asarray(index.list_sizes)
+    over_ok = np.asarray(index.overflow_indices) >= 0
+    o_rows = np.asarray(index.overflow_data, np.float64)[over_ok]
+    o_ids = np.asarray(index.overflow_indices)[over_ok]
+    qd = q.astype(np.float64)
+    coarse = (-(qd @ centers.T) if inner
+              else ((qd[:, None, :] - centers[None]) ** 2).sum(-1))
+    answers = []
+    for qi, c, tol in zip(qd, coarse, ctol):
+        edge = np.sort(c)[n_probes - 1]
+        sure = np.flatnonzero(c < edge - tol).tolist()
+        tied = np.flatnonzero(np.abs(c - edge) <= tol).tolist()
+        alts = []
+        for pick in itertools.combinations(tied, n_probes - len(sure)):
+            p = sure + list(pick)
+            rows = np.concatenate([data[l, :sizes[l]] for l in p] + [o_rows])
+            rid = np.concatenate([ids[l, :sizes[l]] for l in p] + [o_ids])
+            d = -(rows @ qi) if inner else ((rows - qi) ** 2).sum(-1)
+            order = np.argsort(d, kind="stable")[:k]
+            sign = -1.0 if inner else 1.0
+            alts.append((sign * d[order], rid[order],
+                         dict(zip(rid.tolist(), (sign * d).tolist()))))
+        answers.append(alts)
+    return answers
+
+
+def _same_answer(d, i, ref, tol) -> bool:
+    """One query's answer is the reference's: each distance within ``tol``
+    of the reference's at its rank and of its id's own distance, and ids
+    that differ only where their distances tie with the k-th place."""
+    ref_d, ref_i, seen = ref
+    if not (np.abs(d - ref_d) <= tol).all():
+        return False
+    if any(j not in seen or abs(seen[j] - dj) > tol
+           for j, dj in zip(i.tolist(), d)):
+        return False
+    return all(abs(seen[j] - ref_d[-1]) <= tol
+               for j in set(i.tolist()) ^ set(ref_i.tolist()))
+
+
+@pytest.fixture(scope="module")
+def wide():
+    rng = np.random.default_rng(30)
+    db = rng.standard_normal((20000, 128)).astype(np.float32)
+    q = rng.standard_normal((200, 128)).astype(np.float32)
+    return db, q
+
+
+@pytest.fixture(scope="module")
+def wide_index(wide):
+    db, _ = wide
+    return {m: ivf_flat.build(db, ivf_flat.IndexParams(n_lists=64, metric=m))
+            for m in ("sqeuclidean", "inner_product")}
+
+
+@pytest.mark.parametrize("metric", ["sqeuclidean", "inner_product"])
+@pytest.mark.parametrize("n_probes", [8, 64])
+def test_search_matches_numpy_reference(wide, wide_index, metric, n_probes):
+    """``ivf_flat.search`` returns the plain reference's neighbours among
+    the lists it probes, at L2Expanded and InnerProduct, probing a few
+    lists or all of them.
+
+    Tolerance: the program computes ``‖q‖² + ‖v‖² − 2·q·v`` (or ``q·v``)
+    in float32, so each distance may differ from the float64 one by a few
+    float32 roundings of ``‖q‖² + ‖v‖²`` (for the inner product,
+    ``‖q‖·‖v‖``); 1e-5 of that scale is about 80 ulps. Ids may differ
+    only where the two candidates' float64 distances lie within that
+    tolerance of each other at the k-th place, a tie at float32. The
+    coarse distances to the centres carry the same rounding (1e-5 of
+    ``‖q‖² + ‖c‖²``, or ``‖q‖·‖c‖``): lists that close to the last
+    probed one are a tie too, and the answer of any probe set the tie
+    allows is accepted."""
+    db, q = wide
+    index = wide_index[metric]
+    inner = metric == "inner_product"
+    k = 10
+    d, i = ivf_flat.search(index, q, k,
+                           ivf_flat.SearchParams(n_probes=n_probes))
+    d, i = np.asarray(d, np.float64), np.asarray(i)
+    qn = (q.astype(np.float64) ** 2).sum(1)
+    vn = float((db.astype(np.float64) ** 2).sum(1).max())
+    cn = float((np.asarray(index.centers, np.float64) ** 2).sum(1).max())
+    tol = 1e-5 * (np.sqrt(qn * vn) if inner else qn + vn)
+    ctol = 1e-5 * (np.sqrt(qn * cn) if inner else qn + cn)
+    answers = _numpy_ivf_flat(index, q, k, n_probes, inner, ctol)
+    for r, alts in enumerate(answers):
+        assert any(_same_answer(d[r], i[r], ref, tol[r]) for ref in alts), r
+    # a tie at the probe edge is rare: nearly every query has one answer
+    assert sum(len(alts) > 1 for alts in answers) <= len(q) // 20
+
+
+@pytest.mark.parametrize("case,engine,want", [
+    ("float32", "xla", "highest"),
+    ("bfloat16_screen", "xla", "default"),
+    ("int8", "xla", "default"),
+    ("fused", "pallas", "highest"),
+])
+def test_search_counts_its_engine_and_precision(data, rng, case, engine,
+                                                want, monkeypatch):
+    """Each search counts one dispatch in
+    ``raft_tpu_ivf_flat_scan_plans_total{engine,precision}`` with the
+    engine the explain record names (its ``pallas`` counted as ``fused``)
+    and the precision in its plan; on the XLA engine that precision is the
+    one the probe scan's contraction is lowered at."""
+    from raft_tpu.obs.metrics import REGISTRY
+
+    db, q = data
+    params = ivf_flat.SearchParams(n_probes=8)
+    if case == "bfloat16_screen":
+        params.scan_dtype = "bfloat16"
+    if case == "int8":
+        db = rng.integers(-120, 120, db.shape).astype(np.int8)
+        q = rng.integers(-120, 120, q.shape).astype(np.int8)
+    if case == "fused":
+        monkeypatch.setenv("RAFT_TPU_PALLAS_INTERPRET", "1")
+        params.scan_mode = "pallas"
+    index = ivf_flat.build(db, ivf_flat.IndexParams(n_lists=16))
+    lowered = []
+    real = ivf_flat._search_jit
+
+    def spy(*args):
+        lowered.append(real.lower(*args))
+        return real(*args)
+
+    monkeypatch.setattr(ivf_flat, "_search_jit", spy)
+    plans = REGISTRY.get("raft_tpu_ivf_flat_scan_plans_total")
+    before = dict((key, c.value) for key, c in plans.collect())
+    _, ids, rec = ivf_flat.search(index, q, 10, params, explain=True)
+    after = dict((key, c.value) for key, c in plans.collect())
+    label = "fused" if rec.engine == "pallas" else rec.engine
+    assert (rec.engine, rec.plan["precision"]) == (engine, want)
+    assert after[(label, want)] - before.get((label, want), 0) == 1
+    assert sum(after.values()) - sum(before.values()) == 1
+    assert (np.asarray(ids) >= 0).all()
+    if engine == "xla":
+        dots = [line for line in lowered[0].as_text().splitlines()
+                if "stablehlo.dot_general" in line]
+        # the coarse step, then the probe scan
+        assert "precision = [HIGHEST" in dots[0]
+        assert ("precision = [HIGHEST" in dots[1]) == (want == "highest")
